@@ -1,6 +1,7 @@
 """Shared benchmark utilities: timing, CSV emission, method sweep."""
 from __future__ import annotations
 
+import functools
 import time
 from typing import Callable, Dict, Iterable, List
 
@@ -13,10 +14,21 @@ from repro.obs.metrics import Histogram
 RECORDS: List[Dict] = []
 
 
+@functools.lru_cache(maxsize=None)
+def device() -> Dict:
+    """The device every row ran on, as jax reports it."""
+    import jax
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
 def emit(name: str, us_per_call: float, derived: str):
+    dev = device()
     RECORDS.append({"name": name, "value": float(us_per_call),
-                    "derived": derived})
-    print(f"{name},{us_per_call:.2f},{derived}", flush=True)
+                    "derived": derived, "device": dev})
+    print(f"{name},{us_per_call:.2f},{derived},{dev['platform']}",
+          flush=True)
 
 
 def timed(fn: Callable, *args, repeat: int = 1):

@@ -1,10 +1,12 @@
-"""Throughput benchmarks for the sketching hot paths (host + device/interp).
+"""Throughput benchmarks for the sketching hot paths (host + device).
 
 Production framing: dataset-search ingests a lake by sketching every column
 (sketch/s matters) and serves queries by estimating against the whole corpus
-(pair/s matters).  Device-path numbers on this CPU container exercise the
-Pallas interpreter and the jit pipeline, not TPU silicon -- they validate
-scaling shape, not absolute speed (the roofline analysis covers TPU).
+(pair/s matters).  Every kernel launch goes through :mod:`repro.kernels.ops`,
+so it is compiled on a TPU and interpreted elsewhere, and every emitted row
+names the platform it ran on.  Off a TPU the device-path numbers time the
+Pallas interpreter and the jit pipeline: their timing ratios are tripwires
+for the interpreter, not speed results.
 """
 from __future__ import annotations
 
@@ -25,7 +27,6 @@ from repro.data.synthetic import sparse_pair
 from repro import obs as _obs
 from repro.kernels import ops
 from repro.kernels.estimate import estimate_fields_pallas
-from repro.kernels.icws_sketch import icws_sketch_pallas
 from repro.obs.metrics import Histogram
 from repro.roofline import autotune
 from repro.serve import SketchSearchService
@@ -53,22 +54,22 @@ def run(fast: bool = False):
     _, us = timed(sk.estimate_batch, A, B, repeat=3)
     emit("perf/estimate_batch/wmh", us / A.norm.shape[0], f"pairs={A.norm.shape[0]}")
 
-    # device (Pallas interpret) sketch + fused estimate
+    # device sketch + fused estimate
     B_, N, m = 4, 512, 256
     w = jnp.asarray(rng.random((B_, N)), jnp.float32)
     w = w / w.sum(axis=1, keepdims=True)
     keys = jnp.asarray(rng.integers(0, 2**31 - 1, (B_, N)), jnp.int32)
     vals = jnp.sqrt(w)
-    out = icws_sketch_pallas(w, keys, vals, m=m, seed=0, interpret=True)
-    _, us = timed(lambda: icws_sketch_pallas(w, keys, vals, m=m, seed=0,
-                                             interpret=True)[0].block_until_ready())
-    emit("perf/kernel/icws_sketch", us / B_, f"B={B_} N={N} m={m} interpret=True")
+    out = ops.icws_sketch(w, keys, vals, m=m, seed=0)
+    _, us = timed(lambda: ops.icws_sketch(w, keys, vals, m=m,
+                                          seed=0)[0].block_until_ready())
+    emit("perf/kernel/icws_sketch", us / B_, f"B={B_} N={N} m={m}")
 
     fp, val, _, _ = out
     na = jnp.ones((B_,), jnp.float32)
     _, us = timed(lambda: ops.icws_estimate(fp, val, na, fp, val, na)
                   .block_until_ready())
-    emit("perf/kernel/estimate", us / B_, f"pairs={B_} m={m} interpret=True")
+    emit("perf/kernel/estimate", us / B_, f"pairs={B_} m={m}")
 
     # device-resident corpus: one-vs-many query hot loop.  The query sketch
     # stays [1, m] end to end -- no stack_wmh([q] * P)-style restacking, no
@@ -78,14 +79,14 @@ def run(fast: bool = False):
             for _ in range(P)]
     corpus = SketchCorpus(m=mc, seed=1)
     _, us = timed(lambda: corpus.add_batch(lake))
-    emit("perf/corpus/ingest", us / P, f"tables={P} m={mc} interpret=True")
+    emit("perf/corpus/ingest", us / P, f"tables={P} m={mc}")
 
     query = sparse_pair(rng, n=600, nnz=120, overlap=0.2)[0]
     fq, vq, nq, _ = corpus.sketch_query(query)
     corpus.estimate(fq, vq, nq[0]).block_until_ready()      # warm the jit
     dev, us = timed(lambda: corpus.estimate(fq, vq, nq[0]).block_until_ready(),
                     repeat=3)
-    emit("perf/corpus/query_1vN", us / P, f"tables={P} m={mc} interpret=True")
+    emit("perf/corpus/query_1vN", us / P, f"tables={P} m={mc}")
 
     # cross-check: device one-vs-many vs host ICWS estimator on *identical*
     # sketches (the host path is the oracle, and may restack freely)
@@ -184,7 +185,7 @@ def run(fast: bool = False):
         build_rows[name] = bt_B / best
         emit(f"perf/ingest/build_rows_per_s/{name}", build_rows[name],
              f"rows={bt_B} nnz~{bt_nnz} storage={bt_storage:.0f} "
-             f"(icws m=64) interpret=True")
+             f"(icws m=64)")
     build_speedup = build_rows["dmh"] / build_rows["icws"]
     emit("perf/ingest/dmh_vs_icws_build_speedup", build_speedup,
          f"x; dmh rows/s over icws rows/s at nnz~{bt_nnz}, "
@@ -327,7 +328,7 @@ def run(fast: bool = False):
             best = min(best, time.perf_counter() - t0)
         emit(f"perf/family/qps/{name}", best / f_Q * 1e6,
              f"batched qps={f_Q / best:.2f} tables={f_tables} m={f_m} "
-             f"storage-matched interpret=True")
+             f"storage-matched")
 
     # parallel lake build: shard-and-merge vs single-stream.  The deployment
     # this simulates: the lake lives key-partitioned across k producer
@@ -553,7 +554,7 @@ def run(fast: bool = False):
             t_u = min(t_u, time.perf_counter() - t0)
         emit(f"perf/scale/packed_scan_qps/{name}", sc_Q / t_p,
              f"batched packed-corpus scan; tables={sc_tables} m={sc_m} "
-             f"unpacked qps={sc_Q / t_u:.2f} interpret=True")
+             f"unpacked qps={sc_Q / t_u:.2f}")
 
     # roofline-autotuned block sizes vs the declared defaults, on the fused
     # multi-field estimate kernel the serving path launches.  The committed
@@ -578,7 +579,7 @@ def run(fast: bool = False):
     def fields_launch(blocks):
         return estimate_fields_pallas(
             at_fq, at_vq, at_fc, at_vc, qmap=at_qmap, cmap=at_cmap,
-            **blocks)[0].block_until_ready()
+            interpret=ops._interpret(), **blocks)[0].block_until_ready()
 
     # interleaved median-of-5 (2 in the fast lane): default and tuned
     # launches alternate inside one loop so a contention burst hits both
@@ -602,7 +603,7 @@ def run(fast: bool = False):
     n_pairs_at = len(at_qmap) * at_Q * at_P
     emit("perf/autotune/default_pairs_per_s", n_pairs_at / t_def,
          f"fused fields kernel, default blocks; G=6 Q={at_Q} P={at_P} "
-         f"m={at_m} interpret=True median-of-{at_reps}")
+         f"m={at_m} median-of-{at_reps}")
     if tuned:
         t_tun = h_tun.quantile(0.5)
         emit("perf/autotune/tuned_pairs_per_s", n_pairs_at / t_tun,

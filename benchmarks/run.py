@@ -1,6 +1,7 @@
 """Benchmark driver: one module per paper table/figure + theory + perf.
 
-Prints ``name,us_per_call,derived`` CSV rows (see benchmarks.common.emit).
+Prints ``name,us_per_call,derived,platform`` CSV rows (see
+benchmarks.common.emit); every row names the platform it ran on.
 ``--fast`` shrinks trial counts for CI; the default sizes reproduce the
 paper's qualitative results.  ``--json PATH`` additionally dumps every
 emitted row as a JSON artifact (CI uploads ``BENCH_sketch.json`` from the
@@ -23,6 +24,8 @@ def main() -> None:
                     help="write emitted rows as a JSON benchmark artifact")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (common, fig4_synthetic, fig5_worldbank, fig6_newsgroups,
                    perf_sketch, table7_overlap, theory_check)
     suites = {
@@ -44,7 +47,7 @@ def main() -> None:
                  f"(choose from: {', '.join(suites)})")
     if not only:
         ap.error(f"--only selected no suites (choose from: {', '.join(suites)})")
-    print("name,us_per_call,derived")
+    print("name,us_per_call,derived,platform")
     t0 = time.time()
     durations = {}
     failures = {}

@@ -1,12 +1,11 @@
 """CB* compat-boundary checker.
 
-``repro/compat.py`` is the single module allowed to spell version-gated
-jax APIs: ``shard_map`` moved from ``jax.experimental.shard_map`` (0.4.x,
-``check_rep``) to ``jax.shard_map`` (0.7.x, ``check_vma``), and
-``jax.sharding.AxisType`` / ``jax.make_mesh(axis_types=...)`` do not exist
-on the 0.4.x floor the CI matrix pins.  A direct use anywhere else breaks
-one side of the matrix silently until that job runs; these rules catch it
-at lint time.  CB004 additionally pins the ``interpret=True`` dispatch
+``repro/compat.py`` is the single module allowed to spell the jax mesh
+APIs that have moved between jax releases: ``shard_map`` (once
+``jax.experimental.shard_map`` with ``check_rep``, now ``jax.shard_map``
+with ``check_vma``), ``jax.sharding.AxisType`` and ``jax.make_mesh(
+axis_types=...)``.  Keeping every spelling there means the next move is a
+one-module change; these rules catch a direct use at lint time.  CB004 additionally pins the ``interpret=True`` dispatch
 convention: kernels decide interpret-vs-TPU at runtime through
 ``repro.kernels.ops._interpret()``, so a hardcoded ``interpret=True`` call
 site under ``src/`` would pin a production path to the emulator.

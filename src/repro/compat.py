@@ -1,66 +1,37 @@
-"""jax version compatibility shims.
+"""The jax mesh / shard_map spellings, in one module.
 
-The distributed substrate targets two jax API generations:
-
-  * jax >= 0.5-ish: ``jax.shard_map`` (kwarg ``check_vma``) and
-    ``jax.make_mesh(..., axis_types=(jax.sharding.AxisType.Auto, ...))``.
-  * jax 0.4.x (this container ships 0.4.37): ``shard_map`` lives at
-    ``jax.experimental.shard_map.shard_map`` (kwarg ``check_rep``),
-    ``jax.make_mesh`` exists but takes no ``axis_types``, and
-    ``jax.sharding.AxisType`` does not exist at all.
-
-Everything in-repo (``launch/mesh.py``, examples, the subprocess scripts in
-``tests/test_substrate.py``) goes through these wrappers instead of touching
-the version-specific spellings directly.
+The repo runs on one jax installation (0.9), where ``jax.shard_map`` takes
+``check_vma`` and ``jax.make_mesh`` takes ``axis_types``.  Everything
+in-repo (``launch/mesh.py``, ``kernels/ops.py``, examples, the subprocess
+scripts in ``tests/test_substrate.py``) goes through these wrappers, so a
+future API move changes this module only (rules CB001-CB003 of
+``python -m repro.analysis`` keep the spellings here).
 """
 from __future__ import annotations
 
-import inspect
 from typing import Sequence
 
 import jax
-
-try:  # jax >= 0.5-ish
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # jax 0.4.x
-    AxisType = None
-
-HAS_AXIS_TYPES = AxisType is not None
+from jax.sharding import AxisType
 
 
 def auto_axis_types(n: int):
-    """``(AxisType.Auto,) * n`` where supported, else None (0.4.x default)."""
-    if HAS_AXIS_TYPES:
-        return (AxisType.Auto,) * n
-    return None
+    """``(AxisType.Auto,) * n``."""
+    return (AxisType.Auto,) * n
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               axis_types=None, devices=None):
-    """``jax.make_mesh`` that drops ``axis_types`` where unsupported."""
+    """``jax.make_mesh``; ``axis_types``/``devices`` pass through when given."""
     kwargs = {}
     if devices is not None:
         kwargs["devices"] = devices
-    if axis_types is not None and HAS_AXIS_TYPES and (
-            "axis_types" in inspect.signature(jax.make_mesh).parameters):
+    if axis_types is not None:
         kwargs["axis_types"] = axis_types
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
 
 
-if hasattr(jax, "shard_map"):  # jax >= 0.5-ish
-    _shard_map_impl = jax.shard_map
-else:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-# the replication-check kwarg was renamed check_rep -> check_vma around the
-# time shard_map was promoted to the top level, but not atomically with it --
-# probe the signature instead of keying off the import location
-_CHECK_KWARG = ("check_vma"
-                if "check_vma" in inspect.signature(_shard_map_impl).parameters
-                else "check_rep")
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """Uniform ``shard_map``; ``check`` maps to check_vma / check_rep."""
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_CHECK_KWARG: check})
+    """``jax.shard_map``; ``check`` maps to ``check_vma``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

@@ -19,10 +19,22 @@ and no stack-for-batching duplicate).  Every device query, single or
 batched, is sketched by one ``[3Q, N]`` ICWS kernel launch and answered by
 ONE fused multi-field many-vs-many estimate launch
 (:func:`repro.kernels.ops.icws_estimate_fields`) straight off the store
-buffers; a single query is simply the Q=1 case.  Candidate ranking (top-k
-by |sketch-estimated corr| among sufficiently-joinable tables) happens in
-jnp before any result leaves the device; the host then refines the
-correlation of just those k candidates from the matched KMV samples.
+buffers; a single query is simply the Q=1 case.  Candidate selection (the
+``CANDIDATES`` best tables by evidence-weighted |sketch-estimated corr|
+among sufficiently-joinable ones) happens in jnp before any result leaves
+the device; the host then refines the correlation of just those
+candidates from the matched KMV samples, in one vectorized pass, and keeps
+the top-k.
+
+The value and squared-value fields are sketched over values centred on
+their table's mean, rounded to a multiple of the largest power of two at
+or below their standard deviation (so integer data stays integer and
+shard sums stay exact).  Correlation is shift-invariant, and the
+five-estimate correlation cancels catastrophically when a table's level
+dwarfs its spread (``join * sum(v^2) - sum(v)^2`` of two nearly equal,
+noisy estimates); centred fields keep that difference at the size of the
+spread.  Served sums add the shift back: ``sum_b = <1[K_A], V_B - c_B> +
+c_B * |K_A join K_B|``.
 
 Sharded serving: construct the index with a ``mesh`` whose corpus axis (see
 :func:`repro.distributed.sharding.corpus_axis`, logical axis ``"corpus"``,
@@ -30,8 +42,8 @@ by default the ``data`` mesh axis) spans 2+ devices, and the fused estimate
 launch runs per shard over corpus rows under ``repro.compat.shard_map``
 with queries replicated, followed by a per-shard top-k and a global merge.
 Rankings are bitwise identical to the single-device path: per-row estimate
-math is independent of the row count, and the top-k merge preserves
-``jax.lax.top_k`` tie order (ascending index).
+math is independent of the row count, and both top-k paths break score
+ties by ascending index (:func:`repro.kernels.ops.top_k`).
 
 Oracle path (``backend="host"``): the original host-numpy WMH implementation,
 kept verbatim as the cross-checked reference for the device path.  Every §1.3
@@ -45,7 +57,6 @@ statistic falls out of inner-product estimates:
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -71,6 +82,15 @@ _IND, _VAL, _SQ = 0, 1, 2
 QFIELD = (_IND, _VAL, _IND, _SQ, _IND, _VAL)
 CFIELD = (_IND, _IND, _VAL, _IND, _SQ, _VAL)
 
+# Candidates per query the device hands the host to re-rank by sample
+# correlation (at least top_k).  The device score only preselects, so a
+# truly correlated table may sit a few places below the top-k there; the
+# host pass costs time linear in the candidates.
+CANDIDATES = 32
+
+# KMV hashes are < 2^61; this pads a ragged stack of samples
+_HASH_PAD = np.iinfo(np.int64).max
+
 
 @dataclasses.dataclass
 class TableSketch:
@@ -81,6 +101,7 @@ class TableSketch:
     sample: KMVSketch            # KMV keyed sample of (key -> value): the
                                  # correlation sketch of Santos et al. 2021
     n_rows: int
+    value_shift: float = 0.0     # the centring shift of the value fields
 
 
 @dataclasses.dataclass
@@ -95,12 +116,21 @@ class SearchResult:
 
 @jax.jit
 def _corr_scores(join, sum_a, sum_b, sum_a2, sum_b2, prod, min_join):
-    """Ranking scores: |sketch-estimated corr| among joinable rows.
+    """Candidate scores: |sketch-estimated corr| among joinable rows,
+    weighted by the evidence behind it, ``join / (join + min_join)``.
 
     All inputs are [Q, P] device arrays of inner-product estimates.  Rows
-    failing ``join >= min_join`` score -1 so the host can drop them.  One
-    jitted executable serves both the single-device and the sharded ranking
-    path, so scores are bitwise identical between them.
+    failing ``join >= min_join`` score -1 so the host can drop them.  The
+    five-estimate correlation is noisy where few sketch samples collide:
+    over shared hot key domains (days, zip codes) many tables pass
+    ``min_join`` on one or two collisions, and their estimated |corr| is
+    often clipped to 1, above truly correlated tables with far larger
+    joins.  The weight halves a correlation measured over a join of
+    ``min_join`` rows and leaves one over a much larger join nearly
+    whole.  A non-positive ``min_join`` sets no evidence scale: weight 1.
+    The host re-ranks the survivors by their sample correlation.  One
+    jitted executable serves both the single-device and the sharded
+    ranking path, so scores are bitwise identical between them.
     """
     var_a = join * sum_a2 - sum_a * sum_a
     var_b = join * sum_b2 - sum_b * sum_b
@@ -109,14 +139,9 @@ def _corr_scores(join, sum_a, sum_b, sum_a2, sum_b2, prod, min_join):
     corr = jnp.where(ok, cov * jax.lax.rsqrt(jnp.where(ok, var_a * var_b, 1.0)),
                      0.0)
     corr = jnp.clip(corr, -1.0, 1.0)
-    return jnp.where(join >= min_join, jnp.abs(corr), -1.0)
-
-
-@functools.partial(jax.jit, static_argnames=("k",))
-def _top_k(score, k: int):
-    """Top-k scores + indices per query row; (scores [Q, k], idx [Q, k]) is
-    the only data that leaves the device."""
-    return jax.lax.top_k(score, k)
+    keep = join >= min_join
+    weight = jnp.where(min_join > 0, join / (join + min_join), 1.0)
+    return jnp.where(keep, jnp.abs(corr) * weight, -1.0)
 
 
 class DatasetSearchIndex:
@@ -164,9 +189,9 @@ class DatasetSearchIndex:
         # store resolves the corpus axis, shards its buffers over it, and
         # keeps capacity divisible by the shard count
         # packed=True stores the corpus in the family's bit-packed wire
-        # layout and serves queries through the unpack-in-kernel estimate
-        # launches; rankings equal an unpacked index over bf16-roundtripped
-        # rows bit for bit (see repro.data.store.CorpusStore)
+        # layout, which the estimate launches decode in-kernel; rankings
+        # equal an unpacked index over bf16-roundtripped rows bit for bit
+        # (see repro.data.store.CorpusStore)
         self.packed = bool(packed)
         self.store: Optional[CorpusStore] = (
             CorpusStore(family=self.family, fields=len(FIELDS), mesh=mesh,
@@ -204,18 +229,39 @@ class DatasetSearchIndex:
                                    self.key_space)
         return ind, val, sq
 
+    def served_vectors(self, keys: np.ndarray, values: np.ndarray):
+        """The field vectors the sketches hold: :meth:`vectorize` of the
+        values minus a centring shift, plus the raw aggregated values (the
+        KMV sample's input) and the shift.  The shift is the mean rounded
+        to a multiple of the largest power of two at or below the standard
+        deviation: within half a deviation of the mean, and an integer
+        whenever the deviation is at least 1, so integer data stays exact.
+        Returns ``((ind, val, sq), raw, shift)``."""
+        values = np.asarray(values, dtype=np.float64)
+        shift = 0.0
+        if values.size:
+            std = float(values.std())
+            step = 2.0 ** np.floor(np.log2(std)) if std > 0 else 1.0
+            shift = float(np.round(values.mean() / step) * step)
+        ind, val, sq = self.vectorize(keys, values - shift)
+        raw = val.values + shift * ind.values     # same sorted keys
+        raw = SparseVec(indices=val.indices, n=val.n,
+                        values=np.where(raw == 0.0, 1e-9, raw))
+        return (ind, val, sq), raw, shift
+
     def add_table(self, name: str, keys: np.ndarray, values: np.ndarray,
                   tenant: Optional[str] = None):
         """Sketch one table into the corpus; ``tenant`` scopes it to a
         logical corpus inside the shared arena (see :meth:`query`)."""
-        ind, val, sq = self.vectorize(keys, values)
+        (ind, val, sq), raw, shift = self.served_vectors(keys, values)
         if self.store is not None:
             # device path: one [3, N] kernel launch sketches all three
             # fields; the rows append in place into the canonical store
             with _obs.family_context(self.family.name):
                 comps = self.family.sketch_rows([ind, val, sq])
                 self.store.append(*(c[:, None] for c in comps), tenant=tenant)
-        self._register_table(name, keys, ind, val, sq, tenant=tenant)
+        self._register_table(name, keys, (ind, val, sq), raw, shift,
+                             tenant=tenant)
 
     def add_tables_sharded(self, tables: Sequence[Tuple[str, np.ndarray,
                                                         np.ndarray]],
@@ -242,24 +288,20 @@ class DatasetSearchIndex:
         tables = list(tables)
         if not tables:
             return
-        rows, metas = [], []
-        for name, keys, values in tables:
-            ind, val, sq = self.vectorize(keys, values)
-            rows.append((ind, val, sq))
-            metas.append((name, keys, ind, val, sq))
+        metas = [(name, keys) + self.served_vectors(keys, values)
+                 for name, keys, values in tables]
         with _obs.family_context(self.family.name):
-            merged = build_sharded(rows, family=self.family, shards=shards)
+            merged = build_sharded([m[2] for m in metas], family=self.family,
+                                   shards=shards)
             self.store.append(*merged.field_arrays(), tenant=tenant)
-        for name, keys, ind, val, sq in metas:
-            self._register_table(name, keys, ind, val, sq, tenant=tenant)
+        for meta in metas:
+            self._register_table(*meta, tenant=tenant)
 
-    def _register_table(self, name, keys, ind, val, sq,
+    def _register_table(self, name, keys, fields, raw, shift,
                         tenant: Optional[str] = None):
         host = {}
         if self.keep_host_oracle:
-            host = {"key_indicator": self.sketcher.sketch(ind),
-                    "values": self.sketcher.sketch(val),
-                    "values_sq": self.sketcher.sketch(sq)}
+            host = {f: self.sketcher.sketch(v) for f, v in zip(FIELDS, fields)}
         if tenant is not None:
             self._tenant_tables.setdefault(str(tenant), []).append(
                 len(self.tables))
@@ -268,8 +310,8 @@ class DatasetSearchIndex:
             key_indicator=host.get("key_indicator"),
             values=host.get("values"),
             values_sq=host.get("values_sq"),
-            sample=self.kmv.sketch(val),
-            n_rows=len(keys)))
+            sample=self.kmv.sketch(raw),
+            n_rows=len(keys), value_shift=shift))
 
     # -- tenancy -------------------------------------------------------------
     def tenants(self) -> Tuple[str, ...]:
@@ -312,27 +354,36 @@ class DatasetSearchIndex:
                 tenant=tenant)[0]
 
     def _assemble_results(self, scores, idx, join_h, sum_b_h, q_sample,
-                          n_q: int, tables: Optional[List[TableSketch]] = None
+                          n_q: int, top_k: int,
+                          tables: Optional[List[TableSketch]] = None
                           ) -> List[SearchResult]:
         """Host epilogue shared by all device paths: drop min_join failures,
-        refine corr from the matched KMV samples, re-rank the k survivors
-        by refined |corr|.  ``tables`` is the candidate list the estimate
-        columns (and ``idx``) index into -- the full corpus by default, a
-        tenant's subset under tenant-scoped queries."""
+        refine corr from the matched KMV samples, keep the ``top_k``
+        candidates by refined |corr|.  ``tables`` is the candidate list the
+        estimate columns (and ``idx``) index into -- the full corpus by
+        default, a tenant's subset under tenant-scoped queries."""
         if tables is None:
             tables = self.tables
+        idx = np.asarray(idx)[np.asarray(scores) >= 0]   # min_join passed
+        cand = [tables[int(i)] for i in idx]
+        return self._rank(cand, self._sample_corrs(q_sample, cand),
+                          join_h[idx], sum_b_h[idx], n_q, top_k)
+
+    @staticmethod
+    def _rank(cand: List[TableSketch], corr, join, sum_b_c, n_q: int,
+              top_k: int) -> List[SearchResult]:
+        """Results for ``cand``, best ``top_k`` by |corr| (stable).  ``join``
+        and ``sum_b_c`` are the estimates ``<1[K_A], 1[K_B]>`` and ``<1[K_A],
+        V_B - c_B>``; the sum adds the shift ``c_B`` back."""
         results = []
-        for score, i in zip(scores, idx):
-            if score < 0:                    # failed the min_join filter
-                continue
-            t = tables[int(i)]
-            js = max(float(join_h[i]), 0.0)
-            mean_b = float(sum_b_h[i]) / js if js > 0 else 0.0
-            corr = self._sample_corr(q_sample, t.sample)
+        for i in np.argsort(-np.abs(corr), kind="stable")[:top_k]:
+            t = cand[i]
+            js = max(float(join[i]), 0.0)
+            sum_b = float(sum_b_c[i]) + t.value_shift * float(join[i])
             results.append(SearchResult(
                 name=t.name, join_size=js, joinability=js / n_q,
-                sum_b=float(sum_b_h[i]), mean_b=mean_b, corr=corr))
-        results.sort(key=lambda r: abs(r.corr), reverse=True)
+                sum_b=sum_b, mean_b=sum_b / js if js > 0 else 0.0,
+                corr=float(corr[i])))
         return results
 
     # -- batched queries -----------------------------------------------------
@@ -364,19 +415,12 @@ class DatasetSearchIndex:
                                             tenant=tenant)
 
     def _estimate(self, qcomps, cbufs):
-        """The fused single-device fields launch, routed to the packed
-        (unpack-in-kernel) twin when the store holds the packed layout."""
-        if self.packed:
-            return self.family.estimate_fields_packed(
-                qcomps, cbufs, qmap=QFIELD, cmap=CFIELD)
+        """The fused single-device fields launch (unpacked or packed store
+        buffers alike: a packed value plane decodes in-kernel)."""
         return self.family.estimate_fields(qcomps, cbufs,
                                            qmap=QFIELD, cmap=CFIELD)
 
     def _estimate_sharded(self, qcomps, cbufs):
-        if self.packed:
-            return self.family.estimate_fields_packed_sharded(
-                qcomps, cbufs, qmap=QFIELD, cmap=CFIELD, mesh=self.mesh,
-                axis=self._corpus_axis)
         return self.family.estimate_fields_sharded(
             qcomps, cbufs, qmap=QFIELD, cmap=CFIELD, mesh=self.mesh,
             axis=self._corpus_axis)
@@ -391,9 +435,9 @@ class DatasetSearchIndex:
         field_vecs: List[SparseVec] = []
         samples: List[KMVSketch] = []
         for keys, values in queries:
-            ind, val, sq = self.vectorize(keys, values)
-            field_vecs.extend((ind, val, sq))
-            samples.append(self.kmv.sketch(val))
+            fields, raw, _ = self.served_vectors(keys, values)
+            field_vecs.extend(fields)
+            samples.append(self.kmv.sketch(raw))
         # one kernel launch sketches all 3Q query field vectors; each
         # component reshapes [3Q, ...] -> [3, Q, ...] for the fields launch
         qcomps = tuple(
@@ -429,10 +473,10 @@ class DatasetSearchIndex:
                     est = self._estimate(qcomps, cbufs)
                 est = est[:, :, jnp.asarray(self.store.tenant_rows(tenant))]
             est = est[:, :, :P]
-            k = min(top_k, P)
+            k = min(max(top_k, CANDIDATES), P)
             score = _corr_scores(est[0], est[1], est[2], est[3], est[4],
                                  est[5], jnp.float32(min_join))
-            scores, idx = _top_k(score, k)
+            scores, idx = ops.top_k(score, k)
         else:
             if self._corpus_axis is not None:
                 est = self._estimate_sharded(qcomps, cbufs)    # [6, Q, cap]
@@ -441,21 +485,21 @@ class DatasetSearchIndex:
             P = len(self.tables)
             est = est[:, :, :P]
 
-            k = min(top_k, P)
+            k = min(max(top_k, CANDIDATES), P)
             score = _corr_scores(est[0], est[1], est[2], est[3], est[4],
                                  est[5], jnp.float32(min_join))
             if self._corpus_axis is not None:
                 scores, idx = ops.sharded_top_k(score, k, mesh=self.mesh,
                                                 axis=self._corpus_axis)
             else:
-                scores, idx = _top_k(score, k)
+                scores, idx = ops.top_k(score, k)
         scores, idx = np.asarray(scores), np.asarray(idx)
         join_h, sum_b_h = np.asarray(est[0]), np.asarray(est[2])
         return [
             self._assemble_results(scores[qi], idx[qi], join_h[qi],
                                    sum_b_h[qi], samples[qi],
                                    n_q=max(len(queries[qi][0]), 1),
-                                   tables=tables)
+                                   top_k=top_k, tables=tables)
             for qi in range(Q)]
 
     # -- host oracle (the original numpy implementation, cross-checked) -----
@@ -475,9 +519,8 @@ class DatasetSearchIndex:
         if not self.keep_host_oracle or self.tables[0].key_indicator is None:
             raise ValueError("host oracle sketches were not kept at ingest "
                              "(keep_host_oracle=False)")
-        ind, val, sq = self.vectorize(keys, values)
+        (ind, _, _), raw, _ = self.served_vectors(keys, values)
         q_ind = self.sketcher.sketch(ind)
-        q_sample = self.kmv.sketch(val)
         tables = self._tenant_table_list(tenant)
         P = len(tables)
 
@@ -487,46 +530,61 @@ class DatasetSearchIndex:
                 A, stack_wmh([getattr(t, field) for t in tables]))
 
         join = est(q_ind, "key_indicator")                  # <1A, 1B>
-        sum_b = est(q_ind, "values")                        # <1A, VB>
+        sum_b = est(q_ind, "values")                        # <1A, VB - cB>
+        keep = np.flatnonzero(np.maximum(join, 0.0) >= min_join)
+        cand = [tables[i] for i in keep]
+        corr = self._sample_corrs(self.kmv.sketch(raw), cand)
+        return self._rank(cand, corr, join[keep], sum_b[keep],
+                          max(len(keys), 1), top_k)
 
-        results = []
-        for i, t in enumerate(tables):
-            js = max(join[i], 0.0)
-            if js < min_join:
-                continue
-            mean_b = sum_b[i] / js if js > 0 else 0.0
-            corr = self._sample_corr(q_sample, t.sample)
-            results.append(SearchResult(
-                name=t.name, join_size=float(js),
-                joinability=float(js / max(len(keys), 1)),
-                sum_b=float(sum_b[i]), mean_b=float(mean_b), corr=corr))
-        results.sort(key=lambda r: abs(r.corr), reverse=True)
-        return results[:top_k]
-
-    def _sample_corr(self, sa: KMVSketch, sb: KMVSketch,
-                     min_pairs: int = 8) -> float:
-        """Sample Pearson correlation over the join, from matched KMV samples
-        (Santos et al. 2021 correlation sketches).
+    def _sample_corrs(self, sa: KMVSketch, cand: Sequence[TableSketch],
+                      min_pairs: int = 8) -> np.ndarray:
+        """Sample Pearson correlation over the join of the query with each
+        candidate, from matched KMV samples (Santos et al. 2021 correlation
+        sketches); 0 where fewer than ``min_pairs`` samples match or either
+        side is constant.
 
         Matched hashes within the k smallest of the union form a uniform
         sample of joined rows; the *sample* correlation sidesteps the
         catastrophic moment cancellation that estimated E[x^2]-E[x]^2
         suffers under sketch noise.  The device path uses the (noisier)
         five-inner-product corr only to *select* candidates on device; this
-        refines the k survivors.
+        refines the survivors, all candidates in one vectorized pass over a
+        ``[C, k]`` stack of their samples.
         """
-        if sa.hashes.size == 0 or sb.hashes.size == 0:
-            return 0.0
-        union_h = np.union1d(sa.hashes, sb.hashes)
-        kk = min(self.kmv.k, union_h.size)
-        tau = union_h[kk - 1]
-        common, ia, ib = np.intersect1d(sa.hashes, sb.hashes,
-                                        return_indices=True)
-        keep = common <= tau
-        va, vb = sa.values[ia[keep]], sb.values[ib[keep]]
-        if va.size < min_pairs or va.std() == 0 or vb.std() == 0:
-            return 0.0
-        return float(np.clip(np.corrcoef(va, vb)[0, 1], -1.0, 1.0))
+        C = len(cand)
+        ha = sa.hashes
+        if C == 0 or ha.size == 0:
+            return np.zeros(C)
+        lens = np.array([t.sample.hashes.size for t in cand])
+        width = max(int(lens.max()), 1)
+        live = np.arange(width) < lens[:, None]
+        hb = np.full((C, width), _HASH_PAD, np.int64)
+        vb = np.zeros((C, width))
+        hb[live] = np.concatenate([t.sample.hashes for t in cand])
+        vb[live] = np.concatenate([t.sample.values for t in cand])
+        # tau: the kk-th smallest distinct hash of each union
+        both = np.sort(np.concatenate(
+            [np.broadcast_to(ha, (C, ha.size)), hb], axis=1), axis=1)
+        new = np.ones(both.shape, bool)
+        new[:, 1:] = both[:, 1:] != both[:, :-1]
+        distinct = np.cumsum(new & (both != _HASH_PAD), axis=1)
+        kk = np.minimum(self.kmv.k, distinct[:, -1])
+        tau = both[np.arange(C), np.argmax(distinct >= kk[:, None], axis=1)]
+        # matched hashes at or below tau, with their query values
+        pos = np.minimum(np.searchsorted(ha, hb), ha.size - 1)
+        match = live & (ha[pos] == hb) & (hb <= tau[:, None])
+        va = sa.values[pos]
+        n = match.sum(axis=1)
+        nz = np.maximum(n, 1)
+        da = np.where(match, va - (va * match).sum(1, keepdims=True)
+                      / nz[:, None], 0.0)
+        db = np.where(match, vb - (vb * match).sum(1, keepdims=True)
+                      / nz[:, None], 0.0)
+        ssa, ssb = (da * da).sum(1), (db * db).sum(1)
+        ok = (n >= min_pairs) & (ssa > 0) & (ssb > 0)
+        corr = (da * db).sum(1) / np.sqrt(np.where(ok, ssa * ssb, 1.0))
+        return np.where(ok, np.clip(corr, -1.0, 1.0), 0.0)
 
     def storage_doubles(self) -> float:
         """Serving-sketch storage (three fields per table, paper accounting)."""

@@ -73,14 +73,6 @@ from .ingest import (dmh_sketch_batch, pad_linear_batch, pad_sample_batch,
                      sketch_batch)
 
 
-def _pad_last(x: jnp.ndarray, n: int, value=0) -> jnp.ndarray:
-    """Pad the last dim by ``n`` elements of ``value`` (0 -> unchanged)."""
-    if not n:
-        return x
-    widths = [(0, 0)] * (x.ndim - 1) + [(0, n)]
-    return jnp.pad(x, widths, constant_values=value)
-
-
 @dataclasses.dataclass(frozen=True)
 class ComponentSpec:
     """One per-row buffer of a family's corpus layout.
@@ -151,23 +143,20 @@ class ICWSFamily:
     @property
     def packed_components(self) -> Tuple[ComponentSpec, ...]:
         """Packed wire format: fingerprints stay full i32 lanes (31-bit
-        exact-match state), values pack two bf16 halfwords per i32 word
-        (odd m gains one inert pad slot), and the argkeys merge sidecar is
+        exact-match state), values are a bf16 plane (truncated, see
+        :mod:`repro.kernels.packed`), and the argkeys merge sidecar is
         dropped -- packed corpora are frozen serving state, 6m + 4 bytes
         per row vs 12m + 4 unpacked (50%)."""
-        me = self.m + (self.m % 2)
-        return (ComponentSpec("fingerprints", (me,), jnp.int32,
+        return (ComponentSpec("fingerprints", (self.m,), jnp.int32,
                               CORPUS_PAD_FP),
-                ComponentSpec("packed_values", (me // 2,), jnp.int32, 0.0),
+                ComponentSpec("packed_values", (self.m,), jnp.bfloat16, 0.0),
                 ComponentSpec("norms", (), jnp.float32, 0.0))
 
     def pack_rows(self, rows):
         """(fp, val, norm[, argkey]) -> packed components, any leading dims.
         Values are bf16-truncated (exact thereafter); argkeys are dropped."""
-        fp = _pad_last(jnp.asarray(rows[0]).astype(jnp.int32), self.m % 2,
-                       CORPUS_PAD_FP)
-        val = _pad_last(jnp.asarray(rows[1]).astype(jnp.float32), self.m % 2)
-        return (fp, pack_halfwords_f32(val),
+        return (jnp.asarray(rows[0]).astype(jnp.int32),
+                pack_halfwords_f32(rows[1]),
                 jnp.asarray(rows[2]).astype(jnp.float32))
 
     def unpack_rows(self, rows):
@@ -176,24 +165,8 @@ class ICWSFamily:
         back zeroed: packed rows are frozen and cannot re-enter the merge
         path."""
         fp, w, norm = (jnp.asarray(x) for x in rows)
-        val = unpack_halfwords_f32(w)[..., :self.m]
-        return (fp[..., :self.m].astype(jnp.int32), val,
-                norm.astype(jnp.float32),
-                jnp.zeros(fp.shape[:-1] + (self.m,), jnp.int32))
-
-    def estimate_fields_packed(self, q, c, *, qmap, cmap):
-        fq, vq, nq = q[0], q[1], q[2]
-        fpc, wc, nc = c[0], c[1], c[2]
-        return ops.icws_estimate_fields_packed(fq, vq, nq, fpc, wc, nc,
-                                               qmap=qmap, cmap=cmap)
-
-    def estimate_fields_packed_sharded(self, q, c, *, qmap, cmap, mesh,
-                                       axis):
-        fq, vq, nq = q[0], q[1], q[2]
-        fpc, wc, nc = c[0], c[1], c[2]
-        return ops.icws_estimate_fields_packed_sharded(
-            fq, vq, nq, fpc, wc, nc, qmap=qmap, cmap=cmap, mesh=mesh,
-            axis=axis)
+        return (fp.astype(jnp.int32), unpack_halfwords_f32(w),
+                norm.astype(jnp.float32), jnp.zeros(fp.shape, jnp.int32))
 
     def merge_rows(self, a, b):
         """Coordinated per-slot min-merge of row-aligned ICWS components.
@@ -407,30 +380,17 @@ class _LinearFamily:
 
     @property
     def packed_components(self) -> Tuple[ComponentSpec, ...]:
-        """Packed wire format: every table cell bf16-truncated, two cells
-        per i32 word (odd widths gain one zero column) -- half the
+        """Packed wire format: every table cell bf16-truncated -- half the
         unpacked ``[R, W]`` f32 bytes.  Zero-fill stays inert: the zero
-        word decodes to a zero table."""
-        we = self.width + (self.width % 2)
-        return (ComponentSpec("packed_tables", (self.reps, we // 2),
-                              jnp.int32, 0.0),)
+        halfword decodes to a zero table."""
+        return (ComponentSpec("packed_tables", (self.reps, self.width),
+                              jnp.bfloat16, 0.0),)
 
     def pack_rows(self, rows):
-        t = _pad_last(jnp.asarray(rows[0]).astype(jnp.float32),
-                      self.width % 2)
-        return (pack_halfwords_f32(t),)
+        return (pack_halfwords_f32(rows[0]),)
 
     def unpack_rows(self, rows):
-        return (unpack_halfwords_f32(jnp.asarray(rows[0]))[..., :self.width],)
-
-    def estimate_fields_packed(self, q, c, *, qmap, cmap):
-        return ops.linear_estimate_fields_packed(q[0], c[0], qmap=qmap,
-                                                 cmap=cmap)
-
-    def estimate_fields_packed_sharded(self, q, c, *, qmap, cmap, mesh,
-                                       axis):
-        return ops.linear_estimate_fields_packed_sharded(
-            q[0], c[0], qmap=qmap, cmap=cmap, mesh=mesh, axis=axis)
+        return (unpack_halfwords_f32(rows[0]),)
 
     def merge_rows(self, a, b):
         """Exact merge by linearity: ``S(x + y) = S(x) + S(y)`` -- the
@@ -537,41 +497,23 @@ class _SamplingFamily:
     def packed_components(self) -> Tuple[ComponentSpec, ...]:
         """Packed wire format: sample keys stay full i32 lanes (31-bit
         exact-match state -- the information floor of this layout), values
-        pack two bf16 halfwords per i32 word (odd slot counts gain one
-        inert pad slot), taus stay f32: 6S + 4 bytes per row vs 8S + 4
+        are a bf16 plane, taus stay f32: 6S + 4 bytes per row vs 8S + 4
         unpacked (75%)."""
-        se = self.slots + (self.slots % 2)
-        return (ComponentSpec("keys", (se,), jnp.int32, CORPUS_PAD_FP),
-                ComponentSpec("packed_values", (se // 2,), jnp.int32, 0.0),
+        return (ComponentSpec("keys", (self.slots,), jnp.int32,
+                              CORPUS_PAD_FP),
+                ComponentSpec("packed_values", (self.slots,), jnp.bfloat16,
+                              0.0),
                 ComponentSpec("taus", (), jnp.float32, 0.0))
 
     def pack_rows(self, rows):
-        k = _pad_last(jnp.asarray(rows[0]).astype(jnp.int32),
-                      self.slots % 2, CORPUS_PAD_FP)
-        v = _pad_last(jnp.asarray(rows[1]).astype(jnp.float32),
-                      self.slots % 2)
-        return (k, pack_halfwords_f32(v),
+        return (jnp.asarray(rows[0]).astype(jnp.int32),
+                pack_halfwords_f32(rows[1]),
                 jnp.asarray(rows[2]).astype(jnp.float32))
 
     def unpack_rows(self, rows):
         k, w, t = (jnp.asarray(x) for x in rows)
-        return (k[..., :self.slots].astype(jnp.int32),
-                unpack_halfwords_f32(w)[..., :self.slots],
+        return (k.astype(jnp.int32), unpack_halfwords_f32(w),
                 t.astype(jnp.float32))
-
-    def estimate_fields_packed(self, q, c, *, qmap, cmap):
-        kq, vq, tq = q
-        kc, wc, tc = c
-        return ops.sample_estimate_fields_packed(kq, vq, tq, kc, wc, tc,
-                                                 qmap=qmap, cmap=cmap)
-
-    def estimate_fields_packed_sharded(self, q, c, *, qmap, cmap, mesh,
-                                       axis):
-        kq, vq, tq = q
-        kc, wc, tc = c
-        return ops.sample_estimate_fields_packed_sharded(
-            kq, vq, tq, kc, wc, tc, qmap=qmap, cmap=cmap, mesh=mesh,
-            axis=axis)
 
     def _merge_keep(self, live, h, vals, ta, tb):
         raise NotImplementedError

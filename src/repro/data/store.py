@@ -113,14 +113,15 @@ class CorpusStore:
 
     ``packed=True`` switches the resident buffers to the family's bit-packed
     wire layout (``family.packed_components``): sketch values are stored as
-    bf16 halfword pairs in int32 words and decoded *inside* the estimate
-    kernels, cutting resident bytes/row to ~50% (icws / linear) or ~75%
+    bf16 planes (truncated f32) and decoded *inside* the estimate kernels,
+    cutting resident bytes/row to ~50% (icws / linear) or ~75%
     (ts / ps, whose 31-bit exact-match keys are the information floor).
     ``append`` still takes ordinary unpacked sketch rows -- they are
     validated against the family's unpacked contract, then packed via
     ``family.pack_rows`` before the device write, so ingest call sites are
-    unchanged.  Query paths consume the packed buffers directly through
-    ``family.estimate_fields_packed``; rankings are bitwise identical to an
+    unchanged.  Query paths hand the packed buffers to the same
+    ``family.estimate_fields`` launches, which decode the bf16 plane
+    in-kernel; rankings are bitwise identical to an
     unpacked store holding the bf16-roundtripped rows.  Packed stores are
     frozen for merging: the ICWS packed layout drops the ``argkeys``
     re-leveling sidecar, so :func:`repro.data.merge.merge_stores` refuses

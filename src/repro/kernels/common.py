@@ -103,7 +103,10 @@ def uniform01(key: jnp.ndarray, salt) -> jnp.ndarray:
     Uses the top 24 bits => values in [2^-25, 1 - 2^-25]; logs are safe.
     """
     bits = hash_u32(key, salt) >> jnp.uint32(8)          # 24 random bits
-    return bits.astype(jnp.float32) * jnp.float32(2 ** -24) + jnp.float32(2 ** -25)
+    # through int32: exact for 24 bits, and the TPU compiler has no direct
+    # uint32 -> float32 conversion
+    return (bits.astype(jnp.int32).astype(jnp.float32) * jnp.float32(2 ** -24)
+            + jnp.float32(2 ** -25))
 
 
 def salt_for(seed: int, stream: int, t: jnp.ndarray) -> jnp.ndarray:

@@ -16,23 +16,22 @@ what converts the ICWS kernel's per-(lane x sample) hashing into per-lane
 hashing.  Each step draws the 5 uniforms on the ``[BR, BN]`` lane tile,
 masks one ``[BR, BM, BN]`` bin-equality cross for the per-bin argmin, and
 min-merges winners into the running blocks with strict ``<`` (earlier
-tiles win ties -- the oracle's first-index argmin order).  Winner payloads
-(key / level / value) are gathered from the lane tile, not one-hot
-reduced, so the cross tensor count stays ~3 against ICWS's ~6 at 1/m-th
-the draw work.
+tiles win ties -- the oracle's first-index argmin order).  The per-bin
+argmin is two min-reductions (the lowest lane attaining the bin minimum),
+and the winner payloads (key / level / value) are one-hot reduced.
 
-At the last non-zero step a densification epilogue runs entirely in VMEM
-(probes chunked 128 wide to bound temporaries), and with ``pack_vals=True``
-the bf16 pack epilogue mirrors the ICWS one.  The output wire layout is
-identical to ICWS -- ``(fp, val, amin, argkey)`` -- so every estimate /
-packed / sharded launch consumes DMH rows unchanged.
+Densification runs after the kernel, as a jnp epilogue shared with the
+scatter lowering (:func:`_densify`, probes chunked 128 wide to bound
+temporaries): borrowing is a gather across bins, which XLA does natively;
+``pack_vals=True`` packs the values after it, as for ICWS.  The output
+wire layout is identical to ICWS -- ``(fp, val, amin, argkey)`` -- so
+every estimate / packed / sharded launch consumes DMH rows unchanged.
 
 VMEM budget per step (f32): inputs ``3 * BR*BN`` + outputs ``4 * BR*BM`` +
-~3 ``[BR, BM, BN]`` cross temporaries; the epilogue adds ``[BR, BM, 128]``
-probe chunks.  Results are bitwise independent of BR and BN (global
-first-min per bin); BM only pads (inert bins, sliced off) and the probe
-budget is a pure function of m (:func:`repro.kernels.common.
-densify_probes`), never tuned.
+a few ``[BR, BM, BN]`` cross temporaries.  Results are bitwise independent
+of BR and BN (global first-min per bin); BM only pads (inert bins, sliced
+off) and the probe budget is a pure function of m
+(:func:`repro.kernels.common.densify_probes`), never tuned.
 """
 from __future__ import annotations
 
@@ -49,54 +48,69 @@ from .common import (DMH_BETA_STREAM, DMH_BIN_STREAM, DMH_C1_STREAM,
 from .packed import pack_halfwords_f32
 from .ref import BIG
 
-_PROBE_CHUNK = 128   # probe lanes materialized at once in the epilogue
+_PROBE_CHUNK = 128   # probes materialized at once by the densification
 
 
-def _densify(fp_ref, out_val_ref, amin_ref, out_key_ref, *, seed: int,
-             m_live: int, bm: int, jprobe: int):
-    """Fill empty bins from occupied ones (optimal densification).
+def _densify(fp, val, amin, key, *, seed: int, m: int):
+    """Fill empty bins from occupied ones (optimal densification), on
+    ``[B, m]`` per-bin minima.
 
-    Probes ``src = h(t; j) mod m`` for j = 0..jprobe-1; the first probe
-    landing on an occupied bin is the borrow source.  If every probe
-    misses, fall back to the first occupied bin (exact when exactly one
-    bin is occupied; coordinated regardless).  Rows with no occupied bin
-    at all are left untouched (the wrapper's empty fixup emits -1).
+    Probes ``src = h(t; j) mod m`` for j = 0..J-1 (J = ``densify_probes(m)``,
+    ``CHUNK`` probes materialized at a time); the first probe landing on an
+    occupied bin is the borrow source.  If every probe misses, fall back to
+    the first occupied bin (exact when exactly one bin is occupied;
+    coordinated regardless).  Rows with no occupied bin at all are left
+    untouched (the wrappers' empty fixup emits -1).  A gather-shaped jnp
+    epilogue shared by the Pallas and scatter lowerings, so both densify
+    identically.
     """
-    occ = amin_ref[:, :] < BIG                             # [BR, BM]
-    t = jax.lax.iota(jnp.int32, bm)
-    tu = t.astype(jnp.uint32)
+    occ = amin < BIG                                       # [B, m]
+    jprobe = densify_probes(m)
+    tu = jnp.arange(m, dtype=jnp.uint32)
     best_j = jnp.full(occ.shape, jprobe, jnp.int32)
     for j0 in range(0, jprobe, _PROBE_CHUNK):
-        js = j0 + jax.lax.iota(jnp.int32, _PROBE_CHUNK)
+        js = j0 + jnp.arange(_PROBE_CHUNK, dtype=jnp.int32)
         psalt = salt_for(seed, DMH_DENSIFY_STREAM, js)     # [CHUNK]
         src = (hash_u32(tu[:, None], psalt[None, :])
-               % jnp.uint32(m_live)).astype(jnp.int32)     # [BM, CHUNK]
-        hit = jnp.take(occ, src, axis=1)                   # [BR, BM, CHUNK]
+               % jnp.uint32(m)).astype(jnp.int32)          # [m, CHUNK]
+        hit = jnp.take(occ, src, axis=1)                   # [B, m, CHUNK]
         found = jnp.any(hit, axis=2)
         firstj = j0 + jnp.argmax(hit, axis=2).astype(jnp.int32)
         best_j = jnp.where((best_j == jprobe) & found, firstj, best_j)
     has = best_j < jprobe
     salt_w = salt_for(seed, DMH_DENSIFY_STREAM, jnp.where(has, best_j, 0))
     src_w = (hash_u32(tu[None, :], salt_w)
-             % jnp.uint32(m_live)).astype(jnp.int32)       # [BR, BM]
+             % jnp.uint32(m)).astype(jnp.int32)            # [B, m]
     fallback = jnp.argmax(occ, axis=1).astype(jnp.int32)[:, None]
     src_sel = jnp.where(has, src_w, fallback)
     need = (~occ) & jnp.any(occ, axis=1)[:, None]
 
-    for ref_ in (fp_ref, out_val_ref, out_key_ref, amin_ref):
-        cur = ref_[:, :]
-        ref_[:, :] = jnp.where(
-            need, jnp.take_along_axis(cur, src_sel, axis=1), cur)
+    def borrow(x):
+        return jnp.where(need, jnp.take_along_axis(x, src_sel, axis=1), x)
+
+    return borrow(fp), borrow(val), borrow(amin), borrow(key)
+
+
+def _finish(fp, val, amin, key, *, seed: int, m: int, pack_vals: bool):
+    """Densify, then the empty-row fixup (and the optional value pack)
+    shared by both lowerings."""
+    fp, val, amin, key = _densify(fp, val, amin, key, seed=seed, m=m)
+    empty = amin >= BIG
+    outs = (jnp.where(empty, -1, fp), jnp.where(empty, 0.0, val), amin,
+            jnp.where(empty, 0, key))
+    if pack_vals:
+        return outs + (pack_halfwords_f32(outs[1]),)
+    return outs
 
 
 def _dmh_kernel(w_ref, key_ref, val_ref, fp_ref, out_val_ref, amin_ref,
-                out_key_ref, *, seed: int, m_live: int, bm: int, bn: int,
-                n_steps: int, jprobe: int):
+                out_key_ref, *, seed: int, m_live: int, bm: int, bn: int):
     n_idx = pl.program_id(1)
 
     w = w_ref[:, :]                                        # [BR, BN]
     keys = key_ref[:, :]                                   # [BR, BN] int32
     vals = val_ref[:, :]                                   # [BR, BN]
+    br = w.shape[0]
     kk = keys.astype(jnp.uint32)
 
     bin_salt = salt_for(seed, DMH_BIN_STREAM, jnp.uint32(0))
@@ -116,20 +130,24 @@ def _dmh_kernel(w_ref, key_ref, val_ref, fp_ref, out_val_ref, amin_ref,
     a = c / (y * jnp.exp(r))
     a = jnp.where(w > 0, a, BIG)                           # mask padding
 
-    t = jax.lax.iota(jnp.int32, bm)
-    am = jnp.where(bins[:, None, :] == t[None, :, None],
-                   a[:, None, :], BIG)                     # [BR, BM, BN]
-    arg = jnp.argmin(am, axis=2)                           # [BR, BM]
-    amin = jnp.min(am, axis=2)
-    key_sel = jnp.take_along_axis(keys, arg, axis=1)       # [BR, BM]
-    lvl_sel = jnp.take_along_axis(lvl, arg, axis=1)
-    val_sel = jnp.take_along_axis(vals, arg, axis=1)
+    t3 = jax.lax.broadcasted_iota(jnp.int32, (1, bm, 1), 1)
+    am = jnp.where(bins[:, None, :] == t3, a[:, None, :], BIG)  # [BR, BM, BN]
+    # first-hit argmin per bin from two min-reductions, then one-hot
+    # payload selection (at most one lane per bin is selected)
+    amin = jnp.min(am, axis=2)                             # [BR, BM]
+    cols = jax.lax.broadcasted_iota(jnp.int32, (br, bm, bn), 2)
+    arg = jnp.min(jnp.where(am == amin[:, :, None], cols, bn), axis=2)
+    sel = cols == arg[:, :, None]
+    key_sel = jnp.sum(jnp.where(sel, keys[:, None, :], 0), axis=2)
+    lvl_sel = jnp.sum(jnp.where(sel, lvl[:, None, :], 0.0), axis=2)
+    val_sel = jnp.sum(jnp.where(sel, vals[:, None, :], 0.0), axis=2)
 
+    t2 = jax.lax.broadcasted_iota(jnp.int32, (1, bm), 1)
     fpbits = hash_u32(
         key_sel.astype(jnp.uint32)
         ^ (lvl_sel.astype(jnp.int32).astype(jnp.uint32)
            * jnp.uint32(0x9E3779B9)),
-        salt_for(seed, DMH_FP_STREAM, t)[None, :])
+        salt_for(seed, DMH_FP_STREAM, t2))
     # 31-bit fingerprint: non-negative int32 (see ref.dmh_sketch_ref)
     fp = (fpbits & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
 
@@ -148,39 +166,12 @@ def _dmh_kernel(w_ref, key_ref, val_ref, fp_ref, out_val_ref, amin_ref,
         out_val_ref[:, :] = jnp.where(better, val_sel, out_val_ref[:, :])
         out_key_ref[:, :] = jnp.where(better, key_sel, out_key_ref[:, :])
 
-    @pl.when(n_idx == n_steps - 1)
-    def _fill():
-        _densify(fp_ref, out_val_ref, amin_ref, out_key_ref, seed=seed,
-                 m_live=m_live, bm=bm, jprobe=jprobe)
-
-
-def _dmh_kernel_packed(w_ref, key_ref, val_ref, fp_ref, out_val_ref,
-                       amin_ref, out_key_ref, packed_ref, *, seed: int,
-                       m_live: int, bm: int, bn: int, n_steps: int,
-                       jprobe: int):
-    """The DMH kernel plus the bf16 pack-on-output epilogue (the ICWS
-    ``pack_vals`` epilogue, run after densification so borrowed bins pack
-    their borrowed values).  Bins beyond ``m_live`` and empty rows are
-    zeroed before packing, matching ``pack_rows``' zero pad bit for bit."""
-    _dmh_kernel(w_ref, key_ref, val_ref, fp_ref, out_val_ref, amin_ref,
-                out_key_ref, seed=seed, m_live=m_live, bm=bm, bn=bn,
-                n_steps=n_steps, jprobe=jprobe)
-    n_idx = pl.program_id(1)
-
-    @pl.when(n_idx == n_steps - 1)
-    def _pack():
-        t = jax.lax.iota(jnp.int32, bm)
-        v = out_val_ref[:, :]
-        v = jnp.where((t < m_live)[None, :], v, 0.0)
-        v = jnp.where(amin_ref[:, :] >= BIG, 0.0, v)
-        packed_ref[:, :] = pack_halfwords_f32(v)
-
 
 @functools.partial(jax.jit, static_argnames=("m", "seed", "br", "bm", "bn",
                                              "pack_vals", "interpret"))
-def dmh_sketch_pallas(w, keys, vals, *, m: int, seed: int, br: int = 1,
+def dmh_sketch_pallas(w, keys, vals, *, m: int, seed: int, br: int = 8,
                       bm: int = 128, bn: int = 256,
-                      pack_vals: bool = False, interpret: bool = True):
+                      pack_vals: bool = False, interpret: bool = False):
     """Batched DMH sketch via Pallas.  See :func:`repro.kernels.ref.dmh_sketch_ref`.
 
     Args: w/keys/vals [B, N] (padded here to ``br``/``bn`` multiples);
@@ -191,9 +182,8 @@ def dmh_sketch_pallas(w, keys, vals, *, m: int, seed: int, br: int = 1,
     one block (the bin state is VMEM-resident; there is no m grid axis);
     results are bitwise identical for every (br, bm, bn) choice.
 
-    With ``pack_vals=True`` a fifth output is appended: ``[B, (m + m % 2)
-    // 2]`` i32 bf16-halfword packed values, bitwise equal to
-    ``pack_halfwords_f32`` of the zero-padded ``val`` output.
+    With ``pack_vals=True`` a fifth output is appended: the ``[B, m]``
+    bf16 packed value plane, ``pack_halfwords_f32`` of the ``val`` output.
     """
     B, N = w.shape
     if bm % 128 or bm < m:
@@ -207,72 +197,25 @@ def dmh_sketch_pallas(w, keys, vals, *, m: int, seed: int, br: int = 1,
         vals = jnp.pad(vals, ((0, b_pad), (0, n_pad)))
     Bp, Np = w.shape
 
-    grid = (Bp // br, Np // bn)
-    jprobe = densify_probes(m)
-    kw = dict(seed=seed, m_live=m, bm=bm, bn=bn, n_steps=Np // bn,
-              jprobe=jprobe)
-    if pack_vals:
-        kernel = functools.partial(_dmh_kernel_packed, **kw)
-        fp, val, amin, key, packed = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((br, bn), lambda b, ni: (b, ni)),
-                pl.BlockSpec((br, bn), lambda b, ni: (b, ni)),
-                pl.BlockSpec((br, bn), lambda b, ni: (b, ni)),
-            ],
-            out_specs=[
-                pl.BlockSpec((br, bm), lambda b, ni: (b, 0)),
-                pl.BlockSpec((br, bm), lambda b, ni: (b, 0)),
-                pl.BlockSpec((br, bm), lambda b, ni: (b, 0)),
-                pl.BlockSpec((br, bm), lambda b, ni: (b, 0)),
-                pl.BlockSpec((br, bm // 2), lambda b, ni: (b, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((Bp, bm), jnp.int32),
-                jax.ShapeDtypeStruct((Bp, bm), jnp.float32),
-                jax.ShapeDtypeStruct((Bp, bm), jnp.float32),
-                jax.ShapeDtypeStruct((Bp, bm), jnp.int32),
-                jax.ShapeDtypeStruct((Bp, bm // 2), jnp.int32),
-            ],
-            interpret=interpret,
-        )(w.astype(jnp.float32), keys.astype(jnp.int32),
-          vals.astype(jnp.float32))
-    else:
-        kernel = functools.partial(_dmh_kernel, **kw)
-        fp, val, amin, key = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((br, bn), lambda b, ni: (b, ni)),
-                pl.BlockSpec((br, bn), lambda b, ni: (b, ni)),
-                pl.BlockSpec((br, bn), lambda b, ni: (b, ni)),
-            ],
-            out_specs=[
-                pl.BlockSpec((br, bm), lambda b, ni: (b, 0)),
-                pl.BlockSpec((br, bm), lambda b, ni: (b, 0)),
-                pl.BlockSpec((br, bm), lambda b, ni: (b, 0)),
-                pl.BlockSpec((br, bm), lambda b, ni: (b, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((Bp, bm), jnp.int32),
-                jax.ShapeDtypeStruct((Bp, bm), jnp.float32),
-                jax.ShapeDtypeStruct((Bp, bm), jnp.float32),
-                jax.ShapeDtypeStruct((Bp, bm), jnp.int32),
-            ],
-            interpret=interpret,
-        )(w.astype(jnp.float32), keys.astype(jnp.int32),
-          vals.astype(jnp.float32))
-        packed = None
+    kernel = functools.partial(_dmh_kernel, seed=seed, m_live=m, bm=bm,
+                               bn=bn)
+    fp, val, amin, key = pl.pallas_call(
+        kernel,
+        grid=(Bp // br, Np // bn),
+        in_specs=[pl.BlockSpec((br, bn), lambda b, ni: (b, ni))] * 3,
+        out_specs=[pl.BlockSpec((br, bm), lambda b, ni: (b, 0))] * 4,
+        out_shape=[
+            jax.ShapeDtypeStruct((Bp, bm), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, bm), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, bm), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, bm), jnp.int32),
+        ],
+        interpret=interpret,
+    )(w.astype(jnp.float32), keys.astype(jnp.int32),
+      vals.astype(jnp.float32))
 
-    fp, val, amin, key = fp[:B, :m], val[:B, :m], amin[:B, :m], key[:B, :m]
-    empty = amin >= BIG
-    outs = (jnp.where(empty, -1, fp), jnp.where(empty, 0.0, val), amin,
-            jnp.where(empty, 0, key))
-    if pack_vals:
-        me = m + (m % 2)
-        return outs + (packed[:B, :me // 2],)
-    return outs
+    return _finish(fp[:B, :m], val[:B, :m], amin[:B, :m], key[:B, :m],
+                   seed=seed, m=m, pack_vals=pack_vals)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "seed", "pack_vals"))
@@ -334,32 +277,5 @@ def dmh_sketch_scatter(w, keys, vals, *, m: int, seed: int,
         salt_for(seed, DMH_FP_STREAM, t)[None, :])
     fp = (fpbits & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
 
-    # densification epilogue, jnp twin of the in-kernel one
-    occ = amin < BIG                                          # [B, m]
-    J = densify_probes(m)
-    psalt = salt_for(seed, DMH_DENSIFY_STREAM, jnp.arange(J, dtype=jnp.int32))
-    src = (hash_u32(t[:, None].astype(jnp.uint32), psalt[None, :])
-           % jnp.uint32(m)).astype(jnp.int32)                 # [m, J]
-    occ_p = jnp.take(occ, src, axis=1)                        # [B, m, J]
-    has = jnp.any(occ_p, axis=2)
-    firstj = jnp.argmax(occ_p, axis=2).astype(jnp.int32)
-    src_w = (hash_u32(t.astype(jnp.uint32),
-                      salt_for(seed, DMH_DENSIFY_STREAM, firstj))
-             % jnp.uint32(m)).astype(jnp.int32)
-    fallback = jnp.argmax(occ, axis=1).astype(jnp.int32)[:, None]
-    src_sel = jnp.where(has, src_w, fallback)
-    need = (~occ) & jnp.any(occ, axis=1)[:, None]
-
-    def borrow(x):
-        return jnp.where(need, jnp.take_along_axis(x, src_sel, axis=1), x)
-
-    fp, val_sel, key_sel, amin = (borrow(fp), borrow(val_sel),
-                                  borrow(key_sel), borrow(amin))
-    empty = amin >= BIG
-    outs = (jnp.where(empty, -1, fp), jnp.where(empty, 0.0, val_sel), amin,
-            jnp.where(empty, 0, key_sel))
-    if pack_vals:
-        me = m + (m % 2)
-        padded = jnp.pad(outs[1], ((0, 0), (0, me - m)))
-        return outs + (pack_halfwords_f32(padded),)
-    return outs
+    return _finish(fp, val_sel, amin, key_sel, seed=seed, m=m,
+                   pack_vals=pack_vals)

@@ -21,7 +21,11 @@ Four variants share the kernel math:
     above: query/corpus sketches arrive stacked per *field* (``[F, Q, m]`` /
     ``[C, P, m]``) and a static list of (query-field, corpus-field) pairs is
     folded into the leading grid dimension, so e.g. all six §1.3 field-pair
-    estimates of a dataset-search batch run as a single kernel launch.
+    estimates of a dataset-search batch run as a single kernel launch.  The
+    served kernel: a packed store's bf16 value plane runs through it too.
+
+``linear_estimate_fields_pallas`` serves the linear families (CS/JL): per-rep
+MXU dots of the stacked tables, the same pair folding.
 
 Grids keep the m dimension innermost and accumulate into per-(row[, col])
 output blocks.  Pure VPU elementwise + reduction; one pass over the sketches,
@@ -35,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .packed import unpack_halfwords_f32
 
 # Pad sentinels -- the single definition of the padding convention every
 # estimate variant (and the corpus store / sharded wrappers) relies on:
@@ -44,6 +47,30 @@ from .packed import unpack_halfwords_f32
 # guard ``fq >= 0`` keeps both out of the estimate.
 QUERY_PAD_FP = -1
 CORPUS_PAD_FP = -2
+
+
+def _lut(table):
+    """Static python-int lookup expressed as select arithmetic: index maps
+    may not capture traced constants, only combine grid indices with
+    python scalars."""
+    def sel(g):
+        idx = table[0]
+        for i, v in enumerate(table[1:], start=1):
+            idx = jnp.where(g == i, v, idx)
+        return idx
+    return sel
+
+
+def _check_maps(qmap, cmap, F, C):
+    qmap = tuple(int(i) for i in qmap)
+    cmap = tuple(int(i) for i in cmap)
+    if len(qmap) != len(cmap):
+        raise ValueError("qmap/cmap length mismatch")
+    if not qmap:
+        raise ValueError("qmap/cmap must name at least one field pair")
+    if min(qmap) < 0 or max(qmap) >= F or min(cmap) < 0 or max(cmap) >= C:
+        raise ValueError("field map index out of range")
+    return qmap, cmap
 
 
 def _est_kernel(fpa_ref, va_ref, fpb_ref, vb_ref, cnt_ref, sw_ref):
@@ -71,7 +98,7 @@ def _est_kernel(fpa_ref, va_ref, fpb_ref, vb_ref, cnt_ref, sw_ref):
 
 @functools.partial(jax.jit, static_argnames=("bp", "bm", "interpret"))
 def estimate_partials_pallas(fpa, va, fpb, vb, *, bp: int = 8, bm: int = 128,
-                             interpret: bool = True):
+                             interpret: bool = False):
     """Matches :func:`repro.kernels.ref.estimate_partials_ref`."""
     P, m = fpa.shape
     p_pad = (-P) % bp
@@ -97,7 +124,7 @@ def estimate_partials_pallas(fpa, va, fpb, vb, *, bp: int = 8, bm: int = 128,
 
 @functools.partial(jax.jit, static_argnames=("bp", "bm", "interpret"))
 def estimate_one_vs_many_pallas(fq, vq, fpc, vc, *, bp: int = 64, bm: int = 128,
-                                interpret: bool = True):
+                                interpret: bool = False):
     """One query sketch against a P-row corpus; matches
     :func:`repro.kernels.ref.estimate_one_vs_many_ref`.
 
@@ -169,7 +196,7 @@ def _mvm_kernel(fq_ref, vq_ref, fc_ref, vc_ref, cnt_ref, sw_ref):
                    static_argnames=("bq", "bp", "bm", "interpret"))
 def estimate_many_vs_many_pallas(fq, vq, fpc, vc, *, bq: int = 8,
                                  bp: int = 128, bm: int = 128,
-                                 interpret: bool = True):
+                                 interpret: bool = False):
     """Q query sketches against a P-row corpus in one launch; matches
     :func:`repro.kernels.ref.estimate_many_vs_many_ref`.
 
@@ -214,8 +241,10 @@ def estimate_many_vs_many_pallas(fq, vq, fpc, vc, *, bq: int = 8,
 
 def _fields_kernel(fq_ref, vq_ref, fc_ref, vc_ref, cnt_ref, sw_ref):
     m_idx = pl.program_id(3)
-    cnt, sw = _mvm_body(fq_ref[0, :, :], vq_ref[0, :, :],
-                        fc_ref[0, :, :], vc_ref[0, :, :])
+    # a packed store's bf16 value tile decodes here, in VMEM (exact), to
+    # the same tile the unpacked path reads -- one reduction for both
+    cnt, sw = _mvm_body(fq_ref[0, :, :], vq_ref[0, :, :], fc_ref[0, :, :],
+                        vc_ref[0, :, :].astype(jnp.float32))
 
     @pl.when(m_idx == 0)
     def _init():
@@ -232,13 +261,15 @@ def _fields_kernel(fq_ref, vq_ref, fc_ref, vc_ref, cnt_ref, sw_ref):
                                              "interpret"))
 def estimate_fields_pallas(fq, vq, fpc, vc, *, qmap, cmap, bq: int = 8,
                            bp: int = 128, bm: int = 128,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """Fused multi-field many-vs-many partials in ONE kernel launch; matches
     :func:`repro.kernels.ref.estimate_fields_ref`.
 
     Args:
       fq/vq: [F, Q, m] per-field query sketches.
-      fpc/vc: [C, P, m] per-field corpus sketches.
+      fpc/vc: [C, P, m] per-field corpus sketches; ``vc`` is f32, or the
+        bf16 value plane of a packed store (:mod:`repro.kernels.packed`),
+        decoded tile by tile in VMEM -- the f32 plane never exists in HBM.
       qmap/cmap: static same-length tuples of field indices; estimate ``g``
         pairs query field ``qmap[g]`` with corpus field ``cmap[g]`` (§1.3
         uses six such pairs over F = C = 3 fields).
@@ -248,17 +279,10 @@ def estimate_fields_pallas(fq, vq, fpc, vc, *, qmap, cmap, bq: int = 8,
     corpus BlockSpec index maps gather the right field via a static lookup
     table, so no per-pair [Q, m] / [P, m] copies are ever stacked in HBM.
     """
-    qmap = tuple(int(i) for i in qmap)
-    cmap = tuple(int(i) for i in cmap)
-    if len(qmap) != len(cmap):
-        raise ValueError("qmap/cmap length mismatch")
-    if not qmap:
-        raise ValueError("qmap/cmap must name at least one field pair")
-    G = len(qmap)
     F, Q, m = fq.shape
     C, P, _ = fpc.shape
-    if min(qmap) < 0 or max(qmap) >= F or min(cmap) < 0 or max(cmap) >= C:
-        raise ValueError("field map index out of range")
+    qmap, cmap = _check_maps(qmap, cmap, F, C)
+    G = len(qmap)
     q_pad = (-Q) % bq
     p_pad = (-P) % bp
     m_pad = (-m) % bm
@@ -271,17 +295,6 @@ def estimate_fields_pallas(fq, vq, fpc, vc, *, qmap, cmap, bq: int = 8,
         vc = jnp.pad(vc, ((0, 0), (0, p_pad), (0, m_pad)))
     Qp, mp = fq.shape[1:]
     Pp = fpc.shape[1]
-
-    def _lut(table):
-        # static python-int lookup expressed as select arithmetic: index maps
-        # may not capture traced constants, only combine grid indices with
-        # python scalars
-        def sel(g):
-            idx = table[0]
-            for i, v in enumerate(table[1:], start=1):
-                idx = jnp.where(g == i, v, idx)
-            return idx
-        return sel
 
     qsel, csel = _lut(qmap), _lut(cmap)
     grid = (G, Qp // bq, Pp // bp, mp // bm)
@@ -299,166 +312,71 @@ def estimate_fields_pallas(fq, vq, fpc, vc, *, qmap, cmap, bq: int = 8,
         out_shape=[jax.ShapeDtypeStruct((G, Qp, Pp), jnp.float32)] * 2,
         interpret=interpret,
     )(fq.astype(jnp.int32), vq.astype(jnp.float32),
-      fpc.astype(jnp.int32), vc.astype(jnp.float32))
-    return cnt[:, :Q, :P], sw[:, :Q, :P]
-
-
-def _fields_packed_kernel(fq_ref, vq_ref, fc_ref, wc_ref, cnt_ref, sw_ref):
-    m_idx = pl.program_id(3)
-    # decode the corpus value tile in VMEM: [bp, bm//2] i32 -> [bp, bm] f32.
-    # The decode is exact (bf16 -> f32), so the tile is bitwise equal to the
-    # unpacked-roundtripped corpus tile and _mvm_body reduces identically.
-    vc = unpack_halfwords_f32(wc_ref[0, :, :])
-    cnt, sw = _mvm_body(fq_ref[0, :, :], vq_ref[0, :, :], fc_ref[0, :, :], vc)
-
-    @pl.when(m_idx == 0)
-    def _init():
-        cnt_ref[0, :, :] = cnt
-        sw_ref[0, :, :] = sw
-
-    @pl.when(m_idx != 0)
-    def _acc():
-        cnt_ref[0, :, :] = cnt_ref[0, :, :] + cnt
-        sw_ref[0, :, :] = sw_ref[0, :, :] + sw
-
-
-@functools.partial(jax.jit, static_argnames=("qmap", "cmap", "bq", "bp", "bm",
-                                             "interpret"))
-def estimate_fields_packed_pallas(fq, vq, fpc, wc, *, qmap, cmap, bq: int = 8,
-                                  bp: int = 128, bm: int = 128,
-                                  interpret: bool = True):
-    """:func:`estimate_fields_pallas` over a bit-packed corpus value plane.
-
-    Identical contract except the corpus values arrive packed: ``wc`` is
-    ``[C, P, m // 2]`` i32 bf16-halfword words (see
-    :mod:`repro.kernels.packed`) instead of ``vc [C, P, m]`` f32, and the
-    kernel decodes each ``[bp, bm // 2]`` tile to ``[bp, bm]`` in VMEM --
-    the f32 plane never exists in HBM.  ``m`` and ``bm`` must be even
-    (odd-m families pad one inert sample at pack time).  Zero words decode
-    to value 0.0 and spare rows keep sentinel fingerprints, so the packed
-    layout inherits the inert-spare-row invariant unchanged.
-    """
-    qmap = tuple(int(i) for i in qmap)
-    cmap = tuple(int(i) for i in cmap)
-    if len(qmap) != len(cmap):
-        raise ValueError("qmap/cmap length mismatch")
-    if not qmap:
-        raise ValueError("qmap/cmap must name at least one field pair")
-    G = len(qmap)
-    F, Q, m = fq.shape
-    C, P, mw = wc.shape
-    if m % 2 or bm % 2:
-        raise ValueError(f"packed estimate needs even m and bm; got "
-                         f"m={m}, bm={bm}")
-    if fpc.shape[2] != m or 2 * mw != m:
-        raise ValueError(f"packed corpus {(fpc.shape[2], 2 * mw)} does not "
-                         f"match query m={m}")
-    if min(qmap) < 0 or max(qmap) >= F or min(cmap) < 0 or max(cmap) >= C:
-        raise ValueError("field map index out of range")
-    q_pad = (-Q) % bq
-    p_pad = (-P) % bp
-    m_pad = (-m) % bm           # even: m and bm are both even
-    if q_pad or m_pad:
-        fq = jnp.pad(fq, ((0, 0), (0, q_pad), (0, m_pad)),
-                     constant_values=QUERY_PAD_FP)
-        vq = jnp.pad(vq, ((0, 0), (0, q_pad), (0, m_pad)))
-    if p_pad or m_pad:
-        fpc = jnp.pad(fpc, ((0, 0), (0, p_pad), (0, m_pad)),
-                      constant_values=CORPUS_PAD_FP)
-        # zero words decode to value 0.0 -- the same inert fill the
-        # unpacked path pads vc with
-        wc = jnp.pad(wc, ((0, 0), (0, p_pad), (0, m_pad // 2)))
-    Qp, mp = fq.shape[1:]
-    Pp = fpc.shape[1]
-
-    def _lut(table):
-        # static lookup via select arithmetic, as estimate_fields_pallas
-        def sel(g):
-            idx = table[0]
-            for i, v in enumerate(table[1:], start=1):
-                idx = jnp.where(g == i, v, idx)
-            return idx
-        return sel
-
-    qsel, csel = _lut(qmap), _lut(cmap)
-    grid = (G, Qp // bq, Pp // bp, mp // bm)
-    cnt, sw = pl.pallas_call(
-        _fields_packed_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, bm), lambda g, q, p, mi: (qsel(g), q, mi)),
-            pl.BlockSpec((1, bq, bm), lambda g, q, p, mi: (qsel(g), q, mi)),
-            pl.BlockSpec((1, bp, bm), lambda g, q, p, mi: (csel(g), p, mi)),
-            pl.BlockSpec((1, bp, bm // 2),
-                         lambda g, q, p, mi: (csel(g), p, mi)),
-        ],
-        out_specs=[pl.BlockSpec((1, bq, bp),
-                                lambda g, q, p, mi: (g, q, p))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((G, Qp, Pp), jnp.float32)] * 2,
-        interpret=interpret,
-    )(fq.astype(jnp.int32), vq.astype(jnp.float32),
-      fpc.astype(jnp.int32), wc.astype(jnp.int32))
+      fpc.astype(jnp.int32), vc)
     return cnt[:, :Q, :P], sw[:, :Q, :P]
 
 
 # ---------------------------------------------------------------------------
 # Linear-family estimation: per-rep sketch dots as MXU matmuls
 # ---------------------------------------------------------------------------
+def _rep_dots(a, b, out_ref):
+    """``[BQ, R, BW] x [BP, R, BW] -> out_ref[0, r] += [BQ, BP]`` per rep,
+    at full f32 precision on the MXU."""
+    for r in range(a.shape[1]):
+        tile = jax.lax.dot_general(
+            a[:, r, :], b[:, r, :], (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)               # [BQ, BP]
+        out_ref[0, r, :, :] += tile
+
+
 def _linear_fields_kernel(tq_ref, tc_ref, out_ref):
-    w_idx = pl.program_id(3)
-    a = tq_ref[0, :, 0, :]                                    # [BQ, BW]
-    b = tc_ref[0, :, 0, :]                                    # [BP, BW]
-    tile = jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                   # [BQ, BP]
-
-    @pl.when(w_idx == 0)
+    @pl.when(pl.program_id(3) == 0)
     def _init():
-        out_ref[0, 0, :, :] = tile
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(w_idx != 0)
-    def _acc():
-        out_ref[0, 0, :, :] = out_ref[0, 0, :, :] + tile
+    # a packed store's bf16 tables decode here, in VMEM (exact)
+    _rep_dots(tq_ref[0], tc_ref[0].astype(jnp.float32), out_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("qmap", "cmap", "bq", "bp", "bw",
                                              "interpret"))
 def linear_estimate_fields_pallas(tq, tc, *, qmap, cmap, bq: int = 8,
-                                  bp: int = 128, bw: int = 128,
-                                  interpret: bool = True):
+                                  bp: int = 128, bw: int = 512,
+                                  interpret: bool = False):
     """Fused multi-field per-rep linear-sketch dots in ONE kernel launch;
     matches :func:`repro.kernels.ref.linear_estimate_fields_ref`.
 
     Args:
       tq: [F, Q, R, W] per-field query tables (JL: R = 1, W = m).
-      tc: [C, P, R, W] per-field corpus tables.
+      tc: [C, P, R, W] per-field corpus tables, f32 or the bf16 tables of a
+        packed store (decoded tile by tile in VMEM).
       qmap/cmap: static same-length tuples of field indices, exactly as
         :func:`estimate_fields_pallas`.
     Returns [G, R, Q, P] f32 per-rep inner products: each ``[BQ, BW] @
-    [BW, BP]`` tile is MXU work, accumulated over the (innermost) W grid
-    dimension.  The (pair, rep) axes fold into the leading grid dimension
-    the same way the ICWS fields kernel folds its pair list, so all G * R
-    dot matrices of a dataset-search batch run as a single launch.  The
-    median-of-reps (CS) / squeeze (JL) epilogue belongs to the caller.
+    [BW, BP]`` tile is MXU work (full f32 precision), accumulated over the
+    (innermost) W grid dimension; all R reps of a tile run in one grid step
+    (R is the full second-minor dim of the blocks).  The pair list folds
+    into the leading grid dimension the same way the ICWS fields kernel
+    folds it, so all G * R dot matrices of a dataset-search batch run as a
+    single launch.  The median-of-reps (CS) / squeeze (JL) epilogue belongs
+    to the caller.  A table no wider than ``bw`` is one block (a block that
+    spans the full array dim is legal on a TPU at any width, and needs no
+    corpus padding); wider tables tile by ``bw``, a lane multiple.
 
     Zero padding is inert everywhere: padded W lanes add 0 to every dot,
     and padded Q/P rows only produce extra output rows that are sliced off
     -- per-(q, p) results are bitwise independent of Q, P, and row padding.
     """
-    qmap = tuple(int(i) for i in qmap)
-    cmap = tuple(int(i) for i in cmap)
-    if len(qmap) != len(cmap):
-        raise ValueError("qmap/cmap length mismatch")
-    if not qmap:
-        raise ValueError("qmap/cmap must name at least one field pair")
-    G = len(qmap)
     F, Q, R, W = tq.shape
     C, P, Rc, Wc = tc.shape
+    qmap, cmap = _check_maps(qmap, cmap, F, C)
     if (R, W) != (Rc, Wc):
         raise ValueError(f"query tables {(R, W)} do not match corpus "
                          f"tables {(Rc, Wc)}")
-    if min(qmap) < 0 or max(qmap) >= F or min(cmap) < 0 or max(cmap) >= C:
-        raise ValueError("field map index out of range")
+    G = len(qmap)
+    if W <= bw:
+        bw = W
     q_pad = (-Q) % bq
     p_pad = (-P) % bp
     w_pad = (-W) % bw
@@ -467,114 +385,19 @@ def linear_estimate_fields_pallas(tq, tc, *, qmap, cmap, bq: int = 8,
     if p_pad or w_pad:
         tc = jnp.pad(tc, ((0, 0), (0, p_pad), (0, 0), (0, w_pad)))
     Qp, Pp, Wp = Q + q_pad, P + p_pad, W + w_pad
-
-    def _lut(table):
-        # static lookup via select arithmetic, as estimate_fields_pallas
-        def sel(g):
-            idx = table[0]
-            for i, v in enumerate(table[1:], start=1):
-                idx = jnp.where(g == i, v, idx)
-            return idx
-        return sel
-
     qsel, csel = _lut(qmap), _lut(cmap)
-    # (pair g, rep r) fold into the leading grid dim: gr = g * R + r
-    grid = (G * R, Qp // bq, Pp // bp, Wp // bw)
     out = pl.pallas_call(
         _linear_fields_kernel,
-        grid=grid,
+        grid=(G, Qp // bq, Pp // bp, Wp // bw),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, bw),
-                         lambda gr, q, p, wi: (qsel(gr // R), q, gr % R, wi)),
-            pl.BlockSpec((1, bp, 1, bw),
-                         lambda gr, q, p, wi: (csel(gr // R), p, gr % R, wi)),
+            pl.BlockSpec((1, bq, R, bw),
+                         lambda g, q, p, wi: (qsel(g), q, 0, wi)),
+            pl.BlockSpec((1, bp, R, bw),
+                         lambda g, q, p, wi: (csel(g), p, 0, wi)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, bp),
-                               lambda gr, q, p, wi: (gr // R, gr % R, q, p)),
+        out_specs=pl.BlockSpec((1, R, bq, bp),
+                               lambda g, q, p, wi: (g, 0, q, p)),
         out_shape=jax.ShapeDtypeStruct((G, R, Qp, Pp), jnp.float32),
         interpret=interpret,
-    )(tq.astype(jnp.float32), tc.astype(jnp.float32))
-    return out[:, :, :Q, :P]
-
-
-def _linear_fields_packed_kernel(tq_ref, wc_ref, out_ref):
-    w_idx = pl.program_id(3)
-    a = tq_ref[0, :, 0, :]                                    # [BQ, BW]
-    b = unpack_halfwords_f32(wc_ref[0, :, 0, :])              # [BP, BW]
-    tile = jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                   # [BQ, BP]
-
-    @pl.when(w_idx == 0)
-    def _init():
-        out_ref[0, 0, :, :] = tile
-
-    @pl.when(w_idx != 0)
-    def _acc():
-        out_ref[0, 0, :, :] = out_ref[0, 0, :, :] + tile
-
-
-@functools.partial(jax.jit, static_argnames=("qmap", "cmap", "bq", "bp", "bw",
-                                             "interpret"))
-def linear_estimate_fields_packed_pallas(tq, wc, *, qmap, cmap, bq: int = 8,
-                                         bp: int = 128, bw: int = 128,
-                                         interpret: bool = True):
-    """:func:`linear_estimate_fields_pallas` over bf16-halfword corpus tables.
-
-    ``wc`` is ``[C, P, R, W // 2]`` i32 packed words in place of the f32
-    ``tc [C, P, R, W]``; each corpus tile decodes in VMEM before the MXU
-    dot.  ``W`` and ``bw`` must be even (odd widths gain one zero column at
-    pack time -- inert under the dot, exactly like zero W-padding).
-    """
-    qmap = tuple(int(i) for i in qmap)
-    cmap = tuple(int(i) for i in cmap)
-    if len(qmap) != len(cmap):
-        raise ValueError("qmap/cmap length mismatch")
-    if not qmap:
-        raise ValueError("qmap/cmap must name at least one field pair")
-    G = len(qmap)
-    F, Q, R, W = tq.shape
-    C, P, Rc, Ww = wc.shape
-    if W % 2 or bw % 2:
-        raise ValueError(f"packed linear estimate needs even W and bw; got "
-                         f"W={W}, bw={bw}")
-    if (R, W) != (Rc, 2 * Ww):
-        raise ValueError(f"query tables {(R, W)} do not match packed corpus "
-                         f"tables {(Rc, 2 * Ww)}")
-    if min(qmap) < 0 or max(qmap) >= F or min(cmap) < 0 or max(cmap) >= C:
-        raise ValueError("field map index out of range")
-    q_pad = (-Q) % bq
-    p_pad = (-P) % bp
-    w_pad = (-W) % bw           # even: W and bw are both even
-    if q_pad or w_pad:
-        tq = jnp.pad(tq, ((0, 0), (0, q_pad), (0, 0), (0, w_pad)))
-    if p_pad or w_pad:
-        wc = jnp.pad(wc, ((0, 0), (0, p_pad), (0, 0), (0, w_pad // 2)))
-    Qp, Pp, Wp = Q + q_pad, P + p_pad, W + w_pad
-
-    def _lut(table):
-        # static lookup via select arithmetic, as estimate_fields_pallas
-        def sel(g):
-            idx = table[0]
-            for i, v in enumerate(table[1:], start=1):
-                idx = jnp.where(g == i, v, idx)
-            return idx
-        return sel
-
-    qsel, csel = _lut(qmap), _lut(cmap)
-    grid = (G * R, Qp // bq, Pp // bp, Wp // bw)
-    out = pl.pallas_call(
-        _linear_fields_packed_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, bw),
-                         lambda gr, q, p, wi: (qsel(gr // R), q, gr % R, wi)),
-            pl.BlockSpec((1, bp, 1, bw // 2),
-                         lambda gr, q, p, wi: (csel(gr // R), p, gr % R, wi)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, bp),
-                               lambda gr, q, p, wi: (gr // R, gr % R, q, p)),
-        out_shape=jax.ShapeDtypeStruct((G, R, Qp, Pp), jnp.float32),
-        interpret=interpret,
-    )(tq.astype(jnp.float32), wc.astype(jnp.int32))
+    )(tq.astype(jnp.float32), tc)
     return out[:, :, :Q, :P]

@@ -66,7 +66,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, kc: int, causal: bool,
 def flash_attention_pallas(q, k, v, *, group: int = 1, causal: bool = True,
                            window: int = 0, qc: int = 512, kc: int = 512,
                            q_offset: int = 0, k_offset: int = 0,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """q [BH, T, D]; k/v [BH//group, S, D].  Returns o [BH, T, D].
 
     ``group`` = GQA group size: q head i attends kv head i // group via the
@@ -96,7 +96,7 @@ def flash_attention_pallas(q, k, v, *, group: int = 1, causal: bool = True,
     )(q, k, v)
 
 
-def flash_attention(q, k, v, *, causal=True, window=0, interpret=True,
+def flash_attention(q, k, v, *, causal=True, window=0, interpret=False,
                     qc=512, kc=512):
     """Model-layout wrapper: q [B,T,H,D], k/v [B,S,K,D] -> [B,T,H,D]."""
     B, T, H, D = q.shape

@@ -8,18 +8,18 @@ uniform draws, two logs, one exp, one divide per lane, then a per-row argmin
 (value / fingerprint / min) with a strict ``<`` so earlier tiles win ties,
 matching ``jnp.argmin`` first-hit semantics in the oracle.
 
-``BR`` (row block, default 1) amortizes per-step costs across sketch rows:
-a single query sketches 3 field rows and cannot fill a row block, but the
-batched serving/ingest paths launch 3Q-row batches and sketch them with
-``BR`` rows per grid step.  Results are bitwise independent of all three
-block sizes (each row's winner is a global min with first-index ties).
+``BR`` (row block, default 8) amortizes per-step costs across sketch rows
+and is what the TPU tiling rule asks of the row dim: a single query's 3
+field rows pad to one 8-row block, and the batched serving/ingest paths
+launch 3Q-row batches.  Results are bitwise independent of all three block
+sizes (each row's winner is a global min with first-index ties; the argmin
+is two min-reductions, the lowest column attaining the minimum).
 
 VMEM budget per step (f32): inputs ``3 * BR*BN`` + intermediates
-``~6 * BR*BM*BN``.  Defaults BR=1, BM=128, BN=256 => ~800 KiB, comfortably
-under the ~16 MiB/core VMEM of TPU v5e; keep ``BR*BM*BN`` under ~128K lanes
-(~3 MiB per intermediate) when raising BR.  The lane dimension (BN=256) is a
-multiple of 128 as the VPU wants; there is no MXU work in this kernel -- it
-is VPU/transcendental bound, which is exactly why it beats the paper's
+``~6 * BR*BM*BN``.  At BR=8, BM=128, BN=256 one intermediate is 1 MiB,
+inside the v5e's scoped VMEM.  The lane dimension (BN=256) is a multiple of
+128 as the VPU wants; there is no MXU work in this kernel -- it is
+VPU/transcendental bound, which is exactly why it beats the paper's
 scalar "active index" loop on TPU.
 """
 from __future__ import annotations
@@ -45,13 +45,15 @@ def _icws_kernel(w_ref, key_ref, val_ref, fp_ref, out_val_ref, amin_ref,
     w = w_ref[:, :]                                   # [BR, BN]
     keys = key_ref[:, :]                              # [BR, BN] int32
     vals = val_ref[:, :]                              # [BR, BN]
+    br = w.shape[0]
 
-    t = m_idx * bm + jax.lax.iota(jnp.int32, bm)      # global sample ids [BM]
+    # global sample ids, as a [1, BM, 1] column and a [1, BM] row
+    t3 = m_idx * bm + jax.lax.broadcasted_iota(jnp.int32, (1, bm, 1), 1)
+    t2 = m_idx * bm + jax.lax.broadcasted_iota(jnp.int32, (1, bm), 1)
     kk = keys.astype(jnp.uint32)[:, None, :]          # [BR, 1, BN]
 
     def u(stream):
-        salt = salt_for(seed, stream, t)[None, :, None]   # [1, BM, 1]
-        return uniform01(kk, salt)                    # [BR, BM, BN]
+        return uniform01(kk, salt_for(seed, stream, t3))   # [BR, BM, BN]
 
     r = -jnp.log(u(ICWS_R1_STREAM) * u(ICWS_R2_STREAM))
     c = -jnp.log(u(ICWS_C1_STREAM) * u(ICWS_C2_STREAM))
@@ -62,10 +64,12 @@ def _icws_kernel(w_ref, key_ref, val_ref, fp_ref, out_val_ref, amin_ref,
     a = c / (y * jnp.exp(r))
     a = jnp.where((w > 0)[:, None, :], a, BIG)        # mask padding
 
-    arg = jnp.argmin(a, axis=2)                       # [BR, BM]
-    cols = jax.lax.iota(jnp.int32, bn)[None, None, :]
+    # first-hit argmin over the lane axis from two min-reductions: the
+    # lowest column attaining the row minimum
+    amin = jnp.min(a, axis=2)                         # [BR, BM]
+    cols = jax.lax.broadcasted_iota(jnp.int32, (br, bm, bn), 2)
+    arg = jnp.min(jnp.where(a == amin[:, :, None], cols, bn), axis=2)
     sel = cols == arg[:, :, None]                     # one-hot [BR, BM, BN]
-    amin = jnp.min(a, axis=2)
     key_sel = jnp.sum(jnp.where(sel, keys[:, None, :], 0), axis=2)
     lvl_sel = jnp.sum(jnp.where(sel, lvl, 0.0), axis=2)
     val_sel = jnp.sum(jnp.where(sel, vals[:, None, :], 0.0), axis=2)
@@ -73,7 +77,7 @@ def _icws_kernel(w_ref, key_ref, val_ref, fp_ref, out_val_ref, amin_ref,
     fpbits = hash_u32(
         key_sel.astype(jnp.uint32)
         ^ (lvl_sel.astype(jnp.int32).astype(jnp.uint32) * jnp.uint32(0x9E3779B9)),
-        salt_for(seed, ICWS_FP_STREAM, t)[None, :])
+        salt_for(seed, ICWS_FP_STREAM, t2))
     # 31-bit fingerprint: non-negative int32 (see ref.icws_sketch_ref)
     fp = (fpbits & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
 
@@ -93,37 +97,11 @@ def _icws_kernel(w_ref, key_ref, val_ref, fp_ref, out_val_ref, amin_ref,
         out_key_ref[:, :] = jnp.where(better, key_sel, out_key_ref[:, :])
 
 
-def _icws_kernel_packed(w_ref, key_ref, val_ref, fp_ref, out_val_ref,
-                        amin_ref, out_key_ref, packed_ref, *, seed: int,
-                        bm: int, bn: int, m_live: int, n_steps: int):
-    """The sketch kernel plus a pack-on-output epilogue: after the final
-    non-zero tile has merged, the per-row value block is bf16-halfword
-    packed in VMEM (see :mod:`repro.kernels.packed`) and written as a fifth
-    output -- the packed plane a packed :class:`CorpusStore` appends
-    directly, with no host-side re-pack of the f32 values.  Samples beyond
-    ``m_live`` (bm padding / the odd-m inert slot) and empty rows are
-    zeroed before packing, matching the host epilogue's empty fixup and
-    ``pack_rows``' zero pad bit for bit.
-    """
-    _icws_kernel(w_ref, key_ref, val_ref, fp_ref, out_val_ref, amin_ref,
-                 out_key_ref, seed=seed, bm=bm, bn=bn)
-    m_idx = pl.program_id(1)
-    n_idx = pl.program_id(2)
-
-    @pl.when(n_idx == n_steps - 1)
-    def _pack():
-        t = m_idx * bm + jax.lax.iota(jnp.int32, bm)
-        v = out_val_ref[:, :]
-        v = jnp.where((t < m_live)[None, :], v, 0.0)
-        v = jnp.where(amin_ref[:, :] >= BIG, 0.0, v)
-        packed_ref[:, :] = pack_halfwords_f32(v)
-
-
 @functools.partial(jax.jit, static_argnames=("m", "seed", "br", "bm", "bn",
                                              "pack_vals", "interpret"))
-def icws_sketch_pallas(w, keys, vals, *, m: int, seed: int, br: int = 1,
+def icws_sketch_pallas(w, keys, vals, *, m: int, seed: int, br: int = 8,
                        bm: int = 128, bn: int = 256,
-                       pack_vals: bool = False, interpret: bool = True):
+                       pack_vals: bool = False, interpret: bool = False):
     """Batched ICWS sketch via Pallas.  See :func:`repro.kernels.ref.icws_sketch_ref`.
 
     Args: w/keys/vals [B, N] (N padded to a multiple of ``bn`` by the caller
@@ -131,12 +109,13 @@ def icws_sketch_pallas(w, keys, vals, *, m: int, seed: int, br: int = 1,
     argkey [B, m] int32 -- the original vector index that won each sample,
     the sidecar the merge path re-levels from; 0 for empty inputs).
     ``br`` rows are sketched per grid step (pad rows are all-zero => empty);
-    results are bitwise identical for every (br, bm, bn) choice.
+    results are bitwise identical for every (br, bm, bn) choice.  On a TPU
+    ``br`` must be a multiple of 8 and ``bm``/``bn`` of 128 (the block
+    tiling rule).
 
-    With ``pack_vals=True`` (needs even ``bm``) a fifth output is appended:
-    ``[B, (m + m % 2) // 2]`` i32 bf16-halfword packed values, produced
-    in-kernel at the last non-zero grid step -- bitwise equal to
-    ``pack_halfwords_f32`` of the (zero-padded-to-even) ``val`` output.
+    With ``pack_vals=True`` a fifth output is appended: the ``[B, m]``
+    bf16 packed value plane, ``pack_halfwords_f32`` of the ``val`` output
+    (an elementwise epilogue on the device, after the kernel).
     """
     B, N = w.shape
     n_pad = (-N) % bn
@@ -149,69 +128,26 @@ def icws_sketch_pallas(w, keys, vals, *, m: int, seed: int, br: int = 1,
     mp = m + m_pad
     Bp, Np = w.shape
 
-    grid = (Bp // br, mp // bm, Np // bn)
-    if pack_vals:
-        if bm % 2:
-            raise ValueError(f"pack_vals needs an even bm; got bm={bm}")
-        kernel = functools.partial(_icws_kernel_packed, seed=seed, bm=bm,
-                                   bn=bn, m_live=m, n_steps=Np // bn)
-        fp, val, amin, key, packed = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((br, bn), lambda b, mi, ni: (b, ni)),
-                pl.BlockSpec((br, bn), lambda b, mi, ni: (b, ni)),
-                pl.BlockSpec((br, bn), lambda b, mi, ni: (b, ni)),
-            ],
-            out_specs=[
-                pl.BlockSpec((br, bm), lambda b, mi, ni: (b, mi)),
-                pl.BlockSpec((br, bm), lambda b, mi, ni: (b, mi)),
-                pl.BlockSpec((br, bm), lambda b, mi, ni: (b, mi)),
-                pl.BlockSpec((br, bm), lambda b, mi, ni: (b, mi)),
-                pl.BlockSpec((br, bm // 2), lambda b, mi, ni: (b, mi)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((Bp, mp), jnp.int32),
-                jax.ShapeDtypeStruct((Bp, mp), jnp.float32),
-                jax.ShapeDtypeStruct((Bp, mp), jnp.float32),
-                jax.ShapeDtypeStruct((Bp, mp), jnp.int32),
-                jax.ShapeDtypeStruct((Bp, mp // 2), jnp.int32),
-            ],
-            interpret=interpret,
-        )(w.astype(jnp.float32), keys.astype(jnp.int32),
-          vals.astype(jnp.float32))
-    else:
-        kernel = functools.partial(_icws_kernel, seed=seed, bm=bm, bn=bn)
-        fp, val, amin, key = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((br, bn), lambda b, mi, ni: (b, ni)),
-                pl.BlockSpec((br, bn), lambda b, mi, ni: (b, ni)),
-                pl.BlockSpec((br, bn), lambda b, mi, ni: (b, ni)),
-            ],
-            out_specs=[
-                pl.BlockSpec((br, bm), lambda b, mi, ni: (b, mi)),
-                pl.BlockSpec((br, bm), lambda b, mi, ni: (b, mi)),
-                pl.BlockSpec((br, bm), lambda b, mi, ni: (b, mi)),
-                pl.BlockSpec((br, bm), lambda b, mi, ni: (b, mi)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((Bp, mp), jnp.int32),
-                jax.ShapeDtypeStruct((Bp, mp), jnp.float32),
-                jax.ShapeDtypeStruct((Bp, mp), jnp.float32),
-                jax.ShapeDtypeStruct((Bp, mp), jnp.int32),
-            ],
-            interpret=interpret,
-        )(w.astype(jnp.float32), keys.astype(jnp.int32),
-          vals.astype(jnp.float32))
-        packed = None
+    kernel = functools.partial(_icws_kernel, seed=seed, bm=bm, bn=bn)
+    fp, val, amin, key = pl.pallas_call(
+        kernel,
+        grid=(Bp // br, mp // bm, Np // bn),
+        in_specs=[pl.BlockSpec((br, bn), lambda b, mi, ni: (b, ni))] * 3,
+        out_specs=[pl.BlockSpec((br, bm), lambda b, mi, ni: (b, mi))] * 4,
+        out_shape=[
+            jax.ShapeDtypeStruct((Bp, mp), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, mp), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, mp), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, mp), jnp.int32),
+        ],
+        interpret=interpret,
+    )(w.astype(jnp.float32), keys.astype(jnp.int32),
+      vals.astype(jnp.float32))
 
     fp, val, amin, key = fp[:B, :m], val[:B, :m], amin[:B, :m], key[:B, :m]
     empty = amin >= BIG
     outs = (jnp.where(empty, -1, fp), jnp.where(empty, 0.0, val), amin,
             jnp.where(empty, 0, key))
     if pack_vals:
-        me = m + (m % 2)
-        return outs + (packed[:B, :me // 2],)
+        return outs + (pack_halfwords_f32(outs[1]),)
     return outs

@@ -1,7 +1,7 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Dispatch policy: on TPU backends the compiled kernels run natively; on CPU
-(this container) they run under ``interpret=True`` -- same kernel body,
+they run under ``interpret=True`` -- same kernel body,
 executed by the Pallas interpreter -- and every op is validated against the
 pure-jnp oracles in :mod:`repro.kernels.ref`.
 """
@@ -19,22 +19,26 @@ from repro.roofline import autotune
 
 from . import ref
 from .countsketch import countsketch_pallas, countsketch_sparse_pallas
-from .estimate import (CORPUS_PAD_FP, QUERY_PAD_FP, estimate_fields_pallas,
-                       estimate_fields_packed_pallas,
+from .estimate import (CORPUS_PAD_FP, estimate_fields_pallas,
                        estimate_many_vs_many_pallas,
                        estimate_one_vs_many_pallas, estimate_partials_pallas,
-                       linear_estimate_fields_packed_pallas,
                        linear_estimate_fields_pallas)
 from .dmh_sketch import dmh_sketch_pallas, dmh_sketch_scatter
 from .icws_sketch import icws_sketch_pallas
 from .jl_sketch import jl_sketch_pallas
-from .sample_estimate import (sample_estimate_fields_packed_pallas,
-                              sample_estimate_fields_pallas,
+from .sample_estimate import (sample_estimate_fields_pallas,
                               sample_inclusion_probs)
 
 
+def _backend() -> str:
+    """The backend every launch below is built for -- the one place the
+    dispatch policy asks jax, so a compile test can aim the wrappers at a
+    described TPU from a CPU-only process."""
+    return jax.default_backend()
+
+
 def _interpret() -> bool:
-    interp = jax.default_backend() != "tpu"
+    interp = _backend() != "tpu"
     if _obs.enabled():
         _obs.gauge("ops.interpret_mode").set(float(interp))
     return interp
@@ -52,7 +56,7 @@ def _tuned(kernel: str, key, clamp):
     (batched/sequential, sharded/single-device, tenant, packed/unpacked)
     intact under tuning.
     """
-    blocks = autotune.resolve(kernel, jax.default_backend(), key, clamp=clamp)
+    blocks = autotune.resolve(kernel, _backend(), key, clamp=clamp)
     if _obs.enabled():
         _obs.counter("ops.autotune_resolved_total", kernel=kernel,
                      source="tuned" if blocks else "default").inc()
@@ -60,31 +64,27 @@ def _tuned(kernel: str, key, clamp):
 
 
 @_obs.instrumented("icws_sketch")
-def icws_sketch(w, keys, vals, *, m: int, seed: int = 0, row_block: int = 0,
+def icws_sketch(w, keys, vals, *, m: int, seed: int = 0,
                 pack_vals: bool = False):
     """Device ICWS sketch of padded sparse batch.
     [B,N] -> (fp, val, amin, argkey) [B,m].
 
-    ``row_block=0`` auto-picks: large batches (serving micro-batches, lake
-    ingest) sketch several rows per grid step; small/single-query launches
-    keep the minimal-VMEM one-row tiling; a tuned ``icws_sketch`` cache
-    entry (keyed by (m, N)) overrides both when present.  Results are
-    bitwise identical either way.  ``pack_vals=True`` appends the
-    bf16-halfword packed value plane ``[B, (m + m % 2) // 2]`` i32 as a
-    fifth output, packed in-kernel (see :func:`icws_sketch_pallas`).
+    Rows are sketched 8 per grid step (the TPU tiling rule's row block; a
+    single query's 3 field rows pad to one block) unless a tuned
+    ``icws_sketch`` cache entry (keyed by (m, N)) says otherwise.  Results
+    are bitwise identical either way.  ``pack_vals=True`` appends the
+    ``[B, m]`` bf16 packed value plane as a fifth output (see
+    :func:`icws_sketch_pallas`).
     """
-    if row_block == 0:
-        row_block = 4 if w.shape[0] >= 8 else 1
     blocks = _tuned("icws_sketch", {"m": m, "N": w.shape[1]},
                     {"br": (w.shape[0], 1)})
-    br = blocks.pop("br", row_block)
-    return icws_sketch_pallas(w, keys, vals, m=m, seed=seed, br=br,
+    return icws_sketch_pallas(w, keys, vals, m=m, seed=seed,
                               pack_vals=pack_vals, interpret=_interpret(),
                               **blocks)
 
 
 @_obs.instrumented("dmh_sketch")
-def dmh_sketch(w, keys, vals, *, m: int, seed: int = 0, row_block: int = 0,
+def dmh_sketch(w, keys, vals, *, m: int, seed: int = 0,
                pack_vals: bool = False):
     """Device DMH sketch of a padded sparse batch -- same signature and
     ``(fp, val, amin, argkey)`` wire layout as :func:`icws_sketch`, but
@@ -94,8 +94,8 @@ def dmh_sketch(w, keys, vals, *, m: int, seed: int = 0, row_block: int = 0,
 
     The VMEM bin-state width ``bm`` is fixed here to the lane-rounded
     sketch width -- it is a capacity, not a tuning knob, so the autotune
-    cache only carries (br, bn).  Results are bitwise identical across all
-    block choices.
+    cache only carries (br, bn); rows go 8 per grid step without an entry.
+    Results are bitwise identical across all block choices.
 
     Without a compiled Pallas backend the kernel's ``[br, bm, bn]``
     bin-equality cross (free across TPU VPU lanes) would be materialized
@@ -107,16 +107,12 @@ def dmh_sketch(w, keys, vals, *, m: int, seed: int = 0, row_block: int = 0,
     if _interpret():
         return dmh_sketch_scatter(w, keys, vals, m=m, seed=seed,
                                   pack_vals=pack_vals)
-    if row_block == 0:
-        row_block = 4 if w.shape[0] >= 8 else 1
     blocks = _tuned("dmh_sketch", {"m": m, "N": w.shape[1]},
                     {"br": (w.shape[0], 1)})
-    br = blocks.pop("br", row_block)
     blocks.pop("bm", None)
     bm = 128 * (-(-max(m, 1) // 128))
-    return dmh_sketch_pallas(w, keys, vals, m=m, seed=seed, br=br, bm=bm,
-                             pack_vals=pack_vals, interpret=_interpret(),
-                             **blocks)
+    return dmh_sketch_pallas(w, keys, vals, m=m, seed=seed, bm=bm,
+                             pack_vals=pack_vals, interpret=False, **blocks)
 
 
 @_obs.instrumented("countsketch")
@@ -245,7 +241,7 @@ def icws_estimate_many_stacked(fq, vq, nq, fpb, vb, nb):
     return icws_estimate_many(fq, vq, nq, fpb[0], vb[0], nb[0])
 
 
-@_obs.instrumented("linear_estimate_fields")
+@_obs.instrumented("linear_estimate_fields", packed_arg=1)
 @functools.partial(jax.jit, static_argnames=("qmap", "cmap"))
 def linear_estimate_fields(tq, tc, *, qmap, cmap):
     """Fused multi-field linear-sketch estimates: all field pairs, ONE launch.
@@ -265,13 +261,14 @@ def linear_estimate_fields(tq, tc, *, qmap, cmap):
     return jnp.median(dots, axis=1)
 
 
-@_obs.instrumented("icws_estimate_fields")
+@_obs.instrumented("icws_estimate_fields", packed_arg=4)
 @functools.partial(jax.jit, static_argnames=("qmap", "cmap"))
 def icws_estimate_fields(fq, vq, nq, fpc, vc, nc, *, qmap, cmap):
     """Fused multi-field ICWS estimates: all field pairs in ONE launch.
 
     Args: fq/vq [F, Q, m] per-field queries, nq [F, Q] norms; fpc/vc
-    [C, P, m] per-field corpus, nc [C, P] norms; qmap/cmap static length-G
+    [C, P, m] per-field corpus (vc f32, or a packed store's bf16 plane),
+    nc [C, P] norms; qmap/cmap static length-G
     field-pair maps.  Returns [G, Q, P] f32 estimates -- for §1.3 dataset
     search, the six estimate launches of the sequential path collapse into
     this single call.
@@ -286,106 +283,25 @@ def icws_estimate_fields(fq, vq, nq, fpc, vc, nc, *, qmap, cmap):
     return jnp.where((nqg == 0) | (ncg == 0), 0.0, est)
 
 
-@_obs.instrumented("sample_estimate_fields")
+@_obs.instrumented("sample_estimate_fields", packed_arg=4)
 @functools.partial(jax.jit, static_argnames=("qmap", "cmap"))
 def sample_estimate_fields(kq, vq, tq, kc, vc, tc, *, qmap, cmap):
     """Fused multi-field sampling-sketch (TS/PS) estimates, ONE launch.
 
     Args: kq/vq [F, Q, m] per-field query sample keys/values, tq [F, Q]
-    probability scales; kc/vc [C, P, m] / tc [C, P] corpus samples;
-    qmap/cmap static length-G field-pair maps.  Returns [G, Q, P] f32
-    inverse-inclusion-probability estimates from the key-match kernel --
-    the probabilities ``min(1, m * v^2 / tau)`` are reconstructed here
-    (elementwise prologue) so the stored layout stays (key, val, tau).
+    probability scales; kc/vc [C, P, m] / tc [C, P] corpus samples (vc f32
+    or a packed store's bf16 plane); qmap/cmap static length-G field-pair
+    maps.  Returns [G, Q, P] f32 inverse-inclusion-probability estimates
+    from the key-match kernel -- the query probabilities ``min(1, m * v^2 /
+    tau)`` are reconstructed here (elementwise prologue), the corpus ones
+    inside the kernel, so the stored layout stays (key, val, tau).
     """
     aq = sample_inclusion_probs(vq, tq)
-    ac = sample_inclusion_probs(vc, tc)
     blocks = _tuned("sample_estimate_fields", {"S": kq.shape[2]},
-                    {"bq": (kq.shape[1], 8), "bp": (kc.shape[1], 8)})
-    return sample_estimate_fields_pallas(kq, vq, aq, kc, vc, ac,
+                    {"bp": (kc.shape[1], 128)})
+    return sample_estimate_fields_pallas(kq, vq, aq, kc, vc, tc,
                                          qmap=qmap, cmap=cmap,
                                          interpret=_interpret(), **blocks)
-
-
-# ---------------------------------------------------------------------------
-# packed-corpus estimation: the store's bit-packed buffers, decoded in-kernel
-# ---------------------------------------------------------------------------
-# Each wrapper mirrors its unpacked twin exactly -- same epilogue, same true
-# sketch width in every formula -- with the corpus value plane arriving as
-# bf16-halfword words (see repro.kernels.packed).  Queries are sketched
-# fresh per request and stay unpacked; when the stored width gained an
-# inert pad slot (odd m rounded up to even at pack time), the query is
-# padded here with the standard sentinels, which the kernel guards already
-# treat as dead.  Block sizes resolve from the same autotune cache entries
-# as the unpacked path (widths even-normalized in the cache key), so packed
-# and unpacked launches always share a reduction order -- the packed
-# estimates are bitwise equal to the unpacked path run on
-# family.unpack_rows(family.pack_rows(rows)).
-
-@_obs.instrumented("icws_estimate_fields_packed")
-@functools.partial(jax.jit, static_argnames=("qmap", "cmap"))
-def icws_estimate_fields_packed(fq, vq, nq, fpc, wc, nc, *, qmap, cmap):
-    """Packed-corpus :func:`icws_estimate_fields`: fpc ``[C, P, me]`` i32
-    fingerprints, wc ``[C, P, me // 2]`` i32 packed values (me = m rounded
-    up to even), nc ``[C, P]`` norms.  Returns [G, Q, P] f32."""
-    m = fq.shape[2]
-    me = fpc.shape[2]
-    if me != m:
-        fq = jnp.pad(fq, ((0, 0), (0, 0), (0, me - m)),
-                     constant_values=QUERY_PAD_FP)
-        vq = jnp.pad(vq, ((0, 0), (0, 0), (0, me - m)))
-    blocks = _tuned("estimate_fields", {"m": me},
-                    {"bq": (fq.shape[1], 8), "bp": (fpc.shape[1], 128)})
-    cnt, sw = estimate_fields_packed_pallas(fq, vq, fpc, wc,
-                                            qmap=tuple(qmap),
-                                            cmap=tuple(cmap),
-                                            interpret=_interpret(), **blocks)
-    # epilogue over the TRUE sample count m, not the even-padded width:
-    # the pad slot never collides, so cnt/sw match the unpacked launch
-    j_hat = cnt / m
-    m_tilde = 2.0 / (1.0 + j_hat)
-    nqg = jnp.stack([nq[qf] for qf in qmap])[:, :, None]    # [G, Q, 1]
-    ncg = jnp.stack([nc[cf] for cf in cmap])[:, None, :]    # [G, 1, P]
-    est = nqg * ncg * (m_tilde / m) * sw
-    return jnp.where((nqg == 0) | (ncg == 0), 0.0, est)
-
-
-@_obs.instrumented("linear_estimate_fields_packed")
-@functools.partial(jax.jit, static_argnames=("qmap", "cmap"))
-def linear_estimate_fields_packed(tq, wc, *, qmap, cmap):
-    """Packed-corpus :func:`linear_estimate_fields`: wc ``[C, P, R,
-    We // 2]`` i32 packed tables (We = W rounded up to even).  The query
-    gains zero columns for the pad width -- inert under the dot."""
-    W = tq.shape[3]
-    We = 2 * wc.shape[3]
-    if We != W:
-        tq = jnp.pad(tq, ((0, 0), (0, 0), (0, 0), (0, We - W)))
-    blocks = _tuned("linear_estimate_fields", {"W": We},
-                    {"bq": (tq.shape[1], 8), "bp": (wc.shape[1], 128)})
-    dots = linear_estimate_fields_packed_pallas(tq, wc, qmap=qmap, cmap=cmap,
-                                                interpret=_interpret(),
-                                                **blocks)
-    return jnp.median(dots, axis=1)
-
-
-@_obs.instrumented("sample_estimate_fields_packed")
-@functools.partial(jax.jit, static_argnames=("qmap", "cmap"))
-def sample_estimate_fields_packed(kq, vq, tq, kc, wc, tc, *, qmap, cmap):
-    """Packed-corpus :func:`sample_estimate_fields`: kc ``[C, P, Se]`` i32
-    keys, wc ``[C, P, Se // 2]`` i32 packed values (Se = slots rounded up
-    to even), tc ``[C, P]`` taus.  Corpus inclusion probabilities are
-    recomputed in-kernel from the decoded tile and tau, with the TRUE slot
-    count (the query's) in the formula -- the pad slot decodes to value 0
-    and lands on probability 0, exactly like zero-padded ``ac``."""
-    aq = sample_inclusion_probs(vq, tq)
-    s_total = kq.shape[2]
-    blocks = _tuned("sample_estimate_fields", {"S": s_total},
-                    {"bq": (kq.shape[1], 8), "bp": (kc.shape[1], 8)})
-    return sample_estimate_fields_packed_pallas(kq, vq, aq, kc, wc, tc,
-                                                s_total=s_total,
-                                                qmap=qmap, cmap=cmap,
-                                                interpret=_interpret(),
-                                                **blocks)
 
 
 # ---------------------------------------------------------------------------
@@ -396,6 +312,8 @@ def sample_estimate_fields_packed(kq, vq, tq, kc, wc, tc, *, qmap, cmap):
 # independent of every other row (the kernels reduce only over the sample
 # axis m, with identical block sizes on any row count), the concatenated
 # per-shard results are bitwise identical to the single-device launch.
+# Packed buffers shard the same way: their pad rows (zero halfwords) are as
+# inert as zero f32 values.
 
 def _pad_corpus_rows(x, pad: int, axis: int, value=0):
     if not pad:
@@ -454,15 +372,15 @@ def _fields_sharded_fn(mesh, axis: str, qmap, cmap):
         out_specs=PSpec(None, None, axis))
 
 
-@_obs.instrumented("icws_estimate_fields_sharded")
+@_obs.instrumented("icws_estimate_fields_sharded", packed_arg=4)
 def icws_estimate_fields_sharded(fq, vq, nq, fpc, vc, nc, *, qmap, cmap,
                                  mesh, axis="data"):
     """Sharded :func:`icws_estimate_fields`: the fused multi-field launch
     runs per shard over corpus rows split along mesh axis ``axis``.
 
     Args as :func:`icws_estimate_fields` (corpus ``[C, P, m]`` may be
-    full-capacity store buffers).  Returns ``[G, Q, P]`` f32, bitwise
-    identical to the single-device launch.
+    full-capacity store buffers, values f32 or packed bf16).  Returns
+    ``[G, Q, P]`` f32, bitwise identical to the single-device launch.
     """
     d = mesh.shape[axis]
     cap = fpc.shape[1]
@@ -485,7 +403,7 @@ def _linear_fields_sharded_fn(mesh, axis: str, qmap, cmap):
         out_specs=PSpec(None, None, axis))
 
 
-@_obs.instrumented("linear_estimate_fields_sharded")
+@_obs.instrumented("linear_estimate_fields_sharded", packed_arg=1)
 def linear_estimate_fields_sharded(tq, tc, *, qmap, cmap, mesh, axis="data"):
     """Sharded :func:`linear_estimate_fields`: per-shard launches over
     corpus rows split along mesh axis ``axis``, queries replicated.
@@ -516,16 +434,16 @@ def _sample_fields_sharded_fn(mesh, axis: str, qmap, cmap):
         out_specs=PSpec(None, None, axis))
 
 
-@_obs.instrumented("sample_estimate_fields_sharded")
+@_obs.instrumented("sample_estimate_fields_sharded", packed_arg=4)
 def sample_estimate_fields_sharded(kq, vq, tq, kc, vc, tc, *, qmap, cmap,
                                    mesh, axis="data"):
     """Sharded :func:`sample_estimate_fields`: the fused key-match launch
     runs per shard over corpus rows split along mesh axis ``axis``, queries
     replicated.  Returns ``[G, Q, P]`` f32, bitwise identical to the
     single-device launch: each (q, p) estimate reduces only over row p's
-    slot blocks, rows pad with corpus-pad-sentinel keys / zero values /
-    zero tau (inert under the kernel's guards), and the (bt, bu) block
-    reduction order is independent of the per-shard row count.
+    slots, rows pad with corpus-pad-sentinel keys / zero values / zero tau
+    (inert under the kernel's guards), and the slot reduction order is
+    independent of the per-shard row count.
     """
     d = mesh.shape[axis]
     cap = kc.shape[1]
@@ -537,105 +455,46 @@ def sample_estimate_fields_sharded(kq, vq, tq, kc, vc, tc, *, qmap, cmap,
     return f(kq, vq, tq, kc, vc, tc)[:, :, :cap]
 
 
-# Packed sharded twins: identical row-sharding scheme to the unpacked
-# wrappers above (queries replicated, corpus rows split and padded with
-# inert spare-row fills -- sentinel keys/fingerprints, zero words, zero
-# norms/taus).  Per-shard launches resolve the SAME autotune cache entry
-# as the single-device launch (the key holds only the sketch width), so
-# the reduction order matches and the concatenated results stay bitwise
-# identical to the unsharded packed launch.
+def _ordered_top_k(score, k: int):
+    """``jax.lax.top_k`` over the last dim, ties broken by ascending index.
 
-@functools.lru_cache(maxsize=None)
-def _fields_packed_sharded_fn(mesh, axis: str, qmap, cmap):
-    def body(fq, vq, nq, fpc, wc, nc):
-        return icws_estimate_fields_packed(fq, vq, nq, fpc, wc, nc,
-                                           qmap=qmap, cmap=cmap)
-
-    return compat.shard_map(
-        body, mesh=mesh,
-        in_specs=(PSpec(), PSpec(), PSpec(),
-                  PSpec(None, axis), PSpec(None, axis), PSpec(None, axis)),
-        out_specs=PSpec(None, None, axis))
-
-
-@_obs.instrumented("icws_estimate_fields_packed_sharded")
-def icws_estimate_fields_packed_sharded(fq, vq, nq, fpc, wc, nc, *, qmap,
-                                        cmap, mesh, axis="data"):
-    """Sharded :func:`icws_estimate_fields_packed`; returns ``[G, Q, cap]``
-    f32, bitwise identical to the single-device packed launch."""
-    d = mesh.shape[axis]
-    cap = fpc.shape[1]
-    pad = (-cap) % d
-    fpc = _pad_corpus_rows(fpc, pad, 1, CORPUS_PAD_FP)
-    wc = _pad_corpus_rows(wc, pad, 1)
-    nc = _pad_corpus_rows(nc, pad, 1)
-    f = _fields_packed_sharded_fn(mesh, axis, tuple(qmap), tuple(cmap))
-    return f(fq, vq, nq, fpc, wc, nc)[:, :, :cap]
+    ``lax.top_k`` leaves the order of equal scores to the backend, and a
+    TPU orders them differently for different batch shapes, so a query's
+    candidates would depend on the micro-batch it came in.  Every row
+    scoring above the k-th score is in the top k; of the rows tied with
+    it, the lowest indices fill the rest.  A second ``top_k`` over an int32
+    key picks exactly that set, and a sort of the k winners by (score
+    descending, index ascending) fixes their order.
+    """
+    n = score.shape[-1]
+    kth = jax.lax.top_k(score, k)[0][..., -1:]
+    pos = jax.lax.broadcasted_iota(jnp.int32, score.shape, score.ndim - 1)
+    key = jnp.where(score > kth, n, jnp.where(score == kth, n - 1 - pos, -1))
+    idx = jax.lax.top_k(key, k)[1]
+    neg, idx = jax.lax.sort(
+        (-jnp.take_along_axis(score, idx, axis=-1), idx), num_keys=2)
+    return -neg, idx
 
 
-@functools.lru_cache(maxsize=None)
-def _linear_fields_packed_sharded_fn(mesh, axis: str, qmap, cmap):
-    def body(tq, wc):
-        return linear_estimate_fields_packed(tq, wc, qmap=qmap, cmap=cmap)
-
-    return compat.shard_map(
-        body, mesh=mesh,
-        in_specs=(PSpec(), PSpec(None, axis, None, None)),
-        out_specs=PSpec(None, None, axis))
-
-
-@_obs.instrumented("linear_estimate_fields_packed_sharded")
-def linear_estimate_fields_packed_sharded(tq, wc, *, qmap, cmap, mesh,
-                                          axis="data"):
-    """Sharded :func:`linear_estimate_fields_packed`; zero words decode to
-    zero tables, so row padding stays inert exactly as unpacked."""
-    d = mesh.shape[axis]
-    cap = wc.shape[1]
-    pad = (-cap) % d
-    wc = _pad_corpus_rows(wc, pad, 1)
-    f = _linear_fields_packed_sharded_fn(mesh, axis, tuple(qmap), tuple(cmap))
-    return f(tq, wc)[:, :, :cap]
-
-
-@functools.lru_cache(maxsize=None)
-def _sample_fields_packed_sharded_fn(mesh, axis: str, qmap, cmap):
-    def body(kq, vq, tq, kc, wc, tc):
-        return sample_estimate_fields_packed(kq, vq, tq, kc, wc, tc,
-                                             qmap=qmap, cmap=cmap)
-
-    return compat.shard_map(
-        body, mesh=mesh,
-        in_specs=(PSpec(), PSpec(), PSpec(),
-                  PSpec(None, axis), PSpec(None, axis), PSpec(None, axis)),
-        out_specs=PSpec(None, None, axis))
-
-
-@_obs.instrumented("sample_estimate_fields_packed_sharded")
-def sample_estimate_fields_packed_sharded(kq, vq, tq, kc, wc, tc, *, qmap,
-                                          cmap, mesh, axis="data"):
-    """Sharded :func:`sample_estimate_fields_packed`; pad rows carry
-    sentinel keys / zero words / zero tau, inert under the kernel guards."""
-    d = mesh.shape[axis]
-    cap = kc.shape[1]
-    pad = (-cap) % d
-    kc = _pad_corpus_rows(kc, pad, 1, CORPUS_PAD_FP)
-    wc = _pad_corpus_rows(wc, pad, 1)
-    tc = _pad_corpus_rows(tc, pad, 1)
-    f = _sample_fields_packed_sharded_fn(mesh, axis, tuple(qmap), tuple(cmap))
-    return f(kq, vq, tq, kc, wc, tc)[:, :, :cap]
+@_obs.instrumented("top_k")
+@functools.partial(jax.jit, static_argnames=("k",))
+def top_k(score, k: int):
+    """Top-k scores and indices over the last dim of ``score``, ties broken
+    by ascending index on every backend (see :func:`_ordered_top_k`)."""
+    return _ordered_top_k(score, k)
 
 
 @_obs.instrumented("sharded_top_k")
 def sharded_top_k(score, k: int, *, mesh, axis="data"):
     """Per-shard top-k over the last dim of ``score``, merged globally.
 
-    Bitwise identical -- values AND indices -- to ``jax.lax.top_k(score,
-    k)``: ``top_k`` breaks score ties by ascending index, each shard's
-    candidate list keeps ascending global indices within equal scores, and
-    the merge concatenates shards in index order, so the global re-``top_k``
-    resolves ties exactly as the unsharded call does.  Any global top-k row
-    must be in its own shard's top-k (rows ranked above it locally are
-    ranked above it globally), so per-shard k candidates always suffice.
+    Bitwise identical -- values AND indices -- to :func:`top_k`: each
+    shard's candidate list keeps ascending global indices within equal
+    scores, and the merge concatenates shards in index order, so the global
+    re-ranking resolves ties exactly as the unsharded call does.  Any
+    global top-k row must be in its own shard's top-k (rows ranked above it
+    locally are ranked above it globally), so per-shard k candidates always
+    suffice.
     """
     d = mesh.shape[axis]
     n = score.shape[-1]
@@ -646,14 +505,14 @@ def sharded_top_k(score, k: int, *, mesh, axis="data"):
     kl = min(k, shard)
     f = _sharded_topk_fn(mesh, axis, kl, shard, score.ndim)
     vals, idx = f(score)
-    v, pos = jax.lax.top_k(vals, k)
+    v, pos = _ordered_top_k(vals, k)
     return v, jnp.take_along_axis(idx, pos, axis=-1)
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_topk_fn(mesh, axis: str, kl: int, shard: int, ndim: int):
     def body(s):
-        v, i = jax.lax.top_k(s, kl)
+        v, i = _ordered_top_k(s, kl)
         return v, i + jax.lax.axis_index(axis) * shard
 
     spec = PSpec(*([None] * (ndim - 1) + [axis]))

@@ -1,33 +1,30 @@
-"""bf16-halfword codec: the packed corpus value layout (2 samples / i32 word).
+"""bf16-halfword codec: the packed corpus value layout (one bf16 per sample).
 
 The packed :class:`repro.data.store.CorpusStore` layout stores every f32
 *value* component (ICWS sampled values, TS/PS sampled values, linear table
-cells) as bf16 halfwords, two consecutive samples per i32 word:
+cells) as a bf16 plane of the same shape, sample for sample:
 
-    word k = bf16(x[2k]) | bf16(x[2k+1]) << 16
+    packed[..., k] = bf16(x[..., k])
 
 ``bf16(x)`` here is *truncation* -- the top 16 bits of the f32 encoding
 (sign, 8-bit exponent, 7 mantissa bits).  Truncation, not round-to-nearest,
 is deliberate: it makes the decode exact (``unpack(pack(x)) ==
 pack-domain(x)`` bit for bit) and the codec idempotent
-(``pack(unpack(w)) == w`` for every word), which is what the packed-path
-bitwise-identity contract is stated against.  Zero encodes to the zero
-word, so zero-filled spare rows and slot padding stay inert through the
-codec with no sentinel machinery.
+(``pack(unpack(w)) == w`` for every halfword), which is what the
+packed-path bitwise-identity contract is stated against.  Zero encodes to
+the zero halfword, so zero-filled spare rows stay inert through the codec
+with no sentinel machinery.
 
 Integer components (31-bit ICWS fingerprints, TS/PS sample keys) are NOT
 narrowed: they are exact-match state -- a single flipped bit changes
 collision/join semantics -- and 31 bits do not compress below one i32 lane
 without changing results.  The byte savings come entirely from the value
 lanes (f32 -> bf16 halves the dominant component), which the estimate
-kernels decode tile-by-tile in VMEM (`unpack_halfwords_f32` is the
-in-kernel decode used by ``estimate_fields_packed_pallas`` and friends);
-the packed words never expand in HBM.
-
-These helpers are shape-polymorphic over leading dims and run both as
-plain jnp (host-side ``pack_rows``/``unpack_rows``) and inside Pallas
-kernel bodies (interpret and compiled), where shifts/bitcasts lower to
-plain VPU ops.
+kernels decode tile by tile in VMEM with a plain ``bf16 -> f32`` convert
+(:func:`unpack_halfwords_f32`, exact).  A packed tile covers the same
+samples in the same lane order as its unpacked twin, so packed and
+unpacked launches share one block layout and one reduction order; the
+packed values never expand in HBM.
 """
 from __future__ import annotations
 
@@ -35,40 +32,18 @@ import jax
 import jax.numpy as jnp
 
 
-def packed_width(n: int) -> int:
-    """i32 words needed for ``n`` bf16 halfword samples (rounds up)."""
-    return (int(n) + 1) // 2
-
-
 def pack_halfwords_f32(x: jnp.ndarray) -> jnp.ndarray:
-    """``[..., 2k]`` f32 -> ``[..., k]`` i32, two bf16 halfwords per word.
-
-    Each f32 is truncated to its top 16 bits (bf16); the even sample lands
-    in the low halfword.  The last dim must be even -- callers pad odd
-    widths with one zero sample first (zero packs to zero bits).
-    """
-    x = jnp.asarray(x, jnp.float32)
-    if x.shape[-1] % 2:
-        raise ValueError(f"pack_halfwords_f32 needs an even last dim; "
-                         f"got {x.shape}")
-    bits = jax.lax.bitcast_convert_type(x, jnp.uint32) >> 16
-    pairs = bits.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
-    word = pairs[..., 0] | (pairs[..., 1] << 16)
-    return jax.lax.bitcast_convert_type(word, jnp.int32)
+    """``[...]`` f32 -> ``[...]`` bf16, each value truncated to its top 16
+    bits (plain jnp, so it also runs on the device after a sketch launch)."""
+    bits = jax.lax.bitcast_convert_type(jnp.asarray(x, jnp.float32),
+                                        jnp.uint32) >> 16
+    return jax.lax.bitcast_convert_type(bits.astype(jnp.uint16),
+                                        jnp.bfloat16)
 
 
 def unpack_halfwords_f32(w: jnp.ndarray) -> jnp.ndarray:
-    """``[..., k]`` i32 -> ``[..., 2k]`` f32, the exact codec inverse.
-
-    Each halfword expands to the f32 whose top 16 bits it holds (low 16
-    mantissa bits zero) -- bf16 -> f32 is exact, so this reproduces the
-    pack-domain values bit for bit.  Used both host-side and as the
-    in-kernel tile decode of the packed estimate kernels.
-    """
-    wu = jax.lax.bitcast_convert_type(jnp.asarray(w, jnp.int32), jnp.uint32)
-    even = jax.lax.bitcast_convert_type((wu << 16).astype(jnp.uint32),
-                                        jnp.float32)
-    odd = jax.lax.bitcast_convert_type(wu & jnp.uint32(0xFFFF0000),
-                                       jnp.float32)
-    out = jnp.stack([even, odd], axis=-1)
-    return out.reshape(w.shape[:-1] + (2 * w.shape[-1],))
+    """``[...]`` bf16 -> ``[...]`` f32, the exact codec inverse: each
+    halfword expands to the f32 whose top 16 bits it holds (low 16 mantissa
+    bits zero).  Used both host-side and as the in-kernel tile decode of
+    the packed estimate kernels."""
+    return jnp.asarray(w, jnp.bfloat16).astype(jnp.float32)

@@ -14,23 +14,24 @@ contraction over the ``m x m`` slot pairs,
                             / min(pq[q,t], pc[p,u])``
 
 with inverse-inclusion-probability weights ``p = min(1, m * v^2 / tau)``
-(``tau <= 0`` means probability 1; see the ops-layer epilogue
-:func:`sample_inclusion_probs`).  This is a third estimator geometry for
-the kernel layer: not slot-aligned collision counting (ICWS), not dense
-MXU dots (CS/JL), but an unaligned sparse join expressed as a blockwise
-``[bq*bt x bp*bu]`` equality contraction.
+(``tau <= 0`` means probability 1; see :func:`sample_inclusion_probs`).
+This is a third estimator geometry for the kernel layer: not slot-aligned
+collision counting (ICWS), not dense MXU dots (CS/JL), but an unaligned
+sparse join, an O(m^2) equality contraction per (query, row) pair.
 
 ``sample_estimate_fields_pallas`` is the fused multi-field form, mirroring
 :func:`repro.kernels.estimate.estimate_fields_pallas`: per-field stacks
 ``[F, Q, m]`` / ``[C, P, m]`` plus static qmap/cmap field-pair tuples folded
 into the leading grid dimension, so all §1.3 field-pair estimates of a
-dataset-search batch run as ONE launch.  The grid is
-``(G, Q/bq, P/bp, m/bt, m/bu)`` with both *sample* axes tiled and innermost:
-the double sum decomposes over (t, u) blocks, so each output block
-accumulates across the two inner grid dims exactly as the ICWS kernels
-accumulate over m.  VMEM per step is dominated by the ``[bq, bt, bp, bu]``
-cross tensor -- 2 MiB f32 at the defaults (8, 64, 8, 128), comfortably
-inside the ~16 MiB budget with its where/min temporaries.
+dataset-search batch run as ONE launch.  The grid is ``(G, P/bp, Q/bq)``,
+queries innermost so a corpus tile stays in VMEM across the query blocks.
+A step transposes its ``[bp, m]`` corpus tile so slots lie on sublanes and
+rows on lanes, then walks the ``bq`` queries and their slots: each slot's
+key is compared against the whole ``[m, bp]`` tile and the matches are
+reduced over the corpus slots, one ``[1, bp]`` row of sums per slot (VPU
+work; VMEM holds a few ``[m, bp]`` f32 tiles, 192 KiB each at m = 384,
+bp = 128).  The packed store's bf16 value plane runs through the same
+kernel.
 
 Padding reuses the single estimate-kernel sentinel convention
 (:mod:`repro.kernels.estimate`): live keys are 31-bit non-negative, query
@@ -47,8 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .estimate import CORPUS_PAD_FP, QUERY_PAD_FP
-from .packed import unpack_halfwords_f32
+from .estimate import CORPUS_PAD_FP, QUERY_PAD_FP, _check_maps, _lut
 
 # Sampling rows reuse the estimate kernels' pad convention: empty / padded
 # query slots hold -1, corpus padding and spare store rows hold -2.
@@ -57,8 +57,8 @@ SAMPLE_CORPUS_PAD_KEY = CORPUS_PAD_FP
 
 
 def _inclusion_probs(v: jnp.ndarray, t: jnp.ndarray, m: int) -> jnp.ndarray:
-    """Elementwise inclusion-probability core shared by the host-side
-    prologue and the packed kernel's in-VMEM recompute -- a single
+    """Elementwise inclusion-probability core shared by the query-side
+    prologue and the kernel's in-VMEM corpus recompute -- a single
     definition so the two paths are bitwise identical by construction.
     ``v`` f32 values, ``t`` f32 taus broadcastable against ``v``, ``m`` the
     static slot count of the sketch scheme (NOT a padded tile width).
@@ -83,246 +83,101 @@ def sample_inclusion_probs(vals: jnp.ndarray, tau: jnp.ndarray) -> jnp.ndarray:
                             vals.shape[-1])
 
 
-def _sample_fields_kernel(kq_ref, vq_ref, aq_ref, kc_ref, vc_ref, ac_ref,
+def _sample_fields_kernel(kq_ref, vq_ref, aq_ref, kc_ref, vc_ref, tc_ref,
                           out_ref):
-    t_idx = pl.program_id(3)
-    u_idx = pl.program_id(4)
+    s_total = kc_ref.shape[2]
+    n_q = kq_ref.shape[3]
+    # corpus tile with slots on sublanes and rows on lanes: [S, BP].  The
+    # value tile decodes in VMEM when it arrives packed (bf16 -> f32,
+    # exact) and the inclusion probabilities are recomputed here from the
+    # per-row tau, so no probability plane ever exists in HBM.
+    kc = kc_ref[0].T
+    vc = vc_ref[0].astype(jnp.float32).T
+    ac = _inclusion_probs(vc, tc_ref[0], s_total)
+    bp = kc.shape[1]
 
-    kq = kq_ref[0][:, :, None, None]          # [bq, bt, 1, 1]
-    vq = vq_ref[0][:, :, None, None]
-    aq = aq_ref[0][:, :, None, None]
-    kc = kc_ref[0][None, None, :, :]          # [1, 1, bp, bu]
-    vc = vc_ref[0][None, None, :, :]
-    ac = ac_ref[0][None, None, :, :]
+    for q in range(n_q):
+        def slot(t, acc, q=q):
+            # one query slot against every (slot, row) of the tile; the
+            # `kq >= 0` guard drops every pad sentinel and `p > 0` drops
+            # empty slots on either side, so pads never divide or match
+            kq, vq, aq = (r[0, 0, t, q] for r in (kq_ref, vq_ref, aq_ref))
+            p = jnp.minimum(aq, ac)
+            live = (kq == kc) & (kq >= 0) & (p > 0)
+            term = jnp.where(live, vq * vc / jnp.where(live, p, 1.0), 0.0)
+            return acc + jnp.sum(term, axis=0, keepdims=True)
 
-    # unaligned key match: the [bq, bt, bp, bu] cross tensor lives only in
-    # VMEM for this block; `kq >= 0` guards every pad sentinel and `p > 0`
-    # guards empty slots (either side), so pads never divide or match
-    p = jnp.minimum(aq, ac)
-    live = (kq == kc) & (kq >= 0) & (p > 0)
-    term = jnp.where(live, vq * vc / jnp.where(live, p, 1.0), 0.0)
-    tile = term.sum(axis=(1, 3))              # [bq, bp]
-
-    @pl.when((t_idx == 0) & (u_idx == 0))
-    def _init():
-        out_ref[0, :, :] = tile
-
-    @pl.when((t_idx != 0) | (u_idx != 0))
-    def _acc():
-        out_ref[0, :, :] = out_ref[0, :, :] + tile
+        out_ref[0, q:q + 1, :] = jax.lax.fori_loop(
+            0, kq_ref.shape[2], slot, jnp.zeros((1, bp), jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("qmap", "cmap", "bq", "bp",
-                                             "bt", "bu", "interpret"))
-def sample_estimate_fields_pallas(kq, vq, aq, kc, vc, ac, *, qmap, cmap,
-                                  bq: int = 8, bp: int = 8, bt: int = 64,
-                                  bu: int = 128, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("qmap", "cmap", "bp", "bq",
+                                             "interpret"))
+def sample_estimate_fields_pallas(kq, vq, aq, kc, vc, tc, *, qmap, cmap,
+                                  bp: int = 128, bq: int = 8,
+                                  interpret: bool = False):
     """Fused multi-field key-match estimates in ONE kernel launch; matches
     :func:`repro.kernels.ref.sample_estimate_fields_ref`.
 
     Args:
       kq/vq/aq: [F, Q, m] per-field query sample keys / values / inclusion
         probabilities (see :func:`sample_inclusion_probs`).
-      kc/vc/ac: [C, P, m] per-field corpus samples.
+      kc/vc/tc: [C, P, S] corpus sample keys and values, [C, P] taus, as
+        stored.  ``vc`` is f32, or the bf16 plane of a packed store (see
+        :mod:`repro.kernels.packed`): the kernel decodes it per tile.
       qmap/cmap: static same-length tuples of field indices, exactly as
         :func:`repro.kernels.estimate.estimate_fields_pallas`.
     Returns [G, Q, P] f32 inner-product estimates (no epilogue: the inverse-
     probability weighting happens inside the contraction).
 
-    Per-(q, p) results are bitwise independent of Q/P row padding and of
-    the corpus row count: each output element reduces only over its own
-    rows' (t, u) slot blocks, in a fixed (bt, bu) grid order.
+    Grid ``(G, P/bp, Q/bq)``: each step holds one ``[bp, S]`` corpus tile
+    and a block of ``bq`` queries of its field (slots on sublanes, the
+    block's queries on lanes; Q is padded with inert queries to a multiple
+    of ``bq``).
+    Corpus inclusion probabilities ``min(1, S * v^2 / tau)`` come from the
+    decoded tile and ``tc`` in VMEM, with the same elementwise expression
+    the query prologue uses.  Per-(q, p) results are bitwise independent of
+    Q and of the corpus row count: each output element reduces only over
+    its own query column and corpus row, in a fixed slot order.
     """
-    qmap = tuple(int(i) for i in qmap)
-    cmap = tuple(int(i) for i in cmap)
-    if len(qmap) != len(cmap):
-        raise ValueError("qmap/cmap length mismatch")
-    if not qmap:
-        raise ValueError("qmap/cmap must name at least one field pair")
-    G = len(qmap)
     F, Q, m = kq.shape
     C, P, mc = kc.shape
-    if m != mc:
+    qmap, cmap = _check_maps(qmap, cmap, F, C)
+    if m != mc or vc.shape != kc.shape:
         raise ValueError(f"query slots {m} do not match corpus slots {mc}")
-    if min(qmap) < 0 or max(qmap) >= F or min(cmap) < 0 or max(cmap) >= C:
-        raise ValueError("field map index out of range")
-    q_pad = (-Q) % bq
+    G = len(qmap)
     p_pad = (-P) % bp
-    t_pad = (-m) % bt
-    u_pad = (-m) % bu
-    if q_pad or t_pad:
-        kq = jnp.pad(kq, ((0, 0), (0, q_pad), (0, t_pad)),
-                     constant_values=SAMPLE_QUERY_PAD_KEY)
-        vq = jnp.pad(vq, ((0, 0), (0, q_pad), (0, t_pad)))
-        aq = jnp.pad(aq, ((0, 0), (0, q_pad), (0, t_pad)))
-    if p_pad or u_pad:
-        kc = jnp.pad(kc, ((0, 0), (0, p_pad), (0, u_pad)),
+    if p_pad:
+        kc = jnp.pad(kc, ((0, 0), (0, p_pad), (0, 0)),
                      constant_values=SAMPLE_CORPUS_PAD_KEY)
-        vc = jnp.pad(vc, ((0, 0), (0, p_pad), (0, u_pad)))
-        ac = jnp.pad(ac, ((0, 0), (0, p_pad), (0, u_pad)))
-    Qp, mt = kq.shape[1:]
-    Pp, mu = kc.shape[1:]
+        vc = jnp.pad(vc, ((0, 0), (0, p_pad), (0, 0)))
+        tc = jnp.pad(tc, ((0, 0), (0, p_pad)))
+    Pp = P + p_pad
+    q_pad = (-Q) % bq
+    Qp = Q + q_pad
 
-    def _lut(table):
-        # static python-int lookup via select arithmetic, exactly as
-        # estimate_fields_pallas: index maps may not capture traced values
-        def sel(g):
-            idx = table[0]
-            for i, v in enumerate(table[1:], start=1):
-                idx = jnp.where(g == i, v, idx)
-            return idx
-        return sel
+    def q_blocks(x, fill):
+        # [F, Q, m] -> [F, Qp/bq, m, bq]: a block's queries on lanes
+        x = jnp.pad(x, ((0, 0), (0, q_pad), (0, 0)), constant_values=fill)
+        return jnp.swapaxes(x.reshape(F, Qp // bq, bq, m), 2, 3)
 
     qsel, csel = _lut(qmap), _lut(cmap)
-    grid = (G, Qp // bq, Pp // bp, mt // bt, mu // bu)
     out = pl.pallas_call(
         _sample_fields_kernel,
-        grid=grid,
+        grid=(G, Pp // bp, Qp // bq),
         in_specs=[
-            pl.BlockSpec((1, bq, bt),
-                         lambda g, q, p, t, u: (qsel(g), q, t)),
-            pl.BlockSpec((1, bq, bt),
-                         lambda g, q, p, t, u: (qsel(g), q, t)),
-            pl.BlockSpec((1, bq, bt),
-                         lambda g, q, p, t, u: (qsel(g), q, t)),
-            pl.BlockSpec((1, bp, bu),
-                         lambda g, q, p, t, u: (csel(g), p, u)),
-            pl.BlockSpec((1, bp, bu),
-                         lambda g, q, p, t, u: (csel(g), p, u)),
-            pl.BlockSpec((1, bp, bu),
-                         lambda g, q, p, t, u: (csel(g), p, u)),
+            pl.BlockSpec((1, 1, m, bq), lambda g, p, j: (qsel(g), j, 0, 0)),
+            pl.BlockSpec((1, 1, m, bq), lambda g, p, j: (qsel(g), j, 0, 0)),
+            pl.BlockSpec((1, 1, m, bq), lambda g, p, j: (qsel(g), j, 0, 0)),
+            pl.BlockSpec((1, bp, m), lambda g, p, j: (csel(g), p, 0)),
+            pl.BlockSpec((1, bp, m), lambda g, p, j: (csel(g), p, 0)),
+            pl.BlockSpec((1, 1, bp), lambda g, p, j: (csel(g), 0, p)),
         ],
-        out_specs=pl.BlockSpec((1, bq, bp),
-                               lambda g, q, p, t, u: (g, q, p)),
+        out_specs=pl.BlockSpec((1, bq, bp), lambda g, p, j: (g, j, p)),
         out_shape=jax.ShapeDtypeStruct((G, Qp, Pp), jnp.float32),
         interpret=interpret,
-    )(kq.astype(jnp.int32), vq.astype(jnp.float32), aq.astype(jnp.float32),
-      kc.astype(jnp.int32), vc.astype(jnp.float32), ac.astype(jnp.float32))
-    return out[:, :Q, :P]
-
-
-def _sample_fields_packed_kernel(kq_ref, vq_ref, aq_ref, kc_ref, wc_ref,
-                                 tc_ref, out_ref, *, s_total):
-    t_idx = pl.program_id(3)
-    u_idx = pl.program_id(4)
-
-    kq = kq_ref[0][:, :, None, None]          # [bq, bt, 1, 1]
-    vq = vq_ref[0][:, :, None, None]
-    aq = aq_ref[0][:, :, None, None]
-    # decode the corpus value tile and recompute its inclusion probabilities
-    # in VMEM from the per-row tau block -- the f32 value and probability
-    # planes never exist in HBM.  _inclusion_probs with the static scheme
-    # slot count s_total is the same elementwise expression the unpacked
-    # path applies host-side, so the tiles match bitwise; pad slots (zero
-    # words -> value 0) land on probability 0 exactly as zero-padded ac.
-    vc2 = unpack_halfwords_f32(wc_ref[0])     # [bp, bu]
-    ac2 = _inclusion_probs(vc2, tc_ref[0][:, None], s_total)
-    kc = kc_ref[0][None, None, :, :]          # [1, 1, bp, bu]
-    vc = vc2[None, None, :, :]
-    ac = ac2[None, None, :, :]
-
-    p = jnp.minimum(aq, ac)
-    live = (kq == kc) & (kq >= 0) & (p > 0)
-    term = jnp.where(live, vq * vc / jnp.where(live, p, 1.0), 0.0)
-    tile = term.sum(axis=(1, 3))              # [bq, bp]
-
-    @pl.when((t_idx == 0) & (u_idx == 0))
-    def _init():
-        out_ref[0, :, :] = tile
-
-    @pl.when((t_idx != 0) | (u_idx != 0))
-    def _acc():
-        out_ref[0, :, :] = out_ref[0, :, :] + tile
-
-
-@functools.partial(jax.jit, static_argnames=("s_total", "qmap", "cmap", "bq",
-                                             "bp", "bt", "bu", "interpret"))
-def sample_estimate_fields_packed_pallas(kq, vq, aq, kc, wc, tc, *, s_total,
-                                         qmap, cmap, bq: int = 8,
-                                         bp: int = 8, bt: int = 64,
-                                         bu: int = 128,
-                                         interpret: bool = True):
-    """:func:`sample_estimate_fields_pallas` over a bit-packed corpus.
-
-    The corpus side arrives as stored: keys ``kc [C, P, S]`` i32, values
-    packed as bf16-halfword words ``wc [C, P, S // 2]`` i32 (see
-    :mod:`repro.kernels.packed`), and per-row taus ``tc [C, P]`` f32.
-    Corpus inclusion probabilities are recomputed inside the kernel from
-    the decoded tile and ``tc`` -- no ``ac`` plane is materialized.
-    ``s_total`` is the static slot count of the sketch scheme (the ``m``
-    of :func:`sample_inclusion_probs`), which may differ from the stored
-    slot dim ``S`` when an odd slot count gained one inert pad slot at
-    pack time; ``S`` and ``bu`` must be even.  Query slots are independent
-    of corpus slots, exactly as in the unpacked kernel.
-    """
-    qmap = tuple(int(i) for i in qmap)
-    cmap = tuple(int(i) for i in cmap)
-    if len(qmap) != len(cmap):
-        raise ValueError("qmap/cmap length mismatch")
-    if not qmap:
-        raise ValueError("qmap/cmap must name at least one field pair")
-    G = len(qmap)
-    F, Q, m = kq.shape
-    C, P, S = kc.shape
-    if S % 2 or bu % 2:
-        raise ValueError(f"packed sample estimate needs even corpus slots "
-                         f"and bu; got S={S}, bu={bu}")
-    if 2 * wc.shape[2] != S:
-        raise ValueError(f"packed words {wc.shape[2]} do not match corpus "
-                         f"slots {S}")
-    if min(qmap) < 0 or max(qmap) >= F or min(cmap) < 0 or max(cmap) >= C:
-        raise ValueError("field map index out of range")
-    q_pad = (-Q) % bq
-    p_pad = (-P) % bp
-    t_pad = (-m) % bt
-    u_pad = (-S) % bu           # even: S and bu are both even
-    if q_pad or t_pad:
-        kq = jnp.pad(kq, ((0, 0), (0, q_pad), (0, t_pad)),
-                     constant_values=SAMPLE_QUERY_PAD_KEY)
-        vq = jnp.pad(vq, ((0, 0), (0, q_pad), (0, t_pad)))
-        aq = jnp.pad(aq, ((0, 0), (0, q_pad), (0, t_pad)))
-    if p_pad or u_pad:
-        kc = jnp.pad(kc, ((0, 0), (0, p_pad), (0, u_pad)),
-                     constant_values=SAMPLE_CORPUS_PAD_KEY)
-        # zero words decode to value 0 -> probability 0: inert, like ac pad
-        wc = jnp.pad(wc, ((0, 0), (0, p_pad), (0, u_pad // 2)))
-        tc = jnp.pad(tc, ((0, 0), (0, p_pad)))
-    Qp, mt = kq.shape[1:]
-    Pp, mu = kc.shape[1:]
-
-    def _lut(table):
-        # static python-int lookup via select arithmetic, exactly as
-        # estimate_fields_pallas: index maps may not capture traced values
-        def sel(g):
-            idx = table[0]
-            for i, v in enumerate(table[1:], start=1):
-                idx = jnp.where(g == i, v, idx)
-            return idx
-        return sel
-
-    qsel, csel = _lut(qmap), _lut(cmap)
-    grid = (G, Qp // bq, Pp // bp, mt // bt, mu // bu)
-    out = pl.pallas_call(
-        functools.partial(_sample_fields_packed_kernel, s_total=int(s_total)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, bt),
-                         lambda g, q, p, t, u: (qsel(g), q, t)),
-            pl.BlockSpec((1, bq, bt),
-                         lambda g, q, p, t, u: (qsel(g), q, t)),
-            pl.BlockSpec((1, bq, bt),
-                         lambda g, q, p, t, u: (qsel(g), q, t)),
-            pl.BlockSpec((1, bp, bu),
-                         lambda g, q, p, t, u: (csel(g), p, u)),
-            pl.BlockSpec((1, bp, bu // 2),
-                         lambda g, q, p, t, u: (csel(g), p, u)),
-            pl.BlockSpec((1, bp),
-                         lambda g, q, p, t, u: (csel(g), p)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, bp),
-                               lambda g, q, p, t, u: (g, q, p)),
-        out_shape=jax.ShapeDtypeStruct((G, Qp, Pp), jnp.float32),
-        interpret=interpret,
-    )(kq.astype(jnp.int32), vq.astype(jnp.float32), aq.astype(jnp.float32),
-      kc.astype(jnp.int32), wc.astype(jnp.int32), tc.astype(jnp.float32))
+    )(q_blocks(kq.astype(jnp.int32), SAMPLE_QUERY_PAD_KEY),
+      q_blocks(vq.astype(jnp.float32), 0.0),
+      q_blocks(aq.astype(jnp.float32), 0.0),
+      kc.astype(jnp.int32), vc, tc.astype(jnp.float32)[:, None, :])
     return out[:, :Q, :P]

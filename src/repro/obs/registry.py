@@ -27,12 +27,13 @@ from __future__ import annotations
 SPECS = (
     # -- kernels / ops layer -------------------------------------------------
     {"name": "ops.launches_total", "type": "counter",
-     "labels": ("op", "family"), "unit": "launches",
+     "labels": ("op", "family", "packed"), "unit": "launches",
      "help": "Calls through a public repro.kernels.ops launch wrapper, by "
-             "op and ambient serving family ('-' outside a family "
-             "context)."},
+             "op, ambient serving family ('-' outside a family context) "
+             "and whether the corpus value plane was a packed store's bf16 "
+             "plane ('true'/'false'; '-' for ops without one)."},
     {"name": "ops.launch_seconds", "type": "histogram",
-     "labels": ("op", "family"), "unit": "s",
+     "labels": ("op", "family", "packed"), "unit": "s",
      "help": "Steady-state wall time per public ops launch (dispatch time "
              "on async backends; end-to-end under the CPU interpreter). "
              "The first observed call per op lands in "

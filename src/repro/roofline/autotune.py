@@ -1,7 +1,7 @@
 """Roofline-driven block-size autotuner for the estimate/sketch kernels.
 
 The serving kernels (`repro.kernels.estimate` / `sample_estimate` /
-`icws_sketch`) launch with hand-picked ``bq/bp/bm/bt/bu/br`` defaults.
+`icws_sketch`) launch with hand-picked ``bq/bp/bm/bw/bn/br`` defaults.
 This module searches that space analytically -- no device timing loop --
 using the same two inputs the repo already maintains:
 
@@ -57,8 +57,8 @@ from .terms import HBM_BW, PEAK_FLOPS
 # Mirrors repro.analysis.config.AnalysisConfig.vmem_block_budget (PB001).
 VMEM_BLOCK_BUDGET = 2 * 1024 * 1024
 # Cap on kernel-internal temporaries the BlockSpec accounting cannot see
-# (the sample kernel's [bq, bt, bp, bu] cross tensor, the sketch kernel's
-# ~6 per-lane intermediates) so tuning never trades grid steps for an
+# (the sample kernel's [S, bp] match tiles, the sketch kernel's ~6
+# per-lane intermediates) so tuning never trades grid steps for an
 # interpreter-hostile VMEM blowup.
 INTERMEDIATE_BUDGET = 3 * 1024 * 1024
 _BYTES_PER_ELEM = 4
@@ -117,13 +117,15 @@ KERNELS: Dict[str, Dict] = {
         "flops_per_lane": 2.0,
     },
     "sample_estimate_fields": {
+        # whole sample rows ride in every block (a full array dim) with a
+        # block of SAMPLE_BQ queries; only the corpus row tile is free.
+        # The accounting below bounds the slot width by SAMPLE_S_CAP.
         "report_kernel": "sample_estimate_fields_pallas",
         "dims": ("G", "Q", "P", "S"),
         "key_dims": ("S",),
-        "defaults": {"bq": 8, "bp": 8, "bt": 64, "bu": 128},
-        "candidates": {"bq": (8, 16), "bp": (8, 16, 32),
-                       "bt": (32, 64, 128), "bu": (128, 256)},
-        "row_dims": {"bq": ("Q", 8), "bp": ("P", 8)},
+        "defaults": {"bp": 128},
+        "candidates": {"bp": (128, 256, 512)},
+        "row_dims": {"bp": ("P", 128)},
         "flops_per_lane": 6.0,
     },
     "icws_sketch": {
@@ -156,6 +158,11 @@ KERNELS: Dict[str, Dict] = {
 # m = 266) rounded up to a lane multiple.  Used for the PB001/PB002-style
 # block accounting of ``dmh_sketch`` entries, where the real bm <= this.
 DMH_BM_CAP = 384
+# Upper bound on the sample kernel's full-dim slot blocks: the slot width
+# of the largest serving budget (storage 400 -> 399 slots, lane-rounded);
+# and its query block (sample_estimate_fields_pallas's bq).
+SAMPLE_S_CAP = 512
+SAMPLE_BQ = 8
 
 
 def _block_shapes(kernel: str, b: Mapping[str, int]) -> list:
@@ -169,8 +176,10 @@ def _block_shapes(kernel: str, b: Mapping[str, int]) -> list:
         return [(1, (1, b["bq"], 1, b["bw"])), (1, (1, b["bp"], 1, b["bw"])),
                 (1, (1, 1, b["bq"], b["bp"]))]
     if kernel == "sample_estimate_fields":
-        return [(3, (1, b["bq"], b["bt"])), (3, (1, b["bp"], b["bu"])),
-                (1, (1, b["bq"], b["bp"]))]
+        # 3 query planes [S, bq], 2 corpus planes [bp, S], taus, output
+        return [(3, (1, 1, SAMPLE_S_CAP, SAMPLE_BQ)),
+                (2, (1, b["bp"], SAMPLE_S_CAP)), (1, (1, 1, b["bp"])),
+                (1, (1, SAMPLE_BQ, b["bp"]))]
     if kernel == "icws_sketch":
         # 3 inputs [br, bn]; 4 outputs + the pack_vals variant's 5th [br, bm]
         return [(3, (b["br"], b["bn"])), (5, (b["br"], b["bm"]))]
@@ -192,8 +201,9 @@ def block_bytes(kernel: str, blocks: Mapping[str, int]) -> int:
 
 def _intermediate_bytes(kernel: str, b: Mapping[str, int]) -> int:
     if kernel == "sample_estimate_fields":
-        # the [bq, bt, bp, bu] cross tensor (plus same-shape where/min temps)
-        return 2 * _BYTES_PER_ELEM * b["bq"] * b["bt"] * b["bp"] * b["bu"]
+        # ~6 [S, bp] tiles: the transposed corpus planes, the
+        # probabilities, and the per-slot match/term temporaries
+        return 6 * _BYTES_PER_ELEM * SAMPLE_S_CAP * b["bp"]
     if kernel == "icws_sketch":
         # ~6 f32 [br, bm, bn] temporaries (5 uniform draws + hash math)
         return 6 * _BYTES_PER_ELEM * b["br"] * b["bm"] * b["bn"]
@@ -214,9 +224,8 @@ def _grid_steps(kernel: str, s: Mapping[str, int], b: Mapping[str, int]) -> int:
         return (s["G"] * s["R"] * _ceil_div(s["Q"], b["bq"]) *
                 _ceil_div(s["P"], b["bp"]) * _ceil_div(s["W"], b["bw"]))
     if kernel == "sample_estimate_fields":
-        return (s["G"] * _ceil_div(s["Q"], b["bq"]) *
-                _ceil_div(s["P"], b["bp"]) * _ceil_div(s["S"], b["bt"]) *
-                _ceil_div(s["S"], b["bu"]))
+        return (s["G"] * _ceil_div(s["P"], b["bp"]) *
+                _ceil_div(s["Q"], SAMPLE_BQ))
     if kernel == "icws_sketch":
         return (_ceil_div(s["B"], b["br"]) * _ceil_div(s["m"], b["bm"]) *
                 _ceil_div(s["N"], b["bn"]))
@@ -236,9 +245,8 @@ def _lanes(kernel: str, s: Mapping[str, int], b: Mapping[str, int]) -> int:
         return (s["G"] * s["R"] * _ceil_to(s["Q"], b["bq"]) *
                 _ceil_to(s["P"], b["bp"]) * _ceil_to(s["W"], b["bw"]))
     if kernel == "sample_estimate_fields":
-        return (s["G"] * _ceil_to(s["Q"], b["bq"]) *
-                _ceil_to(s["P"], b["bp"]) * _ceil_to(s["S"], b["bt"]) *
-                _ceil_to(s["S"], b["bu"]))
+        # every query slot meets every corpus slot of every row
+        return s["G"] * s["Q"] * _ceil_to(s["P"], b["bp"]) * s["S"] * s["S"]
     if kernel == "icws_sketch":
         return (_ceil_to(s["B"], b["br"]) * _ceil_to(s["m"], b["bm"]) *
                 _ceil_to(s["N"], b["bn"]))
@@ -306,8 +314,7 @@ def tune(kernel: str, shape: Mapping[str, int], backend: str, *,
     if t > default_t:
         # Every feasible candidate models slower than the defaults (this
         # happens when the defaults themselves sit outside the candidate
-        # budgets, e.g. the sample kernel's [bq, bt, bp, bu] cross over
-        # INTERMEDIATE_BUDGET).  The defaults are what an uncached launch
+        # budgets).  The defaults are what an uncached launch
         # runs anyway, so cache *them*: the entry stays self-consistent
         # (model.time_s == model.default_time_s) instead of pinning a
         # strictly worse-modeled block set.

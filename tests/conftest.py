@@ -1,21 +1,9 @@
-"""Test-session wiring.
-
-If the real ``hypothesis`` package is unavailable (air-gapped containers),
-install the minimal fallback from :mod:`repro.testing.hypothesis_fallback`
-into ``sys.modules`` before any test module imports it.  A normal dev setup
-(``pip install -e .``) gets the real thing and this is a no-op.
-"""
+"""Test-session wiring: make ``repro`` importable without ``PYTHONPATH=src``
+(e.g. a bare ``pytest``)."""
 import importlib.util
 import os
 import sys
 
-# allow running pytest without PYTHONPATH=src (e.g. bare `pytest`)
 _SRC = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
 if _SRC not in sys.path and importlib.util.find_spec("repro") is None:
     sys.path.insert(0, _SRC)
-
-if importlib.util.find_spec("hypothesis") is None:
-    from repro.testing import hypothesis_fallback
-
-    sys.modules["hypothesis"] = hypothesis_fallback
-    sys.modules["hypothesis.strategies"] = hypothesis_fallback.strategies

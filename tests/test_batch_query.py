@@ -1,8 +1,7 @@
 """Batched many-vs-many query engine.
 
 Covers: the many-vs-many Pallas kernel and the fused multi-field kernel vs
-their jnp oracles (property-tested via hypothesis, or the vendored fallback
-on hermetic machines); consistency of the batched kernels with the
+their jnp oracles (property-tested via hypothesis); consistency of the batched kernels with the
 one-vs-many serving kernel; ``SketchCorpus.estimate_batch``; and end-to-end
 identity of ``DatasetSearchIndex.query_batch`` / ``SketchSearchService.
 search_batch`` with a loop of single queries on both backends.
@@ -169,6 +168,23 @@ def test_query_batch_identical_to_query_loop(backend):
     seq = [idx.query(k, v, top_k=4, min_join=20, backend=backend)
            for k, v in queries]
     assert batch == seq          # SearchResult dataclass equality: all stats
+
+
+@pytest.mark.parametrize("n,k", [(11, 6), (300, 128), (5, 5)])
+def test_top_k_breaks_ties_by_ascending_index(n, k):
+    """``ops.top_k`` ranks by (score descending, index ascending), and a
+    row's top-k does not depend on the batch it is ranked in -- the
+    property batched == sequential serving rests on, whatever order the
+    backend's own ``top_k`` gives equal scores."""
+    rng = np.random.default_rng(n)
+    score = rng.integers(-1, 3, size=(16, n)).astype(np.float32)
+    v, i = (np.asarray(x) for x in ops.top_k(jnp.asarray(score), k))
+    for q in range(16):
+        want = np.lexsort((np.arange(n), -score[q]))[:k]
+        assert np.array_equal(i[q], want)
+        assert np.array_equal(v[q], score[q, want])
+        _, i1 = ops.top_k(jnp.asarray(score[q:q + 1]), k)
+        assert np.array_equal(np.asarray(i1)[0], want)
 
 
 def test_query_batch_empty_inputs():

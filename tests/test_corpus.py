@@ -260,6 +260,67 @@ def test_dataset_search_zero_values_survive_aggregation():
     assert set(val.indices) == {3, 5}     # cancellation nudged, not dropped
 
 
+def test_served_vectors_centre_values_and_keep_raw_sample_values():
+    """The value fields are sketched centred on the table's mean (rounded
+    to a power-of-two step at or below the deviation, so integers stay
+    integers); the KMV sample keeps the raw aggregated values."""
+    keys = np.array([3, 3, 5, 9])
+    vals = np.array([101.0, 103.0, 98.0, 102.0])
+    idx = DatasetSearchIndex(m=64, seed=0)
+    (ind, val, sq), raw, shift = idx.served_vectors(keys, vals)
+    rind, rval, _ = idx.vectorize(keys, vals)
+    assert shift == vals.mean() == 101.0          # std 1.87: step 1
+    np.testing.assert_array_equal(ind.values, rind.values)
+    # key 3's row at the mean centres to 0, nudged to 1e-9 like any zero
+    np.testing.assert_allclose(val.values, [2.0, -3.0, 1.0], rtol=1e-8)
+    np.testing.assert_allclose(sq.values, [0.0 + 4.0, 9.0, 1.0], rtol=1e-8)
+    np.testing.assert_allclose(raw.values, rval.values, rtol=1e-9)
+    _, _, shift = idx.served_vectors(keys, vals * 1000 + 0.3)
+    assert shift == 99 * 1024                     # std 1870: step 1024
+
+
+def test_device_correlation_survives_large_value_levels():
+    """The five-estimate correlation of a table 0.6-correlated with the
+    query, both at levels 20-100x their spread: over centred fields the
+    device score lands near 0.6 (uncentred, ``join * sum(v^2) - sum(v)^2``
+    cancels into noise: scores of 0 or 1)."""
+    from repro.data.dataset_search import CFIELD, QFIELD, _corr_scores
+    days = np.arange(730)
+    for seed in range(3):
+        rng = np.random.default_rng(seed)
+        sig, noise = rng.normal(size=730), rng.normal(size=730)
+        idx = DatasetSearchIndex(m=256, seed=1, keep_host_oracle=False)
+        idx.add_table("t", days,
+                      1e5 * (1 + 0.05 * (0.6 * sig + 0.8 * noise)))
+        fields, _, _ = idx.served_vectors(days, 1e3 * (1 + 0.01 * sig))
+        q = tuple(jnp.swapaxes(c.reshape((1, 3) + c.shape[1:]), 0, 1)
+                  for c in idx.family.sketch_rows(list(fields)))
+        est = idx.family.estimate_fields(q, idx.store.buffers(), qmap=QFIELD,
+                                         cmap=CFIELD)[:, :, :1]
+        score = float(_corr_scores(*est, jnp.float32(0))[0, 0])
+        assert abs(score - 0.6) < 0.15, (seed, score)
+
+
+def test_corr_score_weighs_correlation_by_join_evidence():
+    """A perfect correlation over a join just past ``min_join`` must rank
+    below a strong one over a large join: with the weight ``join / (join +
+    min_join)`` the small join scores ~0.5, the large one ~0.9.  With no
+    evidence scale (``min_join <= 0``) the score is plain |corr|."""
+    from repro.data.dataset_search import _corr_scores
+    join = jnp.array([[31.0, 2000.0, 10.0]])
+    zero, one = jnp.zeros_like(join), jnp.ones_like(join)
+    prod = jnp.array([[1.0, 0.9, 1.0]])           # corr 1, 0.9, 1
+    score = np.asarray(_corr_scores(join, zero, zero, one, one, prod,
+                                    jnp.float32(30)))[0]
+    np.testing.assert_allclose(score[:2], [31 / 61, 0.9 * 2000 / 2030],
+                               rtol=1e-6)
+    assert score[2] == -1.0                       # fails min_join
+    assert score[1] > score[0]
+    plain = np.asarray(_corr_scores(join, zero, zero, one, one, prod,
+                                    jnp.float32(0)))[0]
+    np.testing.assert_allclose(plain, [1.0, 0.9, 1.0], rtol=1e-6)
+
+
 def test_sparsevec_sum_duplicates_option():
     v = SparseVec.from_pairs([4, 1, 4, 2], [1.0, 2.0, 3.0, 4.0], 10,
                              sum_duplicates=True)

@@ -120,11 +120,11 @@ def test_registry_validates_name_kind_and_labels():
     with pytest.raises(KeyError, match="undeclared"):
         obs.counter("no.such_metric")
     with pytest.raises(TypeError, match="declared as"):
-        obs.gauge("ops.launches_total", op="x", family="y")
+        obs.gauge("ops.launches_total", op="x", family="y", packed="-")
     with pytest.raises(ValueError, match="requires labels"):
         obs.counter("ops.launches_total", op="x")
-    c1 = obs.counter("ops.launches_total", op="x", family="y")
-    c2 = obs.counter("ops.launches_total", family="y", op="x")
+    c1 = obs.counter("ops.launches_total", op="x", family="y", packed="-")
+    c2 = obs.counter("ops.launches_total", packed="-", family="y", op="x")
     assert c1 is c2                                      # one series per key
     c1.inc(3)
     assert c2.value == 3
@@ -166,15 +166,33 @@ def test_instrumented_records_counts_latency_and_trace():
         assert wrapped() == 42                           # first call
         assert wrapped() == 42                           # steady state
     launches = obs.counter("ops.launches_total", op="icws_estimate",
-                           family="ts")
+                           family="ts", packed="-")
     assert launches.value == 2
     first = obs.histogram("ops.first_call_seconds", op="icws_estimate")
     steady = obs.histogram("ops.launch_seconds", op="icws_estimate",
-                           family="ts")
+                           family="ts", packed="-")
     assert first.count == 1 and steady.count == 1
     evts = [e for e in obs.events() if e["name"] == "ops.icws_estimate"]
     assert len(evts) == 2
     assert all(e["args"]["family"] == "ts" for e in evts)
+
+
+def test_instrumented_labels_packed_value_planes():
+    """A launch that reads a store either way labels its series with
+    whether the value plane arrived packed (bf16) or not."""
+    import jax.numpy as jnp
+    obs.enable()
+    wrapped = obs.instrumented("icws_estimate_fields", packed_arg=1)(
+        lambda a, b: b)
+    with obs.family_context("icws"):
+        wrapped(0, jnp.zeros(2, jnp.bfloat16))
+        wrapped(0, jnp.zeros(2, jnp.bfloat16))
+        wrapped(0, jnp.zeros(2, jnp.float32))
+
+    def count(packed):
+        return obs.counter("ops.launches_total", op="icws_estimate_fields",
+                           family="icws", packed=packed).value
+    assert (count("true"), count("false")) == (2, 1)
 
 
 def test_quality_ewma_arithmetic():

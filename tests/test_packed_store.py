@@ -2,8 +2,9 @@
 and the packed serving path's bitwise contracts.
 
 The packed :class:`repro.data.store.CorpusStore` keeps each family's
-bf16-halfword wire format (two truncated f32 values per int32 word,
-decoded *inside* the estimate kernels) and must satisfy, per family:
+bf16-halfword wire format (every f32 value truncated to a bf16 plane of
+the same shape, decoded *inside* the estimate kernels) and must satisfy,
+per family:
 
   * pack -> unpack roundtrips every component (keys/fingerprints exactly,
     values to their bf16 truncation, idempotent from the first re-pack);
@@ -26,8 +27,7 @@ from repro.data import FAMILY_NAMES, make_family, wmh_storage
 from repro.data.merge import merge_stores
 from repro.data.store import CorpusStore
 from repro.data.synthetic import sparse_pair
-from repro.kernels.packed import (pack_halfwords_f32, packed_width,
-                                  unpack_halfwords_f32)
+from repro.kernels.packed import pack_halfwords_f32, unpack_halfwords_f32
 from repro.serve import SketchSearchService
 
 QMAP = (0, 1, 0, 2, 0, 1)
@@ -57,22 +57,26 @@ def test_codec_roundtrip_is_bf16_truncation():
     x = np.concatenate([rng.normal(size=500).astype(np.float32),
                         np.array([0.0, -0.0, 1e-37, -1e37], np.float32)])
     w = pack_halfwords_f32(jnp.asarray(x.reshape(2, 252)))
-    assert w.shape == (2, 126) and w.dtype == jnp.int32
+    assert w.shape == (2, 252) and w.dtype == jnp.bfloat16
     back = np.asarray(unpack_halfwords_f32(w))
     np.testing.assert_array_equal(back, _bf16_trunc(x).reshape(2, 252))
     # idempotent from the first re-pack: packing the decode is the identity
     np.testing.assert_array_equal(
         np.asarray(pack_halfwords_f32(unpack_halfwords_f32(w))),
         np.asarray(w))
-    # zero words decode to exact zeros (what keeps pad rows inert)
+    # zero halfwords decode to exact zeros (what keeps pad rows inert)
     assert np.all(np.asarray(
-        unpack_halfwords_f32(jnp.zeros((3, 4), jnp.int32))) == 0.0)
+        unpack_halfwords_f32(jnp.zeros((3, 4), jnp.bfloat16))) == 0.0)
 
 
-def test_codec_rejects_odd_width():
-    assert packed_width(5) == 3 and packed_width(6) == 3
-    with pytest.raises(ValueError):
-        pack_halfwords_f32(jnp.zeros((2, 5), jnp.float32))
+def test_codec_keeps_every_width():
+    """One halfword per sample: odd widths pack with no pad slot, and the
+    packed plane has the shape of the values it holds."""
+    x = np.arange(1, 11, dtype=np.float32).reshape(2, 5) / 3
+    w = pack_halfwords_f32(jnp.asarray(x))
+    assert w.shape == (2, 5)
+    np.testing.assert_array_equal(np.asarray(unpack_halfwords_f32(w)),
+                                  _bf16_trunc(x))
 
 
 # ---------------------------------------------------------------------------
@@ -115,8 +119,8 @@ def test_packed_estimates_bitwise_equal_unpacked_on_roundtrip(name):
     crows = _field_rows(fam, rng, 6)
     qrows = _field_rows(fam, np.random.default_rng(32), 2)
     packed = fam.pack_rows(crows)
-    est_p = np.asarray(fam.estimate_fields_packed(qrows, packed,
-                                                  qmap=QMAP, cmap=CMAP))
+    est_p = np.asarray(fam.estimate_fields(qrows, packed,
+                                           qmap=QMAP, cmap=CMAP))
     est_u = np.asarray(fam.estimate_fields(qrows, fam.unpack_rows(packed),
                                            qmap=QMAP, cmap=CMAP))
     assert est_p.shape == est_u.shape == (6, 2, 6)
@@ -154,9 +158,9 @@ def test_packed_spare_capacity_bitwise_inert(name, fill):
     assert exact.capacity == fill
 
     qcomps = _field_rows(fam, np.random.default_rng(7), 2)
-    est_full = np.asarray(fam.estimate_fields_packed(
+    est_full = np.asarray(fam.estimate_fields(
         qcomps, store.buffers(), qmap=QMAP, cmap=CMAP))
-    est_exact = np.asarray(fam.estimate_fields_packed(
+    est_exact = np.asarray(fam.estimate_fields(
         qcomps, exact.buffers(), qmap=QMAP, cmap=CMAP))
     assert est_full.shape == (6, 2, 16)
     assert np.all(est_full[:, :, fill:] == 0.0)
@@ -243,9 +247,8 @@ def test_packed_tenant_scoped_equals_dedicated():
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("m", [8, 9])
 def test_icws_sketch_pack_vals_matches_host_pack(m):
-    """The in-kernel pack epilogue == host pack_halfwords_f32 of the val
-    output (odd m zero-pads the inert trailing slot), including rows that
-    sketched empty."""
+    """The pack_vals output == host pack_halfwords_f32 of the val output
+    (odd m included), including rows that sketched empty."""
     from repro.kernels.icws_sketch import icws_sketch_pallas
     rng = np.random.default_rng(51)
     B, N = 5, 64
@@ -260,12 +263,9 @@ def test_icws_sketch_pack_vals_matches_host_pack(m):
     ref4 = icws_sketch_pallas(w, keys, vals, m=m, seed=3, interpret=True)
     for a, b in zip((fp, val, amin, argk), ref4):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    me = m + (m % 2)
-    host_val = np.zeros((B, me), np.float32)
-    host_val[:, :m] = np.asarray(val)
+    assert packed.shape == (B, m)
     np.testing.assert_array_equal(
-        np.asarray(packed),
-        np.asarray(pack_halfwords_f32(jnp.asarray(host_val))))
-    with pytest.raises(ValueError):
-        icws_sketch_pallas(w, keys, vals, m=m, seed=3, bm=3,
-                           pack_vals=True, interpret=True)
+        np.asarray(packed).view(np.uint16),
+        np.asarray(pack_halfwords_f32(jnp.asarray(val))).view(np.uint16))
+    np.testing.assert_array_equal(np.asarray(unpack_halfwords_f32(packed)),
+                                  _bf16_trunc(np.asarray(val)))

@@ -56,7 +56,7 @@ def test_sample_kernel_matches_ref_smoke():
     kc, vc, tc = _random_sample_rows(rng, 3, 9, 90, 64, SAMPLE_CORPUS_PAD_KEY)
     aq, ac = sample_inclusion_probs(vq, tq), sample_inclusion_probs(vc, tc)
     qmap, cmap = (0, 1, 0, 2, 0, 1), (0, 0, 1, 0, 2, 1)
-    ek = sample_estimate_fields_pallas(kq, vq, aq, kc, vc, ac,
+    ek = sample_estimate_fields_pallas(kq, vq, aq, kc, vc, tc,
                                        qmap=qmap, cmap=cmap, interpret=True)
     er = np.asarray(ref.sample_estimate_fields_ref(kq, vq, aq, kc, vc, ac,
                                                    qmap=qmap, cmap=cmap))
@@ -85,7 +85,7 @@ def test_sample_kernel_matches_ref(data):
     kc, vc, tc = _random_sample_rows(rng, C, P, m, pool,
                                      SAMPLE_CORPUS_PAD_KEY)
     aq, ac = sample_inclusion_probs(vq, tq), sample_inclusion_probs(vc, tc)
-    ek = sample_estimate_fields_pallas(kq, vq, aq, kc, vc, ac,
+    ek = sample_estimate_fields_pallas(kq, vq, aq, kc, vc, tc,
                                        qmap=qmap, cmap=cmap, interpret=True)
     er = np.asarray(ref.sample_estimate_fields_ref(kq, vq, aq, kc, vc, ac,
                                                    qmap=qmap, cmap=cmap))

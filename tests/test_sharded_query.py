@@ -31,11 +31,11 @@ _SCRIPT = textwrap.dedent("""
 
     rng = np.random.default_rng(3)
 
-    # -- sharded_top_k == lax.top_k on tie-heavy scores (values AND indices)
+    # -- sharded_top_k == top_k on tie-heavy scores (values AND indices)
     for n, k in ((11, 6), (8, 3), (5, 5)):
         score = jnp.asarray(
             rng.integers(-1, 3, size=(4, n)).astype(np.float32))
-        v0, i0 = jax.lax.top_k(score, k)
+        v0, i0 = ops.top_k(score, k)
         v1, i1 = ops.sharded_top_k(score, k, mesh=mesh, axis="data")
         assert np.array_equal(np.asarray(v0), np.asarray(v1)), (n, k)
         assert np.array_equal(np.asarray(i0), np.asarray(i1)), (n, k)
