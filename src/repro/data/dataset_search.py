@@ -428,16 +428,42 @@ class DatasetSearchIndex:
     def _query_batch_device(self, queries, top_k: int, min_join: float,
                             tenant: Optional[str] = None
                             ) -> List[List[SearchResult]]:
+        """The spans ``query.prep``, ``query.dispatch``, ``query.wait``,
+        ``query.fetch`` and ``query.rerank`` cover the call in the order it
+        runs; they wrap the statements that already block and add no
+        sync, so the device sees the same stream with obs on or off."""
         if self.store is None:
             raise ValueError("device corpus was not built at ingest "
                              "(index constructed with backend='host')")
-        Q = len(queries)
         field_vecs: List[SparseVec] = []
         samples: List[KMVSketch] = []
-        for keys, values in queries:
-            fields, raw, _ = self.served_vectors(keys, values)
-            field_vecs.extend(fields)
-            samples.append(self.kmv.sketch(raw))
+        with _obs.span("query.prep"):
+            for keys, values in queries:
+                fields, raw, _ = self.served_vectors(keys, values)
+                field_vecs.extend(fields)
+                samples.append(self.kmv.sketch(raw))
+        with _obs.span("query.dispatch"):
+            est, scores, idx, tables = self._rank_on_device(
+                field_vecs, len(queries), top_k, min_join, tenant)
+        with _obs.span("query.wait"):
+            scores, idx = np.asarray(scores), np.asarray(idx)
+        with _obs.span("query.fetch") as sp:
+            join_h, sum_b_h = np.asarray(est[0]), np.asarray(est[2])
+            sp.set("bytes", join_h.nbytes + sum_b_h.nbytes)
+        with _obs.span("query.rerank"):
+            return [
+                self._assemble_results(scores[qi], idx[qi], join_h[qi],
+                                       sum_b_h[qi], samples[qi],
+                                       n_q=max(len(queries[qi][0]), 1),
+                                       top_k=top_k, tables=tables)
+                for qi in range(len(queries))]
+
+    def _rank_on_device(self, field_vecs: List[SparseVec], Q: int,
+                        top_k: int, min_join: float,
+                        tenant: Optional[str]):
+        """Enqueue the query sketch, the fields launch, scoring and top-k;
+        returns the device estimates ``[6, Q, P]``, the candidates' scores
+        and indices, and the tables the indices refer to."""
         # one kernel launch sketches all 3Q query field vectors; each
         # component reshapes [3Q, ...] -> [3, Q, ...] for the fields launch
         qcomps = tuple(
@@ -493,14 +519,7 @@ class DatasetSearchIndex:
                                                 axis=self._corpus_axis)
             else:
                 scores, idx = ops.top_k(score, k)
-        scores, idx = np.asarray(scores), np.asarray(idx)
-        join_h, sum_b_h = np.asarray(est[0]), np.asarray(est[2])
-        return [
-            self._assemble_results(scores[qi], idx[qi], join_h[qi],
-                                   sum_b_h[qi], samples[qi],
-                                   n_q=max(len(queries[qi][0]), 1),
-                                   top_k=top_k, tables=tables)
-            for qi in range(Q)]
+        return est, scores, idx, tables
 
     # -- host oracle (the original numpy implementation, cross-checked) -----
     def _stack(self, field: str) -> StackedWMH:

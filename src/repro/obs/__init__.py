@@ -9,7 +9,8 @@ Pieces:
 
 * :mod:`repro.obs.metrics` -- counters, gauges, mergeable log-bucket
   latency histograms; ``describe_metrics()`` / Prometheus exporters.
-* :mod:`repro.obs.trace` -- structured spans, Chrome-trace / JSONL export.
+* :mod:`repro.obs.trace` -- structured spans, Chrome-trace / JSONL export;
+  enabled spans also reach a running JAX profiler trace.
 * :mod:`repro.obs.quality` -- sampled estimator re-scores, rolling
   ppm-error gauge per family.
 * :mod:`repro.obs.instrument` -- the ``@instrumented`` decorator applied
@@ -21,7 +22,8 @@ Every metric name is declared in :mod:`repro.obs.registry`; the generated
 ``METRICS.md`` is pinned against that registry by analysis rule OB002.
 
 This package is pure stdlib (no jax import) so the static-analysis pass
-and the CLI stay usable on machines without the accelerator stack.
+and the CLI stay usable on machines without the accelerator stack; jax is
+imported lazily, once obs is enabled, and only where it is installed.
 """
 from __future__ import annotations
 

@@ -47,8 +47,12 @@ def enabled() -> bool:
 
 
 def enable() -> None:
+    """Start recording; spans also reach the JAX profiler's trace and
+    backend compiles are counted (:func:`repro.obs.trace.bind_jax`)."""
     global _ENABLED
     _ENABLED = True
+    from repro.obs import trace     # trace imports this module
+    trace.bind_jax()
 
 
 def disable() -> None:

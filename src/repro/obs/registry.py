@@ -32,17 +32,11 @@ SPECS = (
              "op, ambient serving family ('-' outside a family context) "
              "and whether the corpus value plane was a packed store's bf16 "
              "plane ('true'/'false'; '-' for ops without one)."},
-    {"name": "ops.launch_seconds", "type": "histogram",
-     "labels": ("op", "family", "packed"), "unit": "s",
-     "help": "Steady-state wall time per public ops launch (dispatch time "
-             "on async backends; end-to-end under the CPU interpreter). "
-             "The first observed call per op lands in "
-             "ops.first_call_seconds instead."},
-    {"name": "ops.first_call_seconds", "type": "histogram",
-     "labels": ("op",), "unit": "s",
-     "help": "Wall time of the first observed call per op -- jit trace + "
-             "compile + execute -- split out so compile cost never "
-             "pollutes the steady-state latency histogram."},
+    {"name": "ops.compiles_total", "type": "counter",
+     "labels": ("op",), "unit": "compiles",
+     "help": "Backend compiles (JAX's backend_compile_duration event), by "
+             "the innermost open ops.* span of the compiling thread ('-' "
+             "outside one): which launch recompiled."},
     {"name": "ops.autotune_resolved_total", "type": "counter",
      "labels": ("kernel", "source"), "unit": "resolutions",
      "help": "Autotune block-size resolutions at trace time: "
@@ -75,10 +69,6 @@ SPECS = (
      "labels": ("endpoint",), "unit": "s",
      "help": "Per-request latency by endpoint: 'search' times one query, "
              "'search_batch' times one micro-batch."},
-    {"name": "serve.batched_query_seconds", "type": "histogram",
-     "labels": (), "unit": "s",
-     "help": "Per-query latency through the batched endpoint: micro-batch "
-             "wall time / batch size, one observation per micro-batch."},
     {"name": "serve.tenant_request_seconds", "type": "histogram",
      "labels": ("tenant",), "unit": "s",
      "help": "Per-request latency of tenant-scoped queries, by tenant."},

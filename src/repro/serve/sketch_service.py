@@ -291,8 +291,6 @@ class SketchSearchService:
             if _obs.enabled():
                 _obs.counter("serve.batches_total").inc()
                 _obs.counter("serve.batch_queries_total").inc(len(chunk))
-                _obs.histogram("serve.batched_query_seconds").record(
-                    dt / len(chunk))
         return results
 
     def describe(self, tenant: Optional[str] = None) -> Dict[str, object]:

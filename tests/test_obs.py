@@ -19,6 +19,7 @@ import pytest
 
 from repro import obs
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 from repro.obs.__main__ import main as obs_cli
 from repro.obs.metrics import (N_FINITE, RECENT_WINDOW, Histogram,
                                bucket_bounds, bucket_index)
@@ -168,13 +169,108 @@ def test_instrumented_records_counts_latency_and_trace():
     launches = obs.counter("ops.launches_total", op="icws_estimate",
                            family="ts", packed="-")
     assert launches.value == 2
-    first = obs.histogram("ops.first_call_seconds", op="icws_estimate")
-    steady = obs.histogram("ops.launch_seconds", op="icws_estimate",
-                           family="ts", packed="-")
-    assert first.count == 1 and steady.count == 1
+    assert set(obs.describe_metrics()["metrics"]) == {"ops.launches_total"}
     evts = [e for e in obs.events() if e["name"] == "ops.icws_estimate"]
     assert len(evts) == 2
-    assert all(e["args"]["family"] == "ts" for e in evts)
+    assert all(e["args"]["family"] == "ts" and e["dur"] >= 0.0
+               for e in evts)
+
+
+class _Annotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records its calls."""
+    calls: list = []
+
+    def __init__(self, name):
+        self.name = name
+        self.calls.append(("new", name))
+
+    def __enter__(self):
+        self.calls.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.calls.append(("exit", self.name))
+        return False
+
+
+def test_enabled_span_enters_and_exits_the_profiler_annotation(monkeypatch):
+    monkeypatch.setattr(obs_trace, "_ANNOTATION", _Annotation)
+    monkeypatch.setattr(_Annotation, "calls", [])
+    obs.enable()
+    with obs.span("serve.search_batch", batch=3):
+        with obs.span("query.fetch") as sp:
+            sp.set("bytes", 64)
+    assert _Annotation.calls == [
+        ("new", "serve.search_batch"), ("enter", "serve.search_batch"),
+        ("new", "query.fetch"), ("enter", "query.fetch"),
+        ("exit", "query.fetch"), ("exit", "serve.search_batch")]
+
+
+def test_disabled_span_reads_no_clock_and_enters_no_annotation(monkeypatch):
+    monkeypatch.setattr(obs_trace, "_ANNOTATION", _Annotation)
+    monkeypatch.setattr(_Annotation, "calls", [])
+
+    def clock():
+        raise AssertionError("a disabled span read the clock")
+    monkeypatch.setattr(obs_trace.time, "perf_counter", clock)
+    with obs.span("query.prep") as sp:
+        assert sp is obs_trace._NULL
+    assert _Annotation.calls == [] and obs.events() == []
+
+
+def test_ring_events_carry_id_and_parent():
+    obs.enable()
+    with obs.span("serve.search_batch"):
+        with obs.span("query.dispatch"):
+            with obs.span("ops.top_k"):
+                pass
+        with obs.span("query.wait"):
+            pass
+    with obs.span("serve.search"):
+        pass
+    by = {e["name"]: e for e in obs.events()}
+    assert len({e["id"] for e in by.values()}) == 5
+    top = by["serve.search_batch"]["id"]
+    assert by["serve.search_batch"]["parent"] is None
+    assert by["serve.search"]["parent"] is None
+    assert by["query.dispatch"]["parent"] == top
+    assert by["query.wait"]["parent"] == top
+    assert by["ops.top_k"]["parent"] == by["query.dispatch"]["id"]
+
+
+def test_import_repro_obs_does_not_import_jax():
+    import os
+    import subprocess
+    import sys
+    code = ("import sys, repro.obs; "
+            "sys.exit(1 if 'jax' in sys.modules else 0)")
+    env = {**os.environ, "REPRO_OBS": "1",
+           "PYTHONPATH": os.pathsep.join(sys.path)}
+    assert subprocess.run([sys.executable, "-c", code], env=env,
+                          timeout=120).returncode == 0
+
+
+def test_compiles_are_counted_by_the_innermost_ops_span():
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    # a hit in a persistent cache would load the program, not compile it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        obs.enable()
+        step = obs.instrumented("compile_probe")(
+            jax.jit(lambda x: x * 3.0 + 1.0))
+        step(jnp.zeros(5))
+        step(jnp.zeros(5))                               # cached
+        step(jnp.zeros(7))                               # a new shape
+        assert obs.counter("ops.compiles_total",
+                           op="compile_probe").value == 2
+        jax.jit(lambda x: x - 2.0)(jnp.zeros(3))         # outside any op
+        assert obs.counter("ops.compiles_total", op="-").value >= 1
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
 
 
 def test_instrumented_labels_packed_value_planes():
