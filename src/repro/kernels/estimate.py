@@ -17,19 +17,30 @@ Four variants share the kernel math:
     variant broadcasts its single row; collisions are formed blockwise as
     ``[BQ, BP, BM]`` in VMEM and reduced immediately -- no ``[Q, P, m]``
     tensor is ever materialized.
-  * ``estimate_fields_pallas``            -- the fused multi-field form of the
-    above: query/corpus sketches arrive stacked per *field* (``[F, Q, m]`` /
-    ``[C, P, m]``) and a static list of (query-field, corpus-field) pairs is
-    folded into the leading grid dimension, so e.g. all six §1.3 field-pair
-    estimates of a dataset-search batch run as a single kernel launch.  The
-    served kernel: a packed store's bf16 value plane runs through it too.
+  * ``estimate_fields_pallas``            -- the served kernel: query and
+    corpus sketches stacked per *field* (``[F, Q, m]`` / ``[C, P, m]``) and
+    a static list of (query-field, corpus-field) pairs, so all six §1.3
+    field-pair estimates of a dataset-search batch run as one launch; a
+    packed store's bf16 value plane runs through it too.  It is laid out
+    for the TPU's [8, 128] vregs: queries on sublanes, corpus rows on
+    lanes, the m slots walked one by one, so every (query, row) sum is an
+    elementwise accumulation with no lane or sublane reduction.  The grid
+    ``(Q/bq, P/bp, G, m/bm)`` puts the pairs that share a corpus field
+    next to each other, so a corpus tile is read from HBM once per call
+    and serves all its pairs while it is resident.  On the first pass over
+    P each query slot is broadcast along the lanes into VMEM and stays
+    there; each corpus tile is transposed once (slots on sublanes, rows on
+    lanes), its values decoded and ``1/v^2`` taken.  The importance term
+    is ``vq * vc * max(1/vq^2, 1/vc^2)``: the ratio above up to rounding,
+    with no divide in the inner loop.
 
 ``linear_estimate_fields_pallas`` serves the linear families (CS/JL): per-rep
 MXU dots of the stacked tables, the same pair folding.
 
-Grids keep the m dimension innermost and accumulate into per-(row[, col])
-output blocks.  Pure VPU elementwise + reduction; one pass over the sketches,
-no intermediate [P, m] / [Q, P, m] materialization in HBM.
+The other grids keep the m dimension innermost and accumulate into
+per-(row[, col]) output blocks: VPU elementwise work reduced over each
+``[.., bm]`` block.  No kernel materializes a [P, m] / [Q, P, m]
+intermediate in HBM.
 """
 from __future__ import annotations
 
@@ -38,6 +49,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 # Pad sentinels -- the single definition of the padding convention every
@@ -239,28 +251,110 @@ def estimate_many_vs_many_pallas(fq, vq, fpc, vc, *, bq: int = 8,
     return cnt[:Q, :P], sw[:Q, :P]
 
 
-def _fields_kernel(fq_ref, vq_ref, fc_ref, vc_ref, cnt_ref, sw_ref):
-    m_idx = pl.program_id(3)
-    # a packed store's bf16 value tile decodes here, in VMEM (exact), to
-    # the same tile the unpacked path reads -- one reduction for both
-    cnt, sw = _mvm_body(fq_ref[0, :, :], vq_ref[0, :, :], fc_ref[0, :, :],
-                        vc_ref[0, :, :].astype(jnp.float32))
+# The fields kernel's vector layout.  A vreg is [8 sublanes, 128 lanes]:
+# queries ride the sublanes, corpus rows the lanes, and the m sketch slots
+# are walked one at a time, so each (query, row) output element is a plain
+# elementwise running sum over the slots -- no lane or sublane reduction.
+_LANES = 128
+_SUBLANES = 8
+# Slots per inner-loop iteration (unrolled): enough independent vreg work
+# to keep the four VALU slots of a v5e bundle busy.
+_SLOT_UNROLL = 16
+# The collision guard ``fq >= 0`` folded into the data: every negative
+# corpus fingerprint (pad -2, empty slot -1) is rewritten to _CORPUS_NEG and
+# every negative query fingerprint to _QUERY_NEG before the slot loop.  The
+# two never equal each other or a live (>= 0) fingerprint, so a plain
+# equality test is exactly ``(fq == fc) & (fq >= 0)``.  The pad sentinels
+# alone would not do: an empty query slot and an empty corpus slot both
+# hold -1.
+_CORPUS_NEG = -2 ** 31
+_QUERY_NEG = -2 ** 31 + 1
+# VMEM the compiler may use beyond the blocks and scratch the launch sizes.
+_VMEM_HEADROOM = 8 * 2 ** 20
 
-    @pl.when(m_idx == 0)
+
+def _recip_sq(v):
+    """``1 / v^2``, 0 where ``v`` is 0: with it the importance term
+    ``vq*vc / min(vq^2, vc^2)`` is ``vq * vc * max(rq, rc)``, both in f32."""
+    return jnp.where(v == 0, 0.0, 1.0 / (v * v))
+
+
+def _fields_kernel(fq_ref, vq_ref, fc_ref, vc_ref, cnt_ref, sw_ref,
+                   fct_ref, vct_ref, rct_ref, qf_ref, qv_ref, qr_ref, *,
+                   qsel, first, m):
+    bq, bm = fq_ref.shape[1:]
+    lanes = fc_ref.shape[1] // _LANES
+    p, g, mi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    f = qsel(g)
+    t0 = mi * bm
+
+    @pl.when(p == 0)
+    def _broadcast_queries():
+        # on the first pass over P, every slot of the query block becomes a
+        # [bq, 128] tile holding each query's fingerprint, value and 1/v^2
+        # along all the lanes; the tiles stay in VMEM for the rest of P
+        def chunk(c, carry):
+            c0 = pl.multiple_of(c * _LANES, _LANES)
+            fq = fq_ref[0, :, pl.ds(c0, _LANES)]
+            vq = vq_ref[0, :, pl.ds(c0, _LANES)]
+            for dst, x in ((qf_ref, jnp.where(fq >= 0, fq, _QUERY_NEG)),
+                           (qv_ref, vq), (qr_ref, _recip_sq(vq))):
+                dst[f, pl.ds(t0 + c0, _LANES)] = jnp.broadcast_to(
+                    x.T[:, :, None], (_LANES, bq, _LANES))
+            return carry
+
+        jax.lax.fori_loop(0, bm // _LANES, chunk, 0)
+
+    # a corpus tile arrives [bp, bm], rows on sublanes; when its field's
+    # first pair comes up it is transposed to [bm, 128] per 128 rows, the
+    # packed bf16 value plane decoded and 1/v^2 taken on the way
+    @pl.when(first(g) == 1)
+    def _transpose_corpus():
+        for j in range(lanes):
+            rows = pl.ds(j * _LANES, _LANES)
+            fc = fc_ref[0, rows, :]
+            fct_ref[j] = jnp.where(fc >= 0, fc, _CORPUS_NEG).T
+            vc = vc_ref[0, rows, :].astype(jnp.float32).T
+            vct_ref[j] = vc
+            rct_ref[j] = _recip_sq(vc)
+
+    @pl.when(mi == 0)
     def _init():
-        cnt_ref[0, :, :] = cnt
-        sw_ref[0, :, :] = sw
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+        sw_ref[...] = jnp.zeros_like(sw_ref)
 
-    @pl.when(m_idx != 0)
-    def _acc():
-        cnt_ref[0, :, :] = cnt_ref[0, :, :] + cnt
-        sw_ref[0, :, :] = sw_ref[0, :, :] + sw
+    tile = (lanes, bq, _LANES)
+    cols = [pl.ds(j * _LANES, _LANES) for j in range(lanes)]
+
+    def slots(i, acc):
+        cnt, sw = acc
+        for u in range(_SLOT_UNROLL):
+            t = i * _SLOT_UNROLL + u
+            fq, vq, rq = (jnp.broadcast_to(r[f, t0 + t], tile)
+                          for r in (qf_ref, qv_ref, qr_ref))
+            # slot t of each corpus row, repeated down the sublanes (a
+            # sublane-broadcast load on the chip)
+            fc, vc, rc = (jnp.broadcast_to(r[:, pl.ds(t, 1), :], tile)
+                          for r in (fct_ref, vct_ref, rct_ref))
+            hit = fc == fq
+            cnt = cnt + jnp.where(hit, 1.0, 0.0)
+            sw = sw + jnp.where(hit, vc * vq * jnp.maximum(rc, rq), 0.0)
+        return cnt, sw
+
+    # the block's live slots only (m may end inside it); the slots of a
+    # last partial iteration are padding, which never collides
+    live = jnp.minimum(bm, m - t0)
+    acc = tuple(jnp.stack([r[0, :, c] for c in cols]) for r in (cnt_ref, sw_ref))
+    cnt, sw = jax.lax.fori_loop(0, pl.cdiv(live, _SLOT_UNROLL), slots, acc)
+    for j, c in enumerate(cols):
+        cnt_ref[0, :, c] = cnt[j]
+        sw_ref[0, :, c] = sw[j]
 
 
 @functools.partial(jax.jit, static_argnames=("qmap", "cmap", "bq", "bp", "bm",
                                              "interpret"))
-def estimate_fields_pallas(fq, vq, fpc, vc, *, qmap, cmap, bq: int = 8,
-                           bp: int = 128, bm: int = 128,
+def estimate_fields_pallas(fq, vq, fpc, vc, *, qmap, cmap, bq: int = 16,
+                           bp: int = 512, bm: int = 256,
                            interpret: bool = False):
     """Fused multi-field many-vs-many partials in ONE kernel launch; matches
     :func:`repro.kernels.ref.estimate_fields_ref`.
@@ -273,16 +367,32 @@ def estimate_fields_pallas(fq, vq, fpc, vc, *, qmap, cmap, bq: int = 8,
       qmap/cmap: static same-length tuples of field indices; estimate ``g``
         pairs query field ``qmap[g]`` with corpus field ``cmap[g]`` (§1.3
         uses six such pairs over F = C = 3 fields).
+      bq/bp/bm: at most ``bq`` queries (a multiple of 8) per block; ``bp``
+        corpus rows (a multiple of 128) per block; a sketch no wider than
+        ``bm`` is one slot block, a wider one goes in 128-slot blocks.
     Returns (n_collide [G, Q, P], s_weight [G, Q, P]) with G = len(qmap).
 
-    The pair list is folded into the leading grid dimension: the query /
-    corpus BlockSpec index maps gather the right field via a static lookup
-    table, so no per-pair [Q, m] / [P, m] copies are ever stacked in HBM.
+    Grid ``(Q/bq, P/bp, G, m/bm)``, the pairs ordered by corpus field, so
+    each corpus tile is read from HBM once and serves every pair of its
+    field while it is resident; the query blocks stay resident too.  In a
+    step queries lie on sublanes and corpus rows on lanes.  The first pass
+    over P broadcasts each query slot along the lanes into VMEM (1.5 KiB
+    per field, slot and query of the block: 18 MiB at F = 3, m = 256 and
+    16 queries, which a v5e's 128 MiB holds); each corpus tile is
+    transposed once, so a slot of 128 rows loads as one sublane-broadcast
+    row; and every (query, row) pair accumulates its collisions and
+    importance terms elementwise, slot after slot.  So each output element
+    sums its own m terms in slot order, whatever Q, P, the blocks, the
+    shard or the tenant slice: batched, sharded, tenant and packed
+    launches are bitwise equal to their plain forms.
     """
     F, Q, m = fq.shape
     C, P, _ = fpc.shape
     qmap, cmap = _check_maps(qmap, cmap, F, C)
     G = len(qmap)
+    bq = min(bq, _SUBLANES * pl.cdiv(Q, _SUBLANES))
+    m_lanes = _LANES * pl.cdiv(m, _LANES)
+    bm = m_lanes if m_lanes <= bm else _LANES
     q_pad = (-Q) % bq
     p_pad = (-P) % bp
     m_pad = (-m) % bm
@@ -296,20 +406,53 @@ def estimate_fields_pallas(fq, vq, fpc, vc, *, qmap, cmap, bq: int = 8,
     Qp, mp = fq.shape[1:]
     Pp = fpc.shape[1]
 
-    qsel, csel = _lut(qmap), _lut(cmap)
-    grid = (G, Qp // bq, Pp // bp, mp // bm)
+    # pairs grouped by corpus field: a field's tile stays put across them,
+    # transposed by the first; a sketch of several slot blocks brings a new
+    # tile every step
+    n_m = mp // bm
+    order = sorted(range(G), key=lambda g: cmap[g])
+    gsel = _lut(order)
+    qsel = _lut(tuple(qmap[g] for g in order))
+    csel = _lut(tuple(cmap[g] for g in order))
+    first = _lut(tuple(int(n_m > 1 or i == 0
+                           or cmap[order[i]] != cmap[order[i - 1]])
+                       for i in range(G)))
+    last_q = (qmap[order[-1]], n_m - 1)
+
+    def q_index(qb, p, g, mi):
+        # past the first pass over P the query block never changes, so it
+        # is not fetched again
+        return (jnp.where(p == 0, qsel(g), last_q[0]), qb,
+                jnp.where(p == 0, mi, last_q[1]))
+
+    scratch = [pltpu.VMEM((bp // _LANES, bm, _LANES), jnp.int32),
+               pltpu.VMEM((bp // _LANES, bm, _LANES), jnp.float32),
+               pltpu.VMEM((bp // _LANES, bm, _LANES), jnp.float32),
+               pltpu.VMEM((F, mp, bq, _LANES), jnp.int32),
+               pltpu.VMEM((F, mp, bq, _LANES), jnp.float32),
+               pltpu.VMEM((F, mp, bq, _LANES), jnp.float32)]
+    # scratch, plus every block twice (double-buffered), 4 bytes an element
+    vmem = 4 * (3 * bp * bm + 3 * F * mp * bq * _LANES
+                + 2 * (2 * bq * bm + 2 * bp * bm + 2 * bq * bp))
+    kernel = functools.partial(_fields_kernel, qsel=qsel, first=first, m=m)
     cnt, sw = pl.pallas_call(
-        _fields_kernel,
-        grid=grid,
+        kernel,
+        grid=(Qp // bq, Pp // bp, G, n_m),
         in_specs=[
-            pl.BlockSpec((1, bq, bm), lambda g, q, p, mi: (qsel(g), q, mi)),
-            pl.BlockSpec((1, bq, bm), lambda g, q, p, mi: (qsel(g), q, mi)),
-            pl.BlockSpec((1, bp, bm), lambda g, q, p, mi: (csel(g), p, mi)),
-            pl.BlockSpec((1, bp, bm), lambda g, q, p, mi: (csel(g), p, mi)),
+            pl.BlockSpec((1, bq, bm), q_index),
+            pl.BlockSpec((1, bq, bm), q_index),
+            pl.BlockSpec((1, bp, bm), lambda qb, p, g, mi: (csel(g), p, mi)),
+            pl.BlockSpec((1, bp, bm), lambda qb, p, g, mi: (csel(g), p, mi)),
         ],
         out_specs=[pl.BlockSpec((1, bq, bp),
-                                lambda g, q, p, mi: (g, q, p))] * 2,
+                                lambda qb, p, g, mi: (gsel(g), qb, p))] * 2,
         out_shape=[jax.ShapeDtypeStruct((G, Qp, Pp), jnp.float32)] * 2,
+        scratch_shapes=scratch,
+        # sequential grid: the query broadcast of the first pass over P
+        # and each corpus tile's transpose are reused by later steps
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 4,
+            vmem_limit_bytes=vmem + _VMEM_HEADROOM),
         interpret=interpret,
     )(fq.astype(jnp.int32), vq.astype(jnp.float32),
       fpc.astype(jnp.int32), vc)

@@ -99,7 +99,7 @@ KERNELS: Dict[str, Dict] = {
         "report_kernel": "estimate_fields_pallas",
         "dims": ("G", "Q", "P", "m"),
         "key_dims": ("m",),
-        "defaults": {"bq": 8, "bp": 128, "bm": 128},
+        "defaults": {"bq": 16, "bp": 512, "bm": 256},
         "candidates": {"bq": (8, 16, 32, 64), "bp": (128, 256, 512, 1024),
                        "bm": (128, 256, 512)},
         # resolve-time clamping of row dims: block -> (shape dim, tile base)

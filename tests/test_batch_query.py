@@ -81,6 +81,84 @@ def test_fields_kernel_matches_ref(data):
                                atol=1e-4 * scale)
 
 
+def _fields_inputs(seed, Q, P, m, bf16, F=3, C=3):
+    """Field-stacked sketches with collisions, pad sentinels (-1, -2) among
+    the fingerprints and zeros among the values of both sides."""
+    rng = np.random.default_rng(seed)
+    fq = rng.integers(-2, 30, size=(F, Q, m)).astype(np.int32)
+    fc = rng.integers(-2, 30, size=(C, P, m)).astype(np.int32)
+    vq = rng.normal(size=(F, Q, m)).astype(np.float32)
+    vc = rng.normal(size=(C, P, m)).astype(np.float32)
+    vq[rng.random(vq.shape) < 0.2] = 0.0
+    vc[rng.random(vc.shape) < 0.2] = 0.0
+    vc = jnp.asarray(vc, jnp.bfloat16 if bf16 else jnp.float32)
+    return jnp.asarray(fq), jnp.asarray(vq), jnp.asarray(fc), vc
+
+
+_ONE_PAIR = ((2,), (1,))
+
+
+@pytest.mark.parametrize("Q,P,m,bf16,maps", [
+    (1, 1, 200, False, "fields"),
+    (1, 130, 256, True, "fields"),
+    (8, 130, 256, True, "fields"),
+    (8, 300, 200, False, "one"),
+    (9, 300, 200, True, "one"),
+    (9, 1, 256, False, "one"),
+    (9, 130, 300, True, "fields"),     # three 128-slot blocks
+    (16, 300, 256, False, "fields"),
+    (16, 130, 200, True, "fields"),
+])
+def test_fields_kernel_layout_cases_match_ref(Q, P, m, bf16, maps):
+    """The fields kernel against its oracle across the padding paths: Q
+    off the query block, P off the row block, m off the lane width or over
+    one slot block, packed (bf16) corpus values, zero values and negative
+    fingerprints on both sides, the §1.3 maps and a single pair."""
+    from repro.data.dataset_search import CFIELD, QFIELD
+    qmap, cmap = (QFIELD, CFIELD) if maps == "fields" else _ONE_PAIR
+    fq, vq, fc, vc = _fields_inputs(Q * 1000 + P + m, Q, P, m, bf16)
+    cnt_k, sw_k = estimate_fields_pallas(fq, vq, fc, vc, qmap=qmap,
+                                         cmap=cmap, interpret=True)
+    cnt_r, sw_r = ref.estimate_fields_ref(fq, vq, fc, vc.astype(jnp.float32),
+                                          qmap=qmap, cmap=cmap)
+    assert cnt_k.shape == (len(qmap), Q, P)
+    np.testing.assert_array_equal(np.asarray(cnt_k), np.asarray(cnt_r))
+    sw_r = np.asarray(sw_r)
+    scale = max(1.0, float(np.max(np.abs(sw_r))))
+    np.testing.assert_allclose(np.asarray(sw_k), sw_r, rtol=1e-4,
+                               atol=1e-4 * scale)
+
+
+def test_fields_kernel_query_row_independent_of_batch():
+    """A query's row alone (Q = 1) is bitwise its row in a 16-query batch."""
+    from repro.data.dataset_search import CFIELD, QFIELD
+    fq, vq, fc, vc = _fields_inputs(5, 16, 300, 256, True)
+    batch = estimate_fields_pallas(fq, vq, fc, vc, qmap=QFIELD, cmap=CFIELD,
+                                   interpret=True)
+    for i in (0, 9, 15):
+        alone = estimate_fields_pallas(fq[:, i:i + 1], vq[:, i:i + 1], fc, vc,
+                                       qmap=QFIELD, cmap=CFIELD,
+                                       interpret=True)
+        for a, b in zip(alone, batch):
+            np.testing.assert_array_equal(np.asarray(a)[:, 0],
+                                          np.asarray(b)[:, i])
+
+
+def test_fields_kernel_table_column_independent_of_slice():
+    """A table's column from a slice of the corpus rows is bitwise its
+    column in the whole corpus, wherever the slice starts."""
+    from repro.data.dataset_search import CFIELD, QFIELD
+    fq, vq, fc, vc = _fields_inputs(7, 9, 1100, 200, False)
+    whole = estimate_fields_pallas(fq, vq, fc, vc, qmap=QFIELD, cmap=CFIELD,
+                                   interpret=True)
+    lo, hi = 600, 1050      # other row blocks and lanes than in the whole
+    part = estimate_fields_pallas(fq, vq, fc[:, lo:hi], vc[:, lo:hi],
+                                  qmap=QFIELD, cmap=CFIELD, interpret=True)
+    for a, b in zip(part, whole):
+        np.testing.assert_array_equal(np.asarray(a),
+                                      np.asarray(b)[:, :, lo:hi])
+
+
 def test_many_vs_many_rows_equal_one_vs_many():
     """Each row of the batched kernel == the one-vs-many serving kernel."""
     rng = np.random.default_rng(11)

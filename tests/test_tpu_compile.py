@@ -85,9 +85,9 @@ def _sketch_case(kernel, n, pack_vals, b=3 * Q):
             args)
 
 
-def _icws_fields_case(packed, p):
-    q = [((F, Q, M), jnp.int32), ((F, Q, M), jnp.float32),
-         ((F, Q), jnp.float32)]
+def _icws_fields_case(packed, p, n_q=Q):
+    q = [((F, n_q, M), jnp.int32), ((F, n_q, M), jnp.float32),
+         ((F, n_q), jnp.float32)]
     dtype = jnp.bfloat16 if packed else jnp.float32
     c = [((F, p, M), jnp.int32), ((F, p, M), dtype), ((F, p), jnp.float32)]
     return (lambda *a: ops.icws_estimate_fields(*a, qmap=QFIELD,
@@ -148,6 +148,14 @@ CASES = {
     "countsketch_dense": _cs_dense_case,
     "icws_estimate_fields": lambda: _icws_fields_case(False, P_LAKE),
     "icws_estimate_fields_packed": lambda: _icws_fields_case(True, P_LAKE),
+    # the other query counts the scan sees: one (the sequential path) and
+    # a batch that pads to the 16-query block
+    "icws_estimate_fields_q1": lambda: _icws_fields_case(False, P_LAKE, 1),
+    "icws_estimate_fields_q9": lambda: _icws_fields_case(False, P_LAKE, 9),
+    "icws_estimate_fields_packed_q1": lambda: _icws_fields_case(True, P_LAKE,
+                                                                1),
+    "icws_estimate_fields_packed_q9": lambda: _icws_fields_case(True, P_LAKE,
+                                                                9),
     "cs_estimate_fields": lambda: _linear_fields_case("cs", False),
     "cs_estimate_fields_packed": lambda: _linear_fields_case("cs", True),
     "jl_estimate_fields": lambda: _linear_fields_case("jl", False),
