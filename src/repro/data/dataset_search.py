@@ -70,6 +70,7 @@ from repro import obs as _obs
 from repro.kernels import ops
 
 from .families import FAMILY_NAMES, make_family, wmh_storage
+from .ingest import padded_width
 from .merge import build_sharded
 from .store import CorpusStore
 
@@ -442,7 +443,8 @@ class DatasetSearchIndex:
                 fields, raw, _ = self.served_vectors(keys, values)
                 field_vecs.extend(fields)
                 samples.append(self.kmv.sketch(raw))
-        with _obs.span("query.dispatch"):
+        lanes = self._sketch_lanes(field_vecs) if _obs.enabled() else {}
+        with _obs.span("query.dispatch", **lanes):
             est, scores, idx, tables = self._rank_on_device(
                 field_vecs, len(queries), top_k, min_join, tenant)
         with _obs.span("query.wait"):
@@ -457,6 +459,19 @@ class DatasetSearchIndex:
                                        n_q=max(len(queries[qi][0]), 1),
                                        top_k=top_k, tables=tables)
                 for qi in range(len(queries))]
+
+    def _sketch_lanes(self, field_vecs: List[SparseVec]) -> dict:
+        """The query sketch batch's ``rows`` (3Q), padded non-zero
+        ``width`` (:func:`padded_width`, as the batch padding computes it)
+        and real non-zeros ``nnz``, for the ``query.dispatch`` span,
+        counted in ``query.sketch_lanes_total``.  Host-known: no sync."""
+        nnz = np.fromiter((v.nnz for v in field_vecs), np.int64,
+                          count=len(field_vecs))
+        rows, width, real = len(field_vecs), padded_width(nnz), int(nnz.sum())
+        for kind, n in (("real", real), ("pad", rows * width - real)):
+            _obs.counter("query.sketch_lanes_total", family=self.family.name,
+                         kind=kind).inc(n)
+        return {"rows": rows, "width": width, "nnz": real}
 
     def _rank_on_device(self, field_vecs: List[SparseVec], Q: int,
                         top_k: int, min_join: float,

@@ -52,6 +52,14 @@ def _keys_i32(idx_cat: np.ndarray) -> np.ndarray:
     return (idx_cat & np.int64(0xFFFFFFFF)).astype(np.uint32).astype(np.int32)
 
 
+def padded_width(nnz: np.ndarray, bucket: int = 256) -> int:
+    """The ``N`` of a padded ``[B, N]`` batch of vectors with ``nnz``
+    non-zeros: the longest rounded up to a ``bucket`` multiple, at least
+    one bucket."""
+    longest = int(nnz.max()) if nnz.size else 0
+    return max(bucket, -(-longest // bucket) * bucket)
+
+
 def pad_sparse_batch(vecs: Sequence[SparseVec], *, bucket: int = 256
                      ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """Pad sparse vectors into the ICWS kernel's ``[B, N]`` layout.
@@ -68,8 +76,7 @@ def pad_sparse_batch(vecs: Sequence[SparseVec], *, bucket: int = 256
     """
     B = len(vecs)
     nnz = np.fromiter((v.nnz for v in vecs), np.int64, count=B)
-    max_nnz = int(nnz.max()) if B else 0
-    N = max(bucket, -(-max_nnz // bucket) * bucket)
+    N = padded_width(nnz, bucket)
     w = np.zeros((B, N), np.float32)
     keys = np.zeros((B, N), np.int32)
     vals = np.zeros((B, N), np.float32)
@@ -95,8 +102,7 @@ def pad_linear_batch(vecs: Sequence[SparseVec], *, bucket: int = 256
     """
     B = len(vecs)
     nnz = np.fromiter((v.nnz for v in vecs), np.int64, count=B)
-    max_nnz = int(nnz.max()) if B else 0
-    N = max(bucket, -(-max_nnz // bucket) * bucket)
+    N = padded_width(nnz, bucket)
     keys = np.zeros((B, N), np.int32)
     vals = np.zeros((B, N), np.float32)
     active = nnz > 0 if B else np.zeros(0, bool)

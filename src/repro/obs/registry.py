@@ -87,6 +87,11 @@ SPECS = (
     {"name": "serve.rows_ingested_total", "type": "counter",
      "labels": (), "unit": "rows",
      "help": "Raw table rows ingested into the serving index."},
+    {"name": "query.sketch_lanes_total", "type": "counter",
+     "labels": ("family", "kind"), "unit": "lanes",
+     "help": "Lanes of the query sketch launches (rows x padded non-zero "
+             "width), by family: kind='real' holds a non-zero, 'pad' is "
+             "padding."},
     # -- estimator quality ---------------------------------------------------
     {"name": "quality.ppm_error", "type": "gauge",
      "labels": ("family",), "unit": "ppm",
