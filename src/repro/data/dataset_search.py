@@ -24,7 +24,9 @@ buffers; a single query is simply the Q=1 case.  Candidate selection (the
 among sufficiently-joinable ones) happens in jnp before any result leaves
 the device; the host then refines the correlation of just those
 candidates from the matched KMV samples, in one vectorized pass, and keeps
-the top-k.
+the top-k.  Only the candidates' scores, indices, join and sum estimates
+are copied to the host (``Q * k * 16`` bytes a call), never a ``[Q, P]``
+plane.
 
 The value and squared-value fields are sketched over values centred on
 their table's mean, rounded to a multiple of the largest power of two at
@@ -82,6 +84,9 @@ FIELDS = ("key_indicator", "values", "values_sq")
 _IND, _VAL, _SQ = 0, 1, 2
 QFIELD = (_IND, _VAL, _IND, _SQ, _IND, _VAL)
 CFIELD = (_IND, _IND, _VAL, _IND, _SQ, _VAL)
+# the estimates the host re-rank reads, gathered at the candidates on the
+# device: join (0) and sum_b (2), in the same order
+CANDIDATE_PLANES = (0, 2)
 
 # Candidates per query the device hands the host to re-rank by sample
 # correlation (at least top_k).  The device score only preselects, so a
@@ -354,21 +359,22 @@ class DatasetSearchIndex:
                 [(np.asarray(keys), np.asarray(values))], top_k, min_join,
                 tenant=tenant)[0]
 
-    def _assemble_results(self, scores, idx, join_h, sum_b_h, q_sample,
+    def _assemble_results(self, scores, idx, join_c, sum_b_c, q_sample,
                           n_q: int, top_k: int,
                           tables: Optional[List[TableSketch]] = None
                           ) -> List[SearchResult]:
         """Host epilogue shared by all device paths: drop min_join failures,
         refine corr from the matched KMV samples, keep the ``top_k``
-        candidates by refined |corr|.  ``tables`` is the candidate list the
-        estimate columns (and ``idx``) index into -- the full corpus by
-        default, a tenant's subset under tenant-scoped queries."""
+        candidates by refined |corr|.  ``join_c`` and ``sum_b_c`` are the
+        candidates' estimates, aligned with ``idx``.  ``tables`` is the
+        list ``idx`` indexes into -- the full corpus by default, a tenant's
+        subset under tenant-scoped queries."""
         if tables is None:
             tables = self.tables
-        idx = np.asarray(idx)[np.asarray(scores) >= 0]   # min_join passed
-        cand = [tables[int(i)] for i in idx]
+        keep = np.asarray(scores) >= 0                   # min_join passed
+        cand = [tables[int(i)] for i in np.asarray(idx)[keep]]
         return self._rank(cand, self._sample_corrs(q_sample, cand),
-                          join_h[idx], sum_b_h[idx], n_q, top_k)
+                          join_c[keep], sum_b_c[keep], n_q, top_k)
 
     @staticmethod
     def _rank(cand: List[TableSketch], corr, join, sum_b_c, n_q: int,
@@ -432,7 +438,10 @@ class DatasetSearchIndex:
         """The spans ``query.prep``, ``query.dispatch``, ``query.wait``,
         ``query.fetch`` and ``query.rerank`` cover the call in the order it
         runs; they wrap the statements that already block and add no
-        sync, so the device sees the same stream with obs on or off."""
+        sync, so the device sees the same stream with obs on or off.
+        ``query.fetch`` copies the candidates' scores, indices, join and
+        sum estimates in one transfer; its ``bytes``, ``Q * k * 16``, are
+        counted in ``query.fetch_bytes_total``."""
         if self.store is None:
             raise ValueError("device corpus was not built at ingest "
                              "(index constructed with backend='host')")
@@ -445,17 +454,21 @@ class DatasetSearchIndex:
                 samples.append(self.kmv.sketch(raw))
         lanes = self._sketch_lanes(field_vecs) if _obs.enabled() else {}
         with _obs.span("query.dispatch", **lanes):
-            est, scores, idx, tables = self._rank_on_device(
+            *cands, tables = self._rank_on_device(
                 field_vecs, len(queries), top_k, min_join, tenant)
         with _obs.span("query.wait"):
-            scores, idx = np.asarray(scores), np.asarray(idx)
+            jax.block_until_ready(cands)
         with _obs.span("query.fetch") as sp:
-            join_h, sum_b_h = np.asarray(est[0]), np.asarray(est[2])
-            sp.set("bytes", join_h.nbytes + sum_b_h.nbytes)
+            scores, idx, join_c, sum_b_c = fetched = jax.device_get(cands)
+            if _obs.enabled():
+                nbytes = sum(a.nbytes for a in fetched)
+                sp.set("bytes", nbytes)
+                _obs.counter("query.fetch_bytes_total",
+                             family=self.family.name).inc(nbytes)
         with _obs.span("query.rerank"):
             return [
-                self._assemble_results(scores[qi], idx[qi], join_h[qi],
-                                       sum_b_h[qi], samples[qi],
+                self._assemble_results(scores[qi], idx[qi], join_c[qi],
+                                       sum_b_c[qi], samples[qi],
                                        n_q=max(len(queries[qi][0]), 1),
                                        top_k=top_k, tables=tables)
                 for qi in range(len(queries))]
@@ -476,9 +489,10 @@ class DatasetSearchIndex:
     def _rank_on_device(self, field_vecs: List[SparseVec], Q: int,
                         top_k: int, min_join: float,
                         tenant: Optional[str]):
-        """Enqueue the query sketch, the fields launch, scoring and top-k;
-        returns the device estimates ``[6, Q, P]``, the candidates' scores
-        and indices, and the tables the indices refer to."""
+        """Enqueue the query sketch, the fields launch, scoring, top-k and
+        the candidates' gather; returns the candidates' scores, indices,
+        join and sum_b estimates (each ``[Q, k]``, on the device) and the
+        tables the indices refer to."""
         # one kernel launch sketches all 3Q query field vectors; each
         # component reshapes [3Q, ...] -> [3, Q, ...] for the fields launch
         qcomps = tuple(
@@ -534,7 +548,9 @@ class DatasetSearchIndex:
                                                 axis=self._corpus_axis)
             else:
                 scores, idx = ops.top_k(score, k)
-        return est, scores, idx, tables
+        join_c, sum_b_c = ops.take_candidates(est, idx,
+                                              planes=CANDIDATE_PLANES)
+        return scores, idx, join_c, sum_b_c, tables
 
     # -- host oracle (the original numpy implementation, cross-checked) -----
     def _stack(self, field: str) -> StackedWMH:

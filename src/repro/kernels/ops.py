@@ -509,6 +509,20 @@ def sharded_top_k(score, k: int, *, mesh, axis="data"):
     return v, jnp.take_along_axis(idx, pos, axis=-1)
 
 
+@_obs.instrumented("take_candidates")
+@functools.partial(jax.jit, static_argnames=("planes",))
+def take_candidates(est, idx, *, planes):
+    """The ranked candidates' estimates, gathered on the device.
+
+    ``est`` is a ``[G, Q, P]`` stack of estimate planes and ``idx`` the
+    ``[Q, k]`` candidate indices :func:`top_k` or :func:`sharded_top_k`
+    picked from it; returns one ``[Q, k]`` array per plane ``g`` in
+    ``planes``, bit for bit ``est[g][q, idx[q]]``.  Only these small
+    arrays need leave the device, never a ``[Q, P]`` plane.
+    """
+    return tuple(jnp.take_along_axis(est[g], idx, axis=-1) for g in planes)
+
+
 @functools.lru_cache(maxsize=None)
 def _sharded_topk_fn(mesh, axis: str, kl: int, shard: int, ndim: int):
     def body(s):

@@ -92,6 +92,11 @@ SPECS = (
      "help": "Lanes of the query sketch launches (rows x padded non-zero "
              "width), by family: kind='real' holds a non-zero, 'pad' is "
              "padding."},
+    {"name": "query.fetch_bytes_total", "type": "counter",
+     "labels": ("family",), "unit": "B",
+     "help": "Bytes the device query path copies to the host (the "
+             "candidates' scores, indices, join and sum estimates, Q x k "
+             "x 16 B a call), by family."},
     # -- estimator quality ---------------------------------------------------
     {"name": "quality.ppm_error", "type": "gauge",
      "labels": ("family",), "unit": "ppm",

@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.data.dataset_search import CFIELD, QFIELD
+from repro.data.dataset_search import (CANDIDATE_PLANES, CANDIDATES, CFIELD,
+                                       QFIELD)
 from repro.data.families import make_family, wmh_storage
 from repro.kernels import ops
 
@@ -180,6 +181,23 @@ def test_ranking_top_k_compiles_for_v5e(one_chip, on_tpu):
     XLA program, no Pallas kernel)."""
     shape = jax.ShapeDtypeStruct((Q, P_LAKE), jnp.float32, sharding=one_chip)
     jax.jit(lambda s: ops.top_k(s, 128)).lower(shape).compile()
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_candidate_gather_compiles_for_v5e(chips, topo, on_tpu):
+    """The candidates' join and sum gathered from the estimate planes of
+    the 2^18-row lake, on one chip and with the planes split over the
+    four chips of a v5e host (an XLA program, no Pallas kernel)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(topo.devices[:chips], ("data",))
+    est = jax.ShapeDtypeStruct((6, Q, P_LAKE), jnp.float32,
+                               sharding=NamedSharding(mesh, P(None, None,
+                                                              "data")))
+    idx = jax.ShapeDtypeStruct((Q, CANDIDATES), jnp.int32,
+                               sharding=NamedSharding(mesh, P()))
+    compiled = jax.jit(lambda e, i: ops.take_candidates(
+        e, i, planes=CANDIDATE_PLANES)).lower(est, idx).compile()
+    assert [o.shape for o in compiled.out_info] == [(Q, CANDIDATES)] * 2
 
 
 def test_sharded_scan_compiles_for_v5e_2x2(topo, on_tpu):
