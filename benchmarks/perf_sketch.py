@@ -18,8 +18,8 @@ import numpy as np
 
 from repro.core import ICWS, SparseVec, inner_fast, make, stack_wmh
 from repro.core.icws import StackedICWS
-from repro.data import FAMILY_NAMES, make_family, wmh_storage
-from repro.data.corpus import SketchCorpus, pad_sparse_batch
+from repro.data import (FAMILY_NAMES, make_family, pad_sparse_batch,
+                        sketch_batch, wmh_storage)
 from repro.data.families import TSFamily
 from repro.data.merge import merge_stores, partition_by_key
 from repro.data.store import CorpusStore
@@ -65,27 +65,33 @@ def run(fast: bool = False):
                                           seed=0)[0].block_until_ready())
     emit("perf/kernel/icws_sketch", us / B_, f"B={B_} N={N} m={m}")
 
-    fp, val, _, _ = out
-    na = jnp.ones((B_,), jnp.float32)
-    _, us = timed(lambda: ops.icws_estimate(fp, val, na, fp, val, na)
-                  .block_until_ready())
-    emit("perf/kernel/estimate", us / B_, f"pairs={B_} m={m}")
+    # all B x B sketch pairs in one single-field launch
+    one = (out[0][None], out[1][None], jnp.ones((1, B_), jnp.float32))
+    _, us = timed(lambda: ops.icws_estimate_fields(
+        *one, *one, qmap=(0,), cmap=(0,)).block_until_ready())
+    emit("perf/kernel/estimate", us / B_ ** 2, f"pairs={B_ ** 2} m={m}")
 
     # device-resident corpus: one-vs-many query hot loop.  The query sketch
-    # stays [1, m] end to end -- no stack_wmh([q] * P)-style restacking, no
-    # [P, m] query tile; the kernel broadcasts it across the corpus grid.
+    # stays [1, 1, m] end to end -- no stack_wmh([q] * P)-style restacking,
+    # no [P, m] query tile; the kernel broadcasts it across the corpus grid.
     P, mc = (16, 128) if fast else (64, 256)
     lake = [sparse_pair(rng, n=600, nnz=120, overlap=0.2)[0]
             for _ in range(P)]
-    corpus = SketchCorpus(m=mc, seed=1)
-    _, us = timed(lambda: corpus.add_batch(lake))
+    corpus = CorpusStore(m=mc, fields=1)
+    _, us = timed(lambda: corpus.append(*sketch_batch(lake, m=mc, seed=1)))
     emit("perf/corpus/ingest", us / P, f"tables={P} m={mc}")
 
     query = sparse_pair(rng, n=600, nnz=120, overlap=0.2)[0]
-    fq, vq, nq, _ = corpus.sketch_query(query)
-    corpus.estimate(fq, vq, nq[0]).block_until_ready()      # warm the jit
-    dev, us = timed(lambda: corpus.estimate(fq, vq, nq[0]).block_until_ready(),
-                    repeat=3)
+    fq, vq, nq, _ = sketch_batch([query], m=mc, seed=1)
+
+    def query_1vn():
+        est = ops.icws_estimate_fields(fq[None], vq[None], nq[None],
+                                       *corpus.buffers()[:3], qmap=(0,),
+                                       cmap=(0,))
+        return est[0, 0, :P].block_until_ready()
+
+    query_1vn()                                             # warm the jit
+    dev, us = timed(query_1vn, repeat=3)
     emit("perf/corpus/query_1vN", us / P, f"tables={P} m={mc}")
 
     # cross-check: device one-vs-many vs host ICWS estimator on *identical*
@@ -634,7 +640,7 @@ def run(fast: bool = False):
         def bare():
             return None
 
-        wrapped = _obs.instrumented("icws_estimate")(bare)
+        wrapped = _obs.instrumented("icws_estimate_fields")(bare)
         n_calls = 10_000
         t0 = time.perf_counter()
         for _ in range(n_calls):

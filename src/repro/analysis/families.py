@@ -11,8 +11,7 @@ fails in serving.  This checker proves, per name in ``FAMILY_NAMES``:
 * FC001 -- a class in the module declares ``name = "<family>"`` and
   (transitively through same-module bases) implements the full contract:
   ``components``, ``storage_doubles_per_row``, ``sketch_rows``,
-  ``estimate_fields``, ``estimate_fields_sharded``, ``merge_rows``,
-  ``host_oracle``.
+  ``estimate_fields``, ``merge_rows``, ``host_oracle``.
 * FC002 -- ``make_family`` can construct it (the name appears as a string
   constant in its body).
 * FC003 -- every parameterized sweep covers it (the sweep file iterates
@@ -33,7 +32,6 @@ CONTRACT_MEMBERS = (
     "storage_doubles_per_row",
     "sketch_rows",
     "estimate_fields",
-    "estimate_fields_sharded",
     "merge_rows",
     "host_oracle",
 )

@@ -428,9 +428,9 @@ class DatasetSearchIndex:
                                            qmap=QFIELD, cmap=CFIELD)
 
     def _estimate_sharded(self, qcomps, cbufs):
-        return self.family.estimate_fields_sharded(
-            qcomps, cbufs, qmap=QFIELD, cmap=CFIELD, mesh=self.mesh,
-            axis=self._corpus_axis)
+        return ops.estimate_fields_sharded(
+            self.family.estimate_fields, qcomps, cbufs, qmap=QFIELD,
+            cmap=CFIELD, mesh=self.mesh, axis=self._corpus_axis)
 
     def _query_batch_device(self, queries, top_k: int, min_join: float,
                             tenant: Optional[str] = None

@@ -18,10 +18,11 @@ engine need to know about one sketch method:
     no sentinel machinery at all.
   * **Sketch launch** (``sketch_rows``): one padded-batch Pallas launch
     turning B sparse vectors into B buffer rows.
-  * **Fused estimate launch** (``estimate_fields`` and its mesh-sharded
-    twin): all (query-field, corpus-field) pairs of a Q-query batch in ONE
-    kernel launch -- the ICWS collision kernel, or MXU matmuls with a
-    median-of-reps epilogue for the linear families.
+  * **Fused estimate launch** (``estimate_fields``): all (query-field,
+    corpus-field) pairs of a Q-query batch in ONE kernel launch -- the ICWS
+    collision kernel, or MXU matmuls with a median-of-reps epilogue for the
+    linear families.  A sharded store runs the same launch per corpus
+    shard through :func:`repro.kernels.ops.estimate_fields_sharded`.
   * **Storage accounting** (``storage_doubles_per_row``) and storage-matched
     construction (:func:`make_family`), using the same per-method sizing as
     :mod:`repro.core.registry` so cross-family comparisons are
@@ -132,13 +133,6 @@ class ICWSFamily:
         fpc, vc, nc = c[0], c[1], c[2]
         return ops.icws_estimate_fields(fq, vq, nq, fpc, vc, nc,
                                         qmap=qmap, cmap=cmap)
-
-    def estimate_fields_sharded(self, q, c, *, qmap, cmap, mesh, axis):
-        fq, vq, nq = q[0], q[1], q[2]
-        fpc, vc, nc = c[0], c[1], c[2]
-        return ops.icws_estimate_fields_sharded(fq, vq, nq, fpc, vc, nc,
-                                                qmap=qmap, cmap=cmap,
-                                                mesh=mesh, axis=axis)
 
     @property
     def packed_components(self) -> Tuple[ComponentSpec, ...]:
@@ -373,11 +367,6 @@ class _LinearFamily:
     def estimate_fields(self, q, c, *, qmap, cmap):
         return ops.linear_estimate_fields(q[0], c[0], qmap=qmap, cmap=cmap)
 
-    def estimate_fields_sharded(self, q, c, *, qmap, cmap, mesh, axis):
-        return ops.linear_estimate_fields_sharded(q[0], c[0], qmap=qmap,
-                                                  cmap=cmap, mesh=mesh,
-                                                  axis=axis)
-
     @property
     def packed_components(self) -> Tuple[ComponentSpec, ...]:
         """Packed wire format: every table cell bf16-truncated -- half the
@@ -485,13 +474,6 @@ class _SamplingFamily:
         kc, vc, tc = c
         return ops.sample_estimate_fields(kq, vq, tq, kc, vc, tc,
                                           qmap=qmap, cmap=cmap)
-
-    def estimate_fields_sharded(self, q, c, *, qmap, cmap, mesh, axis):
-        kq, vq, tq = q
-        kc, vc, tc = c
-        return ops.sample_estimate_fields_sharded(kq, vq, tq, kc, vc, tc,
-                                                  qmap=qmap, cmap=cmap,
-                                                  mesh=mesh, axis=axis)
 
     @property
     def packed_components(self) -> Tuple[ComponentSpec, ...]:

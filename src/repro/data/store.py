@@ -106,8 +106,8 @@ class CorpusStore:
     with ``m`` given) is the ICWS family, preserving the original
     ``(fingerprints, values, norms)`` three-buffer contract bit for bit.
 
-    ``fields=1`` is the generic single-corpus case (see
-    :class:`repro.data.corpus.SketchCorpus`, a thin view over this class);
+    ``fields=1`` is the generic single-corpus case, estimated with
+    :func:`repro.kernels.ops.icws_estimate_fields` at ``qmap = cmap = (0,)``;
     ``fields=3`` backs :class:`repro.data.dataset_search.DatasetSearchIndex`
     with all three §1.3 field corpora in one canonical stack.
 

@@ -5,8 +5,8 @@ Kernels (each = pallas_call + explicit BlockSpec VMEM tiling):
   * countsketch  -- MXU-formulated CountSketch (dense gradients + padded
                     sparse batches for the CS serving family)
   * jl_sketch    -- MXU-formulated JL/AMS projection of padded sparse batches
-  * estimate     -- fused Algorithm-5 estimator partials + per-rep MXU dot
-                    estimation for the linear families
+  * estimate     -- fused multi-field Algorithm-5 estimator partials +
+                    per-rep MXU dot estimation for the linear families
   * sample_estimate -- unaligned key-match contraction for the sampling
                     families (Threshold/Priority Sampling rows)
 
@@ -14,8 +14,7 @@ Kernels (each = pallas_call + explicit BlockSpec VMEM tiling):
 """
 from . import ops, ref
 from .countsketch import countsketch_pallas, countsketch_sparse_pallas
-from .estimate import (estimate_one_vs_many_pallas, estimate_partials_pallas,
-                       linear_estimate_fields_pallas)
+from .estimate import estimate_fields_pallas, linear_estimate_fields_pallas
 from .icws_sketch import icws_sketch_pallas
 from .jl_sketch import jl_sketch_pallas
 from .sample_estimate import (sample_estimate_fields_pallas,
@@ -23,6 +22,5 @@ from .sample_estimate import (sample_estimate_fields_pallas,
 
 __all__ = ["ops", "ref", "icws_sketch_pallas", "countsketch_pallas",
            "countsketch_sparse_pallas", "jl_sketch_pallas",
-           "estimate_partials_pallas", "estimate_one_vs_many_pallas",
-           "linear_estimate_fields_pallas", "sample_estimate_fields_pallas",
-           "sample_inclusion_probs"]
+           "estimate_fields_pallas", "linear_estimate_fields_pallas",
+           "sample_estimate_fields_pallas", "sample_inclusion_probs"]

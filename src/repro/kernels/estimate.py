@@ -1,46 +1,33 @@
-"""Pallas TPU kernels: fused sketch-pair estimator partials (Algorithm 5, line 3).
+"""Pallas TPU kernels: fused field-pair estimators, one per geometry
+(the third, key match, is :mod:`repro.kernels.sample_estimate`).
 
-For P sketch pairs with m samples each, computes per pair:
-  * the collision count  ``sum_t 1[fp_a == fp_b]``
-  * the importance sum   ``sum_t 1[...] * va*vb / min(va^2, vb^2)``
+``estimate_fields_pallas`` -- slot-aligned collisions (ICWS/DMH,
+Algorithm 5, line 3).  For every (query, corpus row) pair it computes
 
-Four variants share the kernel math:
+  * the collision count  ``sum_t 1[fp_q == fp_c]``
+  * the importance sum   ``sum_t 1[...] * vq*vc / min(vq^2, vc^2)``
 
-  * ``estimate_partials_pallas``          -- pairwise: A and B are both [P, m].
-  * ``estimate_one_vs_many_pallas``       -- one query sketch [1, m] against a
-    corpus [P, m].  The query block is *broadcast* across the P grid dimension
-    via its BlockSpec index map (every grid step re-reads block (0, mi)), so
-    the caller never tiles the query into a [P, m] copy.
-  * ``estimate_many_vs_many_pallas``      -- Q query sketches against a corpus
-    [P, m] in ONE launch, grid ``(Q/BQ, P/BP, m/BM)``.  Each query block is
-    re-read across the P grid dimension exactly the way the one-vs-many
-    variant broadcasts its single row; collisions are formed blockwise as
-    ``[BQ, BP, BM]`` in VMEM and reduced immediately -- no ``[Q, P, m]``
-    tensor is ever materialized.
-  * ``estimate_fields_pallas``            -- the served kernel: query and
-    corpus sketches stacked per *field* (``[F, Q, m]`` / ``[C, P, m]``) and
-    a static list of (query-field, corpus-field) pairs, so all six §1.3
-    field-pair estimates of a dataset-search batch run as one launch; a
-    packed store's bf16 value plane runs through it too.  It is laid out
-    for the TPU's [8, 128] vregs: queries on sublanes, corpus rows on
-    lanes, the m slots walked one by one, so every (query, row) sum is an
-    elementwise accumulation with no lane or sublane reduction.  The grid
-    ``(Q/bq, P/bp, G, m/bm)`` puts the pairs that share a corpus field
-    next to each other, so a corpus tile is read from HBM once per call
-    and serves all its pairs while it is resident.  On the first pass over
-    P each query slot is broadcast along the lanes into VMEM and stays
-    there; each corpus tile is transposed once (slots on sublanes, rows on
-    lanes), its values decoded and ``1/v^2`` taken.  The importance term
-    is ``vq * vc * max(1/vq^2, 1/vc^2)``: the ratio above up to rounding,
-    with no divide in the inner loop.
+Query and corpus sketches are stacked per *field* (``[F, Q, m]`` /
+``[C, P, m]``) and paired by a static list of (query-field, corpus-field)
+pairs, so all six §1.3 field-pair estimates of a dataset-search batch run
+as one launch; a packed store's bf16 value plane runs through it too.  A
+single field pair (``qmap = cmap = (0,)``) is the plain many-vs-many
+estimate, and one query (Q = 1) or one row (P = 1) its one-vs-many and
+pairwise forms.  It is laid out for the TPU's [8, 128] vregs: queries on
+sublanes, corpus rows on lanes, the m slots walked one by one, so every
+(query, row) sum is an elementwise accumulation with no lane or sublane
+reduction.  The grid ``(Q/bq, P/bp, G, m/bm)`` puts the pairs that share a
+corpus field next to each other, so a corpus tile is read from HBM once per
+call and serves all its pairs while it is resident.  On the first pass over
+P each query slot is broadcast along the lanes into VMEM and stays there;
+each corpus tile is transposed once (slots on sublanes, rows on lanes), its
+values decoded and ``1/v^2`` taken.  The importance term is
+``vq * vc * max(1/vq^2, 1/vc^2)``: the ratio above up to rounding, with no
+divide in the inner loop.
 
-``linear_estimate_fields_pallas`` serves the linear families (CS/JL): per-rep
-MXU dots of the stacked tables, the same pair folding.
-
-The other grids keep the m dimension innermost and accumulate into
-per-(row[, col]) output blocks: VPU elementwise work reduced over each
-``[.., bm]`` block.  No kernel materializes a [P, m] / [Q, P, m]
-intermediate in HBM.
+``linear_estimate_fields_pallas`` -- MXU dots (CS/JL): per-rep dots of the
+stacked tables, with the same pair folding.  Neither kernel
+materializes a [Q, P, m] intermediate in HBM.
 """
 from __future__ import annotations
 
@@ -52,8 +39,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-# Pad sentinels -- the single definition of the padding convention every
-# estimate variant (and the corpus store / sharded wrappers) relies on:
+# Pad sentinels -- the single definition of the padding convention the
+# estimate kernels and the corpus store's inert rows rely on:
 # query padding (-1, also the empty-sketch fingerprint) and corpus padding
 # (-2) never equal each other or a live fingerprint (>= 0), and the kernel
 # guard ``fq >= 0`` keeps both out of the estimate.
@@ -83,172 +70,6 @@ def _check_maps(qmap, cmap, F, C):
     if min(qmap) < 0 or max(qmap) >= F or min(cmap) < 0 or max(cmap) >= C:
         raise ValueError("field map index out of range")
     return qmap, cmap
-
-
-def _est_kernel(fpa_ref, va_ref, fpb_ref, vb_ref, cnt_ref, sw_ref):
-    m_idx = pl.program_id(1)
-
-    fpa, fpb = fpa_ref[:, :], fpb_ref[:, :]
-    va, vb = va_ref[:, :], vb_ref[:, :]
-    collide = (fpa == fpb) & (fpa >= 0)
-    q = jnp.minimum(va * va, vb * vb)
-    safe_q = jnp.where(collide & (q > 0), q, 1.0)
-    term = jnp.where(collide, va * vb / safe_q, 0.0)
-    cnt = collide.astype(jnp.float32).sum(axis=1)
-    sw = term.sum(axis=1)
-
-    @pl.when(m_idx == 0)
-    def _init():
-        cnt_ref[:] = cnt
-        sw_ref[:] = sw
-
-    @pl.when(m_idx != 0)
-    def _acc():
-        cnt_ref[:] = cnt_ref[:] + cnt
-        sw_ref[:] = sw_ref[:] + sw
-
-
-@functools.partial(jax.jit, static_argnames=("bp", "bm", "interpret"))
-def estimate_partials_pallas(fpa, va, fpb, vb, *, bp: int = 8, bm: int = 128,
-                             interpret: bool = False):
-    """Matches :func:`repro.kernels.ref.estimate_partials_ref`."""
-    P, m = fpa.shape
-    p_pad = (-P) % bp
-    m_pad = (-m) % bm
-    if p_pad or m_pad:
-        fpa = jnp.pad(fpa, ((0, p_pad), (0, m_pad)), constant_values=QUERY_PAD_FP)
-        fpb = jnp.pad(fpb, ((0, p_pad), (0, m_pad)), constant_values=CORPUS_PAD_FP)
-        va = jnp.pad(va, ((0, p_pad), (0, m_pad)))
-        vb = jnp.pad(vb, ((0, p_pad), (0, m_pad)))
-    Pp, mp = fpa.shape
-    grid = (Pp // bp, mp // bm)
-    cnt, sw = pl.pallas_call(
-        _est_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((bp, bm), lambda p, mi: (p, mi))] * 4,
-        out_specs=[pl.BlockSpec((bp,), lambda p, mi: (p,))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((Pp,), jnp.float32)] * 2,
-        interpret=interpret,
-    )(fpa.astype(jnp.int32), va.astype(jnp.float32),
-      fpb.astype(jnp.int32), vb.astype(jnp.float32))
-    return cnt[:P], sw[:P]
-
-
-@functools.partial(jax.jit, static_argnames=("bp", "bm", "interpret"))
-def estimate_one_vs_many_pallas(fq, vq, fpc, vc, *, bp: int = 64, bm: int = 128,
-                                interpret: bool = False):
-    """One query sketch against a P-row corpus; matches
-    :func:`repro.kernels.ref.estimate_one_vs_many_ref`.
-
-    Args: fq/vq [1, m] (or [m]) query fingerprints/values; fpc/vc [P, m]
-    corpus.  Returns (n_collide [P], s_weight [P]).  The query block is
-    broadcast by its index map -- no [P, m] tiling of the query ever exists.
-    """
-    fq = fq.reshape(1, -1)
-    vq = vq.reshape(1, -1)
-    P, m = fpc.shape
-    p_pad = (-P) % bp
-    m_pad = (-m) % bm
-    if m_pad:
-        # pad fingerprints to *different* sentinels so padding never collides
-        fq = jnp.pad(fq, ((0, 0), (0, m_pad)), constant_values=QUERY_PAD_FP)
-        vq = jnp.pad(vq, ((0, 0), (0, m_pad)))
-    if p_pad or m_pad:
-        fpc = jnp.pad(fpc, ((0, p_pad), (0, m_pad)), constant_values=CORPUS_PAD_FP)
-        vc = jnp.pad(vc, ((0, p_pad), (0, m_pad)))
-    Pp, mp = fpc.shape
-    grid = (Pp // bp, mp // bm)
-    cnt, sw = pl.pallas_call(
-        _est_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bm), lambda p, mi: (0, mi)),   # query: broadcast
-            pl.BlockSpec((1, bm), lambda p, mi: (0, mi)),
-            pl.BlockSpec((bp, bm), lambda p, mi: (p, mi)),  # corpus: tiled
-            pl.BlockSpec((bp, bm), lambda p, mi: (p, mi)),
-        ],
-        out_specs=[pl.BlockSpec((bp,), lambda p, mi: (p,))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((Pp,), jnp.float32)] * 2,
-        interpret=interpret,
-    )(fq.astype(jnp.int32), vq.astype(jnp.float32),
-      fpc.astype(jnp.int32), vc.astype(jnp.float32))
-    return cnt[:P], sw[:P]
-
-
-def _mvm_body(fq, vq, fc, vc):
-    """Blockwise many-vs-many partials: [BQ, BM] x [BP, BM] -> [BQ, BP].
-
-    The [BQ, BP, BM] collision tensor lives only in VMEM for this block.
-    """
-    fqb, fcb = fq[:, None, :], fc[None, :, :]
-    vqb, vcb = vq[:, None, :], vc[None, :, :]
-    collide = (fqb == fcb) & (fqb >= 0)
-    q = jnp.minimum(vqb * vqb, vcb * vcb)
-    safe_q = jnp.where(collide & (q > 0), q, 1.0)
-    term = jnp.where(collide, vqb * vcb / safe_q, 0.0)
-    return collide.astype(jnp.float32).sum(axis=2), term.sum(axis=2)
-
-
-def _mvm_kernel(fq_ref, vq_ref, fc_ref, vc_ref, cnt_ref, sw_ref):
-    m_idx = pl.program_id(2)
-    cnt, sw = _mvm_body(fq_ref[:, :], vq_ref[:, :], fc_ref[:, :], vc_ref[:, :])
-
-    @pl.when(m_idx == 0)
-    def _init():
-        cnt_ref[:, :] = cnt
-        sw_ref[:, :] = sw
-
-    @pl.when(m_idx != 0)
-    def _acc():
-        cnt_ref[:, :] = cnt_ref[:, :] + cnt
-        sw_ref[:, :] = sw_ref[:, :] + sw
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("bq", "bp", "bm", "interpret"))
-def estimate_many_vs_many_pallas(fq, vq, fpc, vc, *, bq: int = 8,
-                                 bp: int = 128, bm: int = 128,
-                                 interpret: bool = False):
-    """Q query sketches against a P-row corpus in one launch; matches
-    :func:`repro.kernels.ref.estimate_many_vs_many_ref`.
-
-    Args: fq/vq [Q, m] query fingerprints/values; fpc/vc [P, m] corpus.
-    Returns (n_collide [Q, P], s_weight [Q, P]).  Grid (Q/bq, P/bp, m/bm),
-    m innermost; the query block's index map ignores the P grid index, so
-    every query block is re-read (broadcast) across the corpus dimension and
-    no [Q, P, m] intermediate ever exists outside a [bq, bp, bm] VMEM tile.
-    """
-    Q, m = fq.shape
-    P, _ = fpc.shape
-    q_pad = (-Q) % bq
-    p_pad = (-P) % bp
-    m_pad = (-m) % bm
-    if q_pad or m_pad:
-        # distinct pad sentinels: query padding (-1) never collides with
-        # corpus padding (-2), and fq >= 0 guards both out of the estimate
-        fq = jnp.pad(fq, ((0, q_pad), (0, m_pad)), constant_values=QUERY_PAD_FP)
-        vq = jnp.pad(vq, ((0, q_pad), (0, m_pad)))
-    if p_pad or m_pad:
-        fpc = jnp.pad(fpc, ((0, p_pad), (0, m_pad)), constant_values=CORPUS_PAD_FP)
-        vc = jnp.pad(vc, ((0, p_pad), (0, m_pad)))
-    Qp, mp = fq.shape
-    Pp = fpc.shape[0]
-    grid = (Qp // bq, Pp // bp, mp // bm)
-    cnt, sw = pl.pallas_call(
-        _mvm_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bq, bm), lambda q, p, mi: (q, mi)),   # re-read over p
-            pl.BlockSpec((bq, bm), lambda q, p, mi: (q, mi)),
-            pl.BlockSpec((bp, bm), lambda q, p, mi: (p, mi)),
-            pl.BlockSpec((bp, bm), lambda q, p, mi: (p, mi)),
-        ],
-        out_specs=[pl.BlockSpec((bq, bp), lambda q, p, mi: (q, p))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((Qp, Pp), jnp.float32)] * 2,
-        interpret=interpret,
-    )(fq.astype(jnp.int32), vq.astype(jnp.float32),
-      fpc.astype(jnp.int32), vc.astype(jnp.float32))
-    return cnt[:Q, :P], sw[:Q, :P]
 
 
 # The fields kernel's vector layout.  A vreg is [8 sublanes, 128 lanes]:

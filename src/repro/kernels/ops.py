@@ -19,10 +19,7 @@ from repro.roofline import autotune
 
 from . import ref
 from .countsketch import countsketch_pallas, countsketch_sparse_pallas
-from .estimate import (CORPUS_PAD_FP, estimate_fields_pallas,
-                       estimate_many_vs_many_pallas,
-                       estimate_one_vs_many_pallas, estimate_partials_pallas,
-                       linear_estimate_fields_pallas)
+from .estimate import estimate_fields_pallas, linear_estimate_fields_pallas
 from .dmh_sketch import dmh_sketch_pallas, dmh_sketch_scatter
 from .icws_sketch import icws_sketch_pallas
 from .jl_sketch import jl_sketch_pallas
@@ -143,26 +140,6 @@ def jl_sketch(keys, vals, *, m: int, seed: int = 0):
                             interpret=_interpret())
 
 
-@_obs.instrumented("estimate_partials")
-def estimate_partials(fpa, va, fpb, vb):
-    """Fused Algorithm-5 partial sums for P sketch pairs."""
-    return estimate_partials_pallas(fpa, va, fpb, vb, interpret=_interpret())
-
-
-@_obs.instrumented("estimate_partials_one_vs_many")
-def estimate_partials_one_vs_many(fq, vq, fpc, vc):
-    """Fused Algorithm-5 partial sums: one query sketch vs a [P, m] corpus."""
-    return estimate_one_vs_many_pallas(fq, vq, fpc, vc,
-                                       interpret=_interpret())
-
-
-@_obs.instrumented("estimate_partials_many_vs_many")
-def estimate_partials_many_vs_many(fq, vq, fpc, vc):
-    """Fused Algorithm-5 partial sums: [Q, m] queries vs a [P, m] corpus."""
-    return estimate_many_vs_many_pallas(fq, vq, fpc, vc,
-                                        interpret=_interpret())
-
-
 @_obs.instrumented("estimate_partials_fields")
 def estimate_partials_fields(fq, vq, fpc, vc, *, qmap, cmap):
     """Fused multi-field partial sums: one launch for all field pairs."""
@@ -171,74 +148,6 @@ def estimate_partials_fields(fq, vq, fpc, vc, *, qmap, cmap):
     return estimate_fields_pallas(fq, vq, fpc, vc, qmap=tuple(qmap),
                                   cmap=tuple(cmap), interpret=_interpret(),
                                   **blocks)
-
-
-@_obs.instrumented("icws_estimate")
-@functools.partial(jax.jit, static_argnames=())
-def icws_estimate(fpa, va, na, fpb, vb, nb):
-    """Full ICWS inner-product estimate for P pairs (epilogue in jnp).
-
-    Args: fp [P, m] int32, v [P, m] f32, norms [P] f32.
-    """
-    m = fpa.shape[1]
-    cnt, sw = estimate_partials(fpa, va, fpb, vb)
-    j_hat = cnt / m
-    m_tilde = 2.0 / (1.0 + j_hat)
-    est = na * nb * (m_tilde / m) * sw
-    return jnp.where((na == 0) | (nb == 0), 0.0, est)
-
-
-@_obs.instrumented("icws_estimate_corpus")
-@functools.partial(jax.jit, static_argnames=())
-def icws_estimate_corpus(fq, vq, nq, fpc, vc, nc):
-    """ICWS inner-product estimates of one query against a whole corpus.
-
-    Args: fq/vq [1, m] (or [m]) query, nq scalar norm; fpc/vc [P, m] corpus,
-    nc [P] norms.  Returns [P] f32 estimates.  The query is broadcast inside
-    the kernel -- no [P, m] query tiling is ever materialized.
-    """
-    m = fpc.shape[1]
-    cnt, sw = estimate_partials_one_vs_many(fq, vq, fpc, vc)
-    j_hat = cnt / m
-    m_tilde = 2.0 / (1.0 + j_hat)
-    est = nq * nc * (m_tilde / m) * sw
-    return jnp.where((nq == 0) | (nc == 0), 0.0, est)
-
-
-@_obs.instrumented("icws_estimate_many")
-@functools.partial(jax.jit, static_argnames=())
-def icws_estimate_many(fq, vq, nq, fpc, vc, nc):
-    """ICWS inner-product estimates of Q queries against a whole corpus.
-
-    Args: fq/vq [Q, m] queries, nq [Q] norms; fpc/vc [P, m] corpus, nc [P]
-    norms.  Returns [Q, P] f32 estimates from ONE many-vs-many kernel launch.
-    """
-    m = fpc.shape[1]
-    cnt, sw = estimate_partials_many_vs_many(fq, vq, fpc, vc)
-    j_hat = cnt / m
-    m_tilde = 2.0 / (1.0 + j_hat)
-    est = nq[:, None] * nc[None, :] * (m_tilde / m) * sw
-    return jnp.where((nq[:, None] == 0) | (nc[None, :] == 0), 0.0, est)
-
-
-@_obs.instrumented("icws_estimate_corpus_stacked")
-@jax.jit
-def icws_estimate_corpus_stacked(fq, vq, nq, fpb, vb, nb):
-    """One query vs field 0 of stacked ``[1, cap, m]`` store buffers.
-
-    The field slice happens inside jit, so no standalone ``[cap, m]`` copy
-    of the corpus is materialized outside the launch.  Unused capacity rows
-    (pad-sentinel fingerprints, zero norms) estimate to zero -- callers
-    slice the result to the live row count.
-    """
-    return icws_estimate_corpus(fq, vq, nq, fpb[0], vb[0], nb[0])
-
-
-@_obs.instrumented("icws_estimate_many_stacked")
-@jax.jit
-def icws_estimate_many_stacked(fq, vq, nq, fpb, vb, nb):
-    """Q queries vs field 0 of stacked ``[1, cap, m]`` store buffers."""
-    return icws_estimate_many(fq, vq, nq, fpb[0], vb[0], nb[0])
 
 
 @_obs.instrumented("linear_estimate_fields", packed_arg=1)
@@ -307,13 +216,12 @@ def sample_estimate_fields(kq, vq, tq, kc, vc, tc, *, qmap, cmap):
 # ---------------------------------------------------------------------------
 # sharded query execution: corpus rows spread over a mesh axis
 # ---------------------------------------------------------------------------
-# Each shard runs the same jitted estimate launch on its slice of the corpus
-# rows with the queries replicated; because every corpus row's estimate is
-# independent of every other row (the kernels reduce only over the sample
-# axis m, with identical block sizes on any row count), the concatenated
-# per-shard results are bitwise identical to the single-device launch.
-# Packed buffers shard the same way: their pad rows (zero halfwords) are as
-# inert as zero f32 values.
+# Each shard runs the same single-device fields launch on its slice of the
+# corpus rows with the queries replicated; because every corpus row's
+# estimate is independent of every other row (the kernels reduce only over
+# the sample axis, with identical block sizes on any row count), the
+# concatenated per-shard results are bitwise identical to the single-device
+# launch, for every geometry and for packed buffers alike.
 
 def _pad_corpus_rows(x, pad: int, axis: int, value=0):
     if not pad:
@@ -323,136 +231,40 @@ def _pad_corpus_rows(x, pad: int, axis: int, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-# The shard_map-transformed callables are built once per (mesh, axis, ...)
-# and cached: rebuilding the closure per call would change the transformed
-# function's identity and defeat jax's tracing cache on the serving hot
-# path -- exactly the per-launch overhead the batched engine amortizes.
+# The shard_map-transformed callables are built once per (launch, maps,
+# mesh, axis) and cached: rebuilding the closure per call would change the
+# transformed function's identity and defeat jax's tracing cache on the
+# serving hot path -- exactly the per-launch overhead the batched engine
+# amortizes.
 
 @functools.lru_cache(maxsize=None)
-def _many_sharded_fn(mesh, axis: str):
-    def body(fq, vq, nq, fpb, vb, nb):
-        return icws_estimate_many(fq, vq, nq, fpb[0], vb[0], nb[0])
+def _corpus_sharded_fn(launch, qmap, cmap, mesh, axis: str):
+    def body(q, c):
+        return launch(q, c, qmap=qmap, cmap=cmap)
 
-    return compat.shard_map(
-        body, mesh=mesh,
-        in_specs=(PSpec(), PSpec(), PSpec(),
-                  PSpec(None, axis), PSpec(None, axis), PSpec(None, axis)),
-        out_specs=PSpec(None, axis))
+    return compat.shard_map(body, mesh=mesh,
+                            in_specs=(PSpec(), PSpec(None, axis)),
+                            out_specs=PSpec(None, None, axis))
 
 
-@_obs.instrumented("icws_estimate_many_sharded")
-def icws_estimate_many_sharded(fq, vq, nq, fpb, vb, nb, *, mesh, axis="data"):
-    """Sharded :func:`icws_estimate_many_stacked`: Q queries vs an F=1 store
-    whose corpus rows are split over mesh axis ``axis``.
+@_obs.instrumented("estimate_fields_sharded")
+def estimate_fields_sharded(launch, q, c, *, qmap, cmap, mesh, axis="data"):
+    """Run a single-device fields launch with the corpus rows split over
+    mesh axis ``axis``.
 
-    Queries replicate; corpus buffers shard along their row dim (padded with
-    inert rows to a multiple of the axis size).  Returns ``[Q, cap]`` f32,
-    bitwise identical to the single-device launch.
+    ``launch(q, c, *, qmap, cmap)`` is a family's ``estimate_fields``; ``q``
+    is its tuple of query components (replicated), ``c`` its tuple of
+    ``[C, P, ...]`` corpus components, each split along the row axis.  P
+    must be a multiple of the axis size, as a sharded
+    :class:`repro.data.store.CorpusStore` keeps its capacity.  Returns
+    ``[G, Q, P]`` f32, bitwise identical to ``launch(q, c, ...)``.
     """
     d = mesh.shape[axis]
-    cap = fpb.shape[1]
-    pad = (-cap) % d
-    fpb = _pad_corpus_rows(fpb, pad, 1, CORPUS_PAD_FP)
-    vb = _pad_corpus_rows(vb, pad, 1)
-    nb = _pad_corpus_rows(nb, pad, 1)
-    f = _many_sharded_fn(mesh, axis)
-    return f(fq, vq, nq, fpb, vb, nb)[:, :cap]
-
-
-@functools.lru_cache(maxsize=None)
-def _fields_sharded_fn(mesh, axis: str, qmap, cmap):
-    def body(fq, vq, nq, fpc, vc, nc):
-        return icws_estimate_fields(fq, vq, nq, fpc, vc, nc,
-                                    qmap=qmap, cmap=cmap)
-
-    return compat.shard_map(
-        body, mesh=mesh,
-        in_specs=(PSpec(), PSpec(), PSpec(),
-                  PSpec(None, axis), PSpec(None, axis), PSpec(None, axis)),
-        out_specs=PSpec(None, None, axis))
-
-
-@_obs.instrumented("icws_estimate_fields_sharded", packed_arg=4)
-def icws_estimate_fields_sharded(fq, vq, nq, fpc, vc, nc, *, qmap, cmap,
-                                 mesh, axis="data"):
-    """Sharded :func:`icws_estimate_fields`: the fused multi-field launch
-    runs per shard over corpus rows split along mesh axis ``axis``.
-
-    Args as :func:`icws_estimate_fields` (corpus ``[C, P, m]`` may be
-    full-capacity store buffers, values f32 or packed bf16).  Returns
-    ``[G, Q, P]`` f32, bitwise identical to the single-device launch.
-    """
-    d = mesh.shape[axis]
-    cap = fpc.shape[1]
-    pad = (-cap) % d
-    fpc = _pad_corpus_rows(fpc, pad, 1, CORPUS_PAD_FP)
-    vc = _pad_corpus_rows(vc, pad, 1)
-    nc = _pad_corpus_rows(nc, pad, 1)
-    f = _fields_sharded_fn(mesh, axis, tuple(qmap), tuple(cmap))
-    return f(fq, vq, nq, fpc, vc, nc)[:, :, :cap]
-
-
-@functools.lru_cache(maxsize=None)
-def _linear_fields_sharded_fn(mesh, axis: str, qmap, cmap):
-    def body(tq, tc):
-        return linear_estimate_fields(tq, tc, qmap=qmap, cmap=cmap)
-
-    return compat.shard_map(
-        body, mesh=mesh,
-        in_specs=(PSpec(), PSpec(None, axis, None, None)),
-        out_specs=PSpec(None, None, axis))
-
-
-@_obs.instrumented("linear_estimate_fields_sharded", packed_arg=1)
-def linear_estimate_fields_sharded(tq, tc, *, qmap, cmap, mesh, axis="data"):
-    """Sharded :func:`linear_estimate_fields`: per-shard launches over
-    corpus rows split along mesh axis ``axis``, queries replicated.
-
-    Returns ``[G, Q, P]`` f32, bitwise identical to the single-device
-    launch: each (q, p) dot depends only on row p's table, rows pad with
-    zeros (inert for linear sketches), and the median epilogue is
-    elementwise over the rep axis inside each shard.
-    """
-    d = mesh.shape[axis]
-    cap = tc.shape[1]
-    pad = (-cap) % d
-    tc = _pad_corpus_rows(tc, pad, 1)
-    f = _linear_fields_sharded_fn(mesh, axis, tuple(qmap), tuple(cmap))
-    return f(tq, tc)[:, :, :cap]
-
-
-@functools.lru_cache(maxsize=None)
-def _sample_fields_sharded_fn(mesh, axis: str, qmap, cmap):
-    def body(kq, vq, tq, kc, vc, tc):
-        return sample_estimate_fields(kq, vq, tq, kc, vc, tc,
-                                      qmap=qmap, cmap=cmap)
-
-    return compat.shard_map(
-        body, mesh=mesh,
-        in_specs=(PSpec(), PSpec(), PSpec(),
-                  PSpec(None, axis), PSpec(None, axis), PSpec(None, axis)),
-        out_specs=PSpec(None, None, axis))
-
-
-@_obs.instrumented("sample_estimate_fields_sharded", packed_arg=4)
-def sample_estimate_fields_sharded(kq, vq, tq, kc, vc, tc, *, qmap, cmap,
-                                   mesh, axis="data"):
-    """Sharded :func:`sample_estimate_fields`: the fused key-match launch
-    runs per shard over corpus rows split along mesh axis ``axis``, queries
-    replicated.  Returns ``[G, Q, P]`` f32, bitwise identical to the
-    single-device launch: each (q, p) estimate reduces only over row p's
-    slots, rows pad with corpus-pad-sentinel keys / zero values / zero tau
-    (inert under the kernel's guards), and the slot reduction order is
-    independent of the per-shard row count.
-    """
-    d = mesh.shape[axis]
-    cap = kc.shape[1]
-    pad = (-cap) % d
-    kc = _pad_corpus_rows(kc, pad, 1, CORPUS_PAD_FP)
-    vc = _pad_corpus_rows(vc, pad, 1)
-    tc = _pad_corpus_rows(tc, pad, 1)
-    f = _sample_fields_sharded_fn(mesh, axis, tuple(qmap), tuple(cmap))
-    return f(kq, vq, tq, kc, vc, tc)[:, :, :cap]
+    rows = c[0].shape[1]
+    if rows % d:
+        raise ValueError(f"{rows} corpus rows do not split over {d} shards")
+    f = _corpus_sharded_fn(launch, tuple(qmap), tuple(cmap), mesh, axis)
+    return f(q, c)
 
 
 def _ordered_top_k(score, k: int):

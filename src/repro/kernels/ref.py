@@ -7,6 +7,8 @@ order semantics where it matters), with no tiling.  Tests assert
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -228,38 +230,15 @@ def countsketch_decode_ref(table, indices, seed: int):
 # ---------------------------------------------------------------------------
 # Fused sketch-pair estimator (Algorithm 5 inner loop over m samples)
 # ---------------------------------------------------------------------------
-def estimate_partials_ref(fpa, va, fpb, vb):
-    """Per-pair partial sums for the WMH/ICWS estimator.
-
-    Args:  fpa/fpb [P, m] int32 fingerprints; va/vb [P, m] f32 values.
-    Returns:
-      n_collide [P] f32   -- number of colliding samples,
-      s_weight  [P] f32   -- sum of va*vb / min(va^2, vb^2) over collisions.
-    """
-    collide = (fpa == fpb) & (fpa >= 0)
-    q = jnp.minimum(va * va, vb * vb)
-    safe_q = jnp.where(collide & (q > 0), q, 1.0)
-    term = jnp.where(collide, va * vb / safe_q, 0.0)
-    return collide.astype(jnp.float32).sum(axis=1), term.sum(axis=1)
-
-
-def estimate_one_vs_many_ref(fq, vq, fpc, vc):
-    """One query sketch vs a P-row corpus (broadcast form of the above).
-
-    Args:  fq/vq [1, m] or [m] query; fpc/vc [P, m] corpus.
-    Returns (n_collide [P], s_weight [P]).
-    """
-    fq = fq.reshape(1, -1)
-    vq = vq.reshape(1, -1)
-    return estimate_partials_ref(fq, vq, fpc, vc)
-
-
 def estimate_many_vs_many_ref(fq, vq, fpc, vc):
-    """Q query sketches vs a P-row corpus.
+    """Per-pair partial sums for the WMH/ICWS estimator, Q query sketches
+    vs a P-row corpus.
 
-    Args:  fq/vq [Q, m] queries; fpc/vc [P, m] corpus.
-    Returns (n_collide [Q, P], s_weight [Q, P]).  The oracle may materialize
-    the [Q, P, m] broadcast; the kernel must not.
+    Args:  fq/vq [Q, m] int32 fingerprints / f32 values; fpc/vc [P, m].
+    Returns:
+      n_collide [Q, P] f32 -- number of colliding samples,
+      s_weight  [Q, P] f32 -- sum of vq*vc / min(vq^2, vc^2) over collisions.
+    The oracle may materialize the [Q, P, m] broadcast; the kernel must not.
     """
     fqb, fcb = fq[:, None, :], fpc[None, :, :]
     vqb, vcb = vq[:, None, :], vc[None, :, :]
@@ -270,12 +249,14 @@ def estimate_many_vs_many_ref(fq, vq, fpc, vc):
     return collide.astype(jnp.float32).sum(axis=2), term.sum(axis=2)
 
 
+@functools.partial(jax.jit, static_argnames=("qmap", "cmap"))
 def estimate_fields_ref(fq, vq, fpc, vc, *, qmap, cmap):
     """Fused multi-field many-vs-many partials.
 
     Args:  fq/vq [F, Q, m] per-field queries; fpc/vc [C, P, m] per-field
     corpus; qmap/cmap length-G field-index tuples (see the kernel).
-    Returns (n_collide [G, Q, P], s_weight [G, Q, P]).
+    Returns (n_collide [G, Q, P], s_weight [G, Q, P]).  Jitted: one
+    compile a shape instead of one dispatch per op.
     """
     cnts, sws = [], []
     for qf, cf in zip(qmap, cmap):

@@ -64,16 +64,10 @@ def estimate_pairwise(sketches, cfg: TelemetryConfig) -> jnp.ndarray:
     if cfg.method == "jl":
         proj = sketches["proj"]                       # [R, m]
         return proj @ proj.T
-    fp, val, norm = sketches["fp"], sketches["val"], sketches["norm"]
-    R = fp.shape[0]
-    fa = jnp.repeat(fp, R, axis=0)
-    va = jnp.repeat(val, R, axis=0)
-    na = jnp.repeat(norm, R)
-    fb = jnp.tile(fp, (R, 1))
-    vb = jnp.tile(val, (R, 1))
-    nb = jnp.tile(norm, R)
-    est = kops.icws_estimate(fa, va, na, fb, vb, nb)
-    return est.reshape(R, R)
+    # the R sketches are both the queries and the corpus of one launch
+    fp, val, norm = (sketches[k][None] for k in ("fp", "val", "norm"))
+    return kops.icws_estimate_fields(fp, val, norm, fp, val, norm,
+                                     qmap=(0,), cmap=(0,))[0]
 
 
 def gradient_agreement(flat_grad: jnp.ndarray, axis_name: str,

@@ -291,9 +291,6 @@ class ICWSFamily:
     def estimate_fields(self, q, c):
         return None
 
-    def estimate_fields_sharded(self, q, c):
-        return None
-
     def merge_rows(self, a, b):
         return a
 
@@ -339,8 +336,6 @@ def test_fc001_family_missing_merge_rows(tmp_path):
                       "    def sketch_rows(self, vecs):\n"
                       "        return ()\n"
                       "    def estimate_fields(self, q, c):\n"
-                      "        return None\n"
-                      "    def estimate_fields_sharded(self, q, c):\n"
                       "        return None\n"
                       "    def host_oracle(self):\n"
                       "        return None\n")
@@ -397,9 +392,6 @@ class _Base:
         return ()
 
     def estimate_fields(self, q, c):
-        return None
-
-    def estimate_fields_sharded(self, q, c):
         return None
 
     def merge_rows(self, a, b):

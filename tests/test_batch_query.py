@@ -1,10 +1,11 @@
 """Batched many-vs-many query engine.
 
-Covers: the many-vs-many Pallas kernel and the fused multi-field kernel vs
-their jnp oracles (property-tested via hypothesis); consistency of the batched kernels with the
-one-vs-many serving kernel; ``SketchCorpus.estimate_batch``; and end-to-end
-identity of ``DatasetSearchIndex.query_batch`` / ``SketchSearchService.
-search_batch`` with a loop of single queries on both backends.
+Covers: the fused multi-field kernel vs its jnp oracle at one field pair
+and at many (property-tested via hypothesis); a query's estimates alone ==
+its row in a batch; batched == sequential estimates against a single-field
+``CorpusStore``; and end-to-end identity of ``DatasetSearchIndex.
+query_batch`` / ``SketchSearchService.search_batch`` with a loop of single
+queries on both backends.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -12,26 +13,29 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.data import DatasetSearchIndex, SketchCorpus
+from repro.data import CorpusStore, DatasetSearchIndex, sketch_batch
 from repro.data.synthetic import sparse_pair
 from repro.kernels import ops, ref
-from repro.kernels.estimate import (estimate_fields_pallas,
-                                    estimate_many_vs_many_pallas,
-                                    estimate_one_vs_many_pallas)
+from repro.kernels.estimate import estimate_fields_pallas
 from repro.serve import SketchSearchService
 
 
+# one query field against one corpus field: the plain ICWS estimate
+ONE_PAIR = {"qmap": (0,), "cmap": (0,)}
+
+
 def _sketch_pair_batch(rng, Q, P, m, lo=0, hi=40):
-    """Random fingerprint/value batches with plenty of collisions."""
-    fq = rng.integers(lo, hi, size=(Q, m)).astype(np.int32)
-    fc = rng.integers(lo, hi, size=(P, m)).astype(np.int32)
-    vq = rng.normal(size=(Q, m)).astype(np.float32)
-    vc = rng.normal(size=(P, m)).astype(np.float32)
+    """Random one-field fingerprint/value batches ``[1, Q, m]`` /
+    ``[1, P, m]`` with plenty of collisions."""
+    fq = rng.integers(lo, hi, size=(1, Q, m)).astype(np.int32)
+    fc = rng.integers(lo, hi, size=(1, P, m)).astype(np.int32)
+    vq = rng.normal(size=(1, Q, m)).astype(np.float32)
+    vc = rng.normal(size=(1, P, m)).astype(np.float32)
     return (jnp.asarray(fq), jnp.asarray(vq), jnp.asarray(fc), jnp.asarray(vc))
 
 
 # ---------------------------------------------------------------------------
-# many-vs-many kernel vs ref oracle (property-tested)
+# fields kernel vs ref oracle (property-tested)
 # ---------------------------------------------------------------------------
 @pytest.mark.slow
 @settings(max_examples=10, deadline=None)
@@ -40,9 +44,10 @@ def _sketch_pair_batch(rng, Q, P, m, lo=0, hi=40):
 def test_many_vs_many_kernel_matches_ref(q, p, m, seed):
     rng = np.random.default_rng(seed)
     fq, vq, fc, vc = _sketch_pair_batch(rng, q, p, m)
-    cnt_k, sw_k = estimate_many_vs_many_pallas(fq, vq, fc, vc, interpret=True)
-    cnt_r, sw_r = ref.estimate_many_vs_many_ref(fq, vq, fc, vc)
-    assert cnt_k.shape == (q, p)
+    cnt_k, sw_k = estimate_fields_pallas(fq, vq, fc, vc, interpret=True,
+                                         **ONE_PAIR)
+    cnt_r, sw_r = ref.estimate_fields_ref(fq, vq, fc, vc, **ONE_PAIR)
+    assert cnt_k.shape == (1, q, p)
     np.testing.assert_array_equal(np.asarray(cnt_k), np.asarray(cnt_r))
     # adversarial random values make the collision terms span many orders of
     # magnitude, so normalize by the result scale (the kernel reduces m in
@@ -129,16 +134,23 @@ def test_fields_kernel_layout_cases_match_ref(Q, P, m, bf16, maps):
                                atol=1e-4 * scale)
 
 
-def test_fields_kernel_query_row_independent_of_batch():
-    """A query's row alone (Q = 1) is bitwise its row in a 16-query batch."""
+@pytest.mark.parametrize("maps", ["fields", "one"])
+def test_fields_kernel_query_row_independent_of_batch(maps):
+    """A query's row alone (Q = 1) is bitwise its row in a batch: a packed
+    16-query batch over the §1.3 maps, and every row of an f32 6-query
+    batch at one field pair."""
     from repro.data.dataset_search import CFIELD, QFIELD
-    fq, vq, fc, vc = _fields_inputs(5, 16, 300, 256, True)
-    batch = estimate_fields_pallas(fq, vq, fc, vc, qmap=QFIELD, cmap=CFIELD,
-                                   interpret=True)
-    for i in (0, 9, 15):
+    if maps == "fields":
+        pairs, rows = {"qmap": QFIELD, "cmap": CFIELD}, (0, 9, 15)
+        fq, vq, fc, vc = _fields_inputs(5, 16, 300, 256, True)
+    else:
+        pairs, rows = ONE_PAIR, range(6)
+        fq, vq, fc, vc = _sketch_pair_batch(np.random.default_rng(11),
+                                            6, 13, 260)
+    batch = estimate_fields_pallas(fq, vq, fc, vc, interpret=True, **pairs)
+    for i in rows:
         alone = estimate_fields_pallas(fq[:, i:i + 1], vq[:, i:i + 1], fc, vc,
-                                       qmap=QFIELD, cmap=CFIELD,
-                                       interpret=True)
+                                       interpret=True, **pairs)
         for a, b in zip(alone, batch):
             np.testing.assert_array_equal(np.asarray(a)[:, 0],
                                           np.asarray(b)[:, i])
@@ -159,28 +171,16 @@ def test_fields_kernel_table_column_independent_of_slice():
                                       np.asarray(b)[:, :, lo:hi])
 
 
-def test_many_vs_many_rows_equal_one_vs_many():
-    """Each row of the batched kernel == the one-vs-many serving kernel."""
-    rng = np.random.default_rng(11)
-    Q, P, m = 6, 13, 260
-    fq, vq, fc, vc = _sketch_pair_batch(rng, Q, P, m)
-    cnt_b, sw_b = estimate_many_vs_many_pallas(fq, vq, fc, vc, interpret=True)
-    for i in range(Q):
-        cnt_1, sw_1 = estimate_one_vs_many_pallas(fq[i:i + 1], vq[i:i + 1],
-                                                  fc, vc, interpret=True)
-        np.testing.assert_array_equal(np.asarray(cnt_1), np.asarray(cnt_b)[i])
-        np.testing.assert_array_equal(np.asarray(sw_1), np.asarray(sw_b)[i])
-
-
 def test_many_vs_many_empty_query_guard():
     """All-empty query rows (fp == -1) collide with nothing; padding rows of
     a ragged batch behave like empty queries."""
     Q, P, m = 3, 5, 128
-    fq = jnp.full((Q, m), -1, jnp.int32)
-    vq = jnp.zeros((Q, m))
-    fc = jnp.full((P, m), -1, jnp.int32)
-    vc = jnp.zeros((P, m))
-    cnt, sw = estimate_many_vs_many_pallas(fq, vq, fc, vc, interpret=True)
+    fq = jnp.full((1, Q, m), -1, jnp.int32)
+    vq = jnp.zeros((1, Q, m))
+    fc = jnp.full((1, P, m), -1, jnp.int32)
+    vc = jnp.zeros((1, P, m))
+    cnt, sw = estimate_fields_pallas(fq, vq, fc, vc, interpret=True,
+                                     **ONE_PAIR)
     assert np.all(np.asarray(cnt) == 0.0) and np.all(np.asarray(sw) == 0.0)
 
 
@@ -191,13 +191,11 @@ def test_many_vs_many_matches_ref_on_real_sketches():
     vecs = [sparse_pair(rng, n=500, nnz=120, overlap=0.3)[0] for _ in range(9)]
     queries = [sparse_pair(rng, n=500, nnz=120, overlap=0.3)[0]
                for _ in range(5)]
-    corpus = SketchCorpus(m=256, seed=4)
-    corpus.add_batch(vecs)
-    from repro.data.corpus import sketch_batch
     fq, vq, _, _ = sketch_batch(queries, m=256, seed=4)
-    fc, vc, _, _ = corpus.arrays()
-    cnt_k, sw_k = estimate_many_vs_many_pallas(fq, vq, fc, vc, interpret=True)
-    cnt_r, sw_r = ref.estimate_many_vs_many_ref(fq, vq, fc, vc)
+    fc, vc, _, _ = sketch_batch(vecs, m=256, seed=4)
+    args = tuple(x[None] for x in (fq, vq, fc, vc))
+    cnt_k, sw_k = estimate_fields_pallas(*args, interpret=True, **ONE_PAIR)
+    cnt_r, sw_r = ref.estimate_fields_ref(*args, **ONE_PAIR)
     np.testing.assert_array_equal(np.asarray(cnt_k), np.asarray(cnt_r))
     sw_k, sw_r = np.asarray(sw_k, np.float64), np.asarray(sw_r, np.float64)
     scale = np.maximum(np.maximum(np.abs(sw_k), np.abs(sw_r)), 1e-12)
@@ -205,20 +203,26 @@ def test_many_vs_many_matches_ref_on_real_sketches():
 
 
 # ---------------------------------------------------------------------------
-# SketchCorpus batched estimation
+# batched estimation against a single-field store
 # ---------------------------------------------------------------------------
 def test_corpus_estimate_batch_matches_sequential():
     rng = np.random.default_rng(19)
     vecs = [sparse_pair(rng, n=500, nnz=120, overlap=0.3)[0] for _ in range(9)]
     queries = [sparse_pair(rng, n=500, nnz=120, overlap=0.3)[0]
                for _ in range(5)]
-    corpus = SketchCorpus(m=128, seed=3)
-    corpus.add_batch(vecs)
-    batched = np.asarray(corpus.estimate_vecs(queries))
+    store = CorpusStore(m=128, fields=1)
+    store.append(*sketch_batch(vecs, m=128, seed=3))
+
+    def estimate(qs):
+        fq, vq, nq, _ = sketch_batch(qs, m=128, seed=3)
+        return np.asarray(ops.icws_estimate_fields(
+            fq[None], vq[None], nq[None], *store.buffers()[:3],
+            **ONE_PAIR)[0, :, :len(store)])
+
+    batched = estimate(queries)
     assert batched.shape == (5, 9)
     for qi, q in enumerate(queries):
-        seq = np.asarray(corpus.estimate_vec(q))
-        np.testing.assert_array_equal(batched[qi], seq)
+        np.testing.assert_array_equal(batched[qi], estimate([q])[0])
 
 
 # ---------------------------------------------------------------------------

@@ -101,8 +101,8 @@ class ICWSSketchLike:
 
 @pytest.mark.parametrize("seed", [0, 5])
 def test_mixed_host_device_estimate_matches_host(seed):
-    """icws_estimate on (host-sketched A, device-sketched B) pairs must agree
-    with the all-host estimator: one sketch per path, same contract."""
+    """Device estimates of (host-sketched A, device-sketched B) pairs must
+    agree with the all-host estimator: one sketch per path, same contract."""
     rng = np.random.default_rng(40 + seed)
     n, m = 400, 1024
     pairs = []
@@ -122,14 +122,16 @@ def test_mixed_host_device_estimate_matches_host(seed):
     scale = np.maximum(np.abs(host_host), 1.0)
     np.testing.assert_allclose(mixed / scale, host_host / scale, atol=0.05)
 
-    # and via the device estimator kernel on the same mixed arrays
-    dev = np.asarray(ops.icws_estimate(
-        jnp.asarray(A.fingerprints, jnp.int32),
-        jnp.asarray(A.values, jnp.float32),
-        jnp.asarray(A.norm, jnp.float32),
-        jnp.asarray(B_dev.fingerprints, jnp.int32),
-        jnp.asarray(B_dev.values, jnp.float32),
-        jnp.asarray(B_dev.norm, jnp.float32)))
+    # and via the device estimate kernel on the same mixed arrays: A's
+    # sketches as the queries, B's as the corpus, pairs on the diagonal
+    est = ops.icws_estimate_fields(
+        jnp.asarray(A.fingerprints, jnp.int32)[None],
+        jnp.asarray(A.values, jnp.float32)[None],
+        jnp.asarray(A.norm, jnp.float32)[None],
+        jnp.asarray(B_dev.fingerprints, jnp.int32)[None],
+        jnp.asarray(B_dev.values, jnp.float32)[None],
+        jnp.asarray(B_dev.norm, jnp.float32)[None], qmap=(0,), cmap=(0,))
+    dev = np.diagonal(np.asarray(est[0]))
     np.testing.assert_allclose(dev / scale, mixed / scale, atol=1e-4)
 
 

@@ -20,7 +20,7 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     import sys; sys.path.insert(0, "src")
     import numpy as np, jax, jax.numpy as jnp
-    from repro.data import DatasetSearchIndex, SketchCorpus
+    from repro.data import CorpusStore, DatasetSearchIndex, ICWSFamily
     from repro.data.synthetic import sparse_pair
     from repro.kernels import ops
     from repro.launch.mesh import make_corpus_mesh
@@ -40,39 +40,51 @@ _SCRIPT = textwrap.dedent("""
         assert np.array_equal(np.asarray(v0), np.asarray(v1)), (n, k)
         assert np.array_equal(np.asarray(i0), np.asarray(i1)), (n, k)
 
-    # -- raw sharded wrapper with corpus rows NOT divisible by the axis:
-    #    the wrapper's own inert-row padding path (sharded stores keep
-    #    capacity divisible, so only raw buffers exercise it)
-    fpb = jnp.asarray(rng.integers(0, 30, size=(1, 5, 64)).astype(np.int32))
-    vb = jnp.asarray(rng.normal(size=(1, 5, 64)).astype(np.float32))
-    nb = jnp.asarray(np.ones((1, 5), np.float32))
-    fq2 = jnp.asarray(rng.integers(0, 30, size=(2, 64)).astype(np.int32))
-    vq2 = jnp.asarray(rng.normal(size=(2, 64)).astype(np.float32))
-    nq2 = jnp.ones((2,), jnp.float32)
-    u = np.asarray(ops.icws_estimate_many_stacked(fq2, vq2, nq2,
-                                                  fpb, vb, nb))
-    s = np.asarray(ops.icws_estimate_many_sharded(
-        fq2, vq2, nq2, fpb, vb, nb, mesh=mesh, axis="data"))
-    assert np.array_equal(u, s)
+    # -- the shard helper on a raw single-field corpus: sharded ==
+    #    single-device, bitwise, and rows that do not split over the axis
+    #    are refused (a sharded store keeps its capacity divisible)
+    fam = ICWSFamily(m=64)
+    pairs = {"qmap": (0,), "cmap": (0,)}
+    q = (jnp.asarray(rng.integers(0, 30, size=(1, 2, 64)).astype(np.int32)),
+         jnp.asarray(rng.normal(size=(1, 2, 64)).astype(np.float32)),
+         jnp.ones((1, 2), jnp.float32))
+    c = (jnp.asarray(rng.integers(0, 30, size=(1, 6, 64)).astype(np.int32)),
+         jnp.asarray(rng.normal(size=(1, 6, 64)).astype(np.float32)),
+         jnp.ones((1, 6), jnp.float32))
+    u = np.asarray(fam.estimate_fields(q, c, **pairs))
+    s = np.asarray(ops.estimate_fields_sharded(
+        fam.estimate_fields, q, c, mesh=mesh, axis="data", **pairs))
+    assert u.shape == (1, 2, 6) and np.array_equal(u, s)
+    try:
+        ops.estimate_fields_sharded(fam.estimate_fields, q,
+                                    tuple(x[:, :5] for x in c), mesh=mesh,
+                                    axis="data", **pairs)
+        raise AssertionError("5 rows split over 2 shards")
+    except ValueError:
+        pass
 
-    # -- SketchCorpus many-vs-many: sharded == unsharded, bitwise
-    #    (5 tables: corpus rows NOT divisible by the 2-way axis)
+    # -- a single-field store of device sketches: sharded == unsharded,
+    #    bitwise (5 tables: live rows NOT divisible by the 2-way axis)
     vecs = [sparse_pair(rng, n=400, nnz=80, overlap=0.3)[0] for _ in range(5)]
     queries = [sparse_pair(rng, n=400, nnz=80, overlap=0.3)[0]
                for _ in range(3)]
-    plain = SketchCorpus(m=128, seed=2)
-    shard = SketchCorpus(m=128, seed=2, mesh=mesh)
-    for c in (plain, shard):
-        c.add_batch(vecs)
-    e0 = np.asarray(plain.estimate_vecs(queries))
-    e1 = np.asarray(shard.estimate_vecs(queries))
-    assert e0.shape == (3, 5)
-    assert np.array_equal(e0, e1)
+    fam = ICWSFamily(m=128, seed=2)
+    plain = CorpusStore(family=fam, fields=1)
+    shard = CorpusStore(family=fam, fields=1, mesh=mesh)
+    for st in (plain, shard):
+        st.append(*fam.sketch_rows(vecs))
+    q = tuple(x[None] for x in fam.sketch_rows(queries)[:3])
+    e0 = np.asarray(fam.estimate_fields(q, plain.buffers(), **pairs))
+    e1 = np.asarray(ops.estimate_fields_sharded(
+        fam.estimate_fields, q, shard.buffers(), mesh=mesh, axis="data",
+        **pairs))
+    assert np.array_equal(e0[:, :, :5], e1[:, :, :5])
+    assert not e1[:, :, 5:].any()                  # spare rows are inert
     # the sharded store's buffers are ALLOCATED across the mesh (corpus
     # memory spreads over devices; queries never redistribute the corpus)
-    fpb, _, _, _ = shard._store.buffers()
+    fpb, _, _, _ = shard.buffers()
     assert len(fpb.sharding.device_set) == 2, fpb.sharding
-    assert shard._store.capacity % 2 == 0
+    assert shard.capacity % 2 == 0
 
     # -- end-to-end index: rankings and every statistic identical,
     #    sequential query and query_batch

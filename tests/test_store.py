@@ -143,18 +143,16 @@ def test_spare_capacity_is_inert_in_estimates():
     fpb, vb, nb, _ = store.buffers()
     assert np.all(np.asarray(fpb)[0, P:] == PAD_FP)
 
-    fq = jnp.asarray(rng.integers(0, 100, size=(2, m)).astype(np.int32))
-    vq = jnp.asarray(rng.normal(size=(2, m)).astype(np.float32))
-    nq = jnp.ones((2,), jnp.float32)
+    fq = jnp.asarray(rng.integers(0, 100, size=(1, 2, m)).astype(np.int32))
+    vq = jnp.asarray(rng.normal(size=(1, 2, m)).astype(np.float32))
+    nq = jnp.ones((1, 2), jnp.float32)
 
-    exact = ops.icws_estimate_many(fq, vq, nq, *store.arrays()[:3])
-    padded = ops.icws_estimate_many_stacked(fq, vq, nq, fpb, vb, nb)
+    def estimate(fp, val, norm):
+        return ops.icws_estimate_fields(fq, vq, nq, fp, val, norm,
+                                        qmap=(0,), cmap=(0,))[0]
+
+    exact = estimate(*(a[None] for a in store.arrays()[:3]))
+    padded = estimate(fpb, vb, nb)
     assert padded.shape == (2, store.capacity)
     assert np.all(np.asarray(padded)[:, P:] == 0.0)       # spare rows: zero
     assert np.array_equal(np.asarray(padded)[:, :P], np.asarray(exact))
-
-    one = ops.icws_estimate_corpus(fq[:1], vq[:1], nq[0],
-                                   *store.arrays()[:3])
-    one_p = ops.icws_estimate_corpus_stacked(fq[:1], vq[:1], nq[0],
-                                             fpb, vb, nb)
-    assert np.array_equal(np.asarray(one_p)[:P], np.asarray(one))

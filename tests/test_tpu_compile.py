@@ -214,8 +214,9 @@ def test_sharded_scan_compiles_for_v5e_2x2(topo, on_tpu):
          ((F, P_LAKE, M), jnp.bfloat16, rows), ((F, P_LAKE), jnp.float32, rows)]
 
     def scan(*a):
-        est = ops.icws_estimate_fields_sharded(
-            *a, qmap=QFIELD, cmap=CFIELD, mesh=mesh, axis="data")
+        est = ops.estimate_fields_sharded(
+            _fam("icws").estimate_fields, a[:3], a[3:], qmap=QFIELD,
+            cmap=CFIELD, mesh=mesh, axis="data")
         return ops.sharded_top_k(est[0], 10, mesh=mesh, axis="data")
 
     shapes = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d, sh in q + c]
